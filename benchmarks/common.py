@@ -1,13 +1,14 @@
 """Shared benchmark utilities: frontier traces, timing, CSV rows.
 
-Methodology note (CPU-only container): the paper's headline tables measure
-multi-thread scalability on a 32-vcore Xeon. This box exposes ONE core, so
-thread-scaling numbers are produced by a discrete-event simulation of the
-morsel dispatching policies (benchmarks/sched_sim.py) driven by MEASURED
-per-frontier work traces from the real graphs/engine; absolute work claims
-(scan sharing, visit factors, frontier shapes) are measured directly on the
-engine. The TPU-mapping performance story lives in the dry-run roofline
-(benchmarks/roofline.py), which is hardware-model-based by design.
+Methodology note: the paper's headline tables measure multi-thread
+scalability on a 32-vcore Xeon. Here thread-scaling numbers come from a
+discrete-event simulation of the morsel dispatching policies
+(benchmarks/sched_sim.py) driven by per-frontier work traces from the real
+graphs/engine; absolute work claims (scan sharing, visit factors, frontier
+shapes) are counted directly on the engine. These modules run on whatever
+backend JAX finds and record no device identity, so none of their wall
+times is a TPU measurement; ``chip_smoke.py`` is the path that runs on the
+chip.
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """Discrete-event simulator of the paper's morsel dispatching policies.
 
 Reproduces the paper's thread-scaling experiments (Tables 1/3/4, Figs 9-12)
-from MEASURED per-frontier work traces on this container's single core.
+from per-frontier work traces measured on the engine (counts, not times).
 
 Model (paper §3/§4):
 - A *source morsel* is an IFE run: a list of per-level work amounts

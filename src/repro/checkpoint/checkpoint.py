@@ -10,8 +10,7 @@ Design for 1000+-node pods (orbax is not available offline):
 - self-pruning: keep the last ``keep`` checkpoints.
 
 On a real multi-host pod each host writes its addressable shards and the
-manifest is written by host 0 (the code paths are the same; this container is
-single-host).
+manifest is written by host 0 (the code paths are the same as single-host).
 """
 from __future__ import annotations
 

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..compat import axis_size
-
 PACK = 32
 
 #: Frontier-union impl for the hybrid's re-dispatch phase. Phase 2 runs nT1S
@@ -60,14 +58,14 @@ def _axis_size(axis_names) -> int:
         axis_names = (axis_names,)
     s = 1
     for a in axis_names:
-        s *= axis_size(a)
+        s *= lax.axis_size(a)
     return s
 
 
 def ring_or_u32(x: jax.Array, axis_name: str) -> jax.Array:
     """Bitwise-OR all-reduce of a uint32 array over one mesh axis via
     ring reduce-scatter + ring all-gather (ppermute)."""
-    K = axis_size(axis_name)
+    K = lax.axis_size(axis_name)
     if K == 1:
         return x
     d = lax.axis_index(axis_name)
@@ -144,7 +142,7 @@ def ring_reduce_scatter(x: jax.Array, axis_name: str, op) -> jax.Array:
     """Generic ring reduce-scatter over one mesh axis: x (flat, length
     divisible by K) -> this device's fully-reduced chunk [n/K].
     ``op(a, b)`` combines chunks (e.g. bitwise_or, minimum)."""
-    K = axis_size(axis_name)
+    K = lax.axis_size(axis_name)
     flat = x.reshape(-1)
     if K == 1:
         return flat
@@ -180,7 +178,7 @@ def allgather_reduce_scatter(x: jax.Array, axis_name: str, op) -> jax.Array:
     phase 1) must use this flavor: a ring there deadlocks the groups still
     iterating once the first group exits (the early group never arrives at
     the all-device rendezvous)."""
-    K = axis_size(axis_name)
+    K = lax.axis_size(axis_name)
     flat = x.reshape(-1)
     if K == 1:
         return flat
@@ -211,7 +209,7 @@ def or_reduce_scatter(x: jax.Array, axis_names, impl: str = "ring") -> jax.Array
         rows = x.shape[0] // _axis_size(axis_names)
         idx = jnp.int32(0)
         for a in axis_names:
-            idx = idx * axis_size(a) + lax.axis_index(a)
+            idx = idx * lax.axis_size(a) + lax.axis_index(a)
         return lax.dynamic_slice_in_dim(full, idx * rows, rows, axis=0)
     # ring on packed bits, sequentially over axes (major axis first)
     flat = (x != 0).reshape(1, -1)

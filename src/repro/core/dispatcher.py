@@ -39,7 +39,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import axis_size, shard_map
 from ..graph.csr import CSRGraph, EllGraph, ShardedBlocks
 from .collectives import gang_merge_scatter, merge_contribution, merge_scatter
 from .edge_compute import EDGE_COMPUTES
@@ -68,7 +67,7 @@ def _flat_axis_index(axes: tuple[str, ...]):
     matching how PartitionSpec((a0, a1)) tiles a dimension)."""
     idx = jnp.int32(0)
     for a in axes:
-        idx = idx * axis_size(a) + lax.axis_index(a)
+        idx = idx * lax.axis_size(a) + lax.axis_index(a)
     return idx
 
 
@@ -385,11 +384,12 @@ def build_engine(
         # stats stack over morsels like iterations: [m, cap, STATS_WIDTH]
         out_spec = (out_spec, P(sa if sa else None))
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             worker,
-            mesh,
+            mesh=mesh,
             in_specs=(g_specs, src_spec),
             out_specs=out_spec,
+            check_vma=False,
         )
     )
     return QueryEngine(
@@ -502,11 +502,12 @@ def build_resume_engine(
     if collect_stats:
         out_spec = (out_spec, P())
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             worker,
-            mesh,
+            mesh=mesh,
             in_specs=(g_specs, P(), P()),
             out_specs=out_spec,
+            check_vma=False,
         )
     )
     return QueryEngine(
@@ -671,11 +672,12 @@ def build_gang_resume_engine(
     if collect_stats:
         out_spec = (out_spec, P())
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             worker,
-            mesh,
+            mesh=mesh,
             in_specs=(g_specs, in_state, P()),
             out_specs=out_spec,
+            check_vma=False,
         )
     )
     return QueryEngine(
@@ -720,17 +722,19 @@ def prepare_graph(
     shard directly on its devices instead of materializing the whole host
     structure first — peak host memory drops to ~1/shards of the wholesale
     build, and under multi-process JAX each process builds only the shards
-    its addressable devices own (``None`` = auto: stream exactly when
-    ``jax.process_count() > 1``). Falls back to the wholesale build when
-    the policy has no graph axes (replicated operands). The placed arrays
-    are bitwise-identical to the wholesale path's either way."""
+    its addressable devices own (``None`` = auto: stream exactly when the
+    policy splits the graph over more than one device — the wholesale
+    build assembles the whole padded structure on the default device
+    before resharding it). Falls back to the wholesale build when the
+    policy has no graph axes (replicated operands). The placed arrays are
+    bitwise-identical to the wholesale path's either way."""
     spec = as_spec(extend)
     k_policy = _axes_size(mesh, policy.graph_axes)
     shards = k_policy
     if pad_shards is not None:
         shards = int(np.lcm(shards, int(pad_shards)))
     if stream is None:
-        stream = jax.process_count() > 1
+        stream = k_policy > 1
     if stream and policy.graph_axes and k_policy > 1:
         return _prepare_graph_streamed(
             csr, mesh, policy, spec, max_deg, shards, k_policy, version

@@ -1518,9 +1518,20 @@ class BackendCostProbe:
     def rates(self, ops, n_pad: int) -> dict:
         """``{backend: {"ms_per_slot", "bytes_per_slot", "probe_ms",
         "slots"}}`` for every backend ``ops`` can run. Bytes are the
-        analytic ``BYTES_PER_SLOT`` stream volume; wall is measured."""
+        analytic ``BYTES_PER_SLOT`` stream volume; wall is measured.
+
+        Operands stacked for K > 1 graph shards are probed on shard 0
+        alone — the slice one device scans inside the engines — since the
+        backends' binned views read only their first shard."""
         ops = as_operands(ops)
-        ctx = ExtendCtx(n_out=n_pad)
+        binned = (
+            ops.rev_binned if ops.rev_binned is not None
+            else ops.rev_binned_pack
+        )
+        k = 1 if binned is None else n_pad // binned.rows_local
+        if k > 1:
+            ops = jax.tree.map(lambda x: x[: x.shape[0] // k], ops)
+        ctx = ExtendCtx(n_out=n_pad, row_offset=0)
         frontier = (
             jnp.arange(n_pad) < max(n_pad // 2, 1)
         )  # half-full: both directions do real work
@@ -1536,10 +1547,12 @@ class BackendCostProbe:
             )
         out = {}
         for name, (be, slots) in probes.items():
+            # operands go in as arguments: a closure would bake the padded
+            # ELL into the program as constants (GiBs at a real size)
             fn = jax.jit(
-                lambda f, v, be=be: be.reach_dense(ops, f, v, ctx)
+                lambda o, f, v, be=be: be.reach_dense(o, f, v, ctx)
             )
-            ms = self.measure_ms(fn, frontier, visited)
+            ms = self.measure_ms(fn, ops, frontier, visited)
             out[name] = {
                 "ms_per_slot": ms / max(slots, 1),
                 "bytes_per_slot": BYTES_PER_SLOT,

@@ -1,9 +1,10 @@
 """Deterministic synthetic graph generators (host-side, numpy).
 
 The paper evaluates on LDBC100, LiveJournal, Spotify, and Graph500-28
-(20M–4.2B edges). This container is CPU-only, so benchmarks use *proxies* with
-matched degree structure at reduced scale; the full-scale shapes appear only in
-the dry-run (ShapeDtypeStructs, no allocation).
+(20M–4.2B edges). The repo generates *proxies* with matched degree structure
+from a seed, at a scale cut to what the device holds (the padded forward ELL
+sets that limit today); the full-scale shapes appear only in the dry-run
+(ShapeDtypeStructs, no allocation).
 
 All generators are deterministic in (shape, seed).
 """

@@ -2,9 +2,10 @@
 
 Each kernel ships as <name>/{<name>.py, ops.py, ref.py}: the pallas_call with
 explicit BlockSpec VMEM tiling, the jit'd wrapper, and the pure-jnp oracle.
-Kernels are validated in interpret mode on CPU (this container) and target
-real TPU lowering (interpret=False) in production. All wrappers share the
-``interpret=None`` auto-detect convention via ``common.default_interpret``.
+Tests run them in interpret mode on the CPU; on a TPU backend
+``interpret=None`` compiles them with Mosaic. All wrappers share that
+auto-detect convention via ``common.default_interpret``. ``binned_pull``
+does not lower for TPU yet and raises ``FusedPullNotLowerable`` there.
 
 - msbfs_extend   : MS-BFS frontier extension (paper hot loop, MXU int8)
 - block_spmm     : block-sparse SpMM (GNN message passing)

@@ -33,9 +33,16 @@ is held as a single VMEM-resident block padded to a multiple of 128 with the
 gather-neutral value, so sentinel slab entries (= padded node count) gather
 the neutral **in-bounds**. This sizes the kernel for graphs whose padded
 node vector fits VMEM alongside one slab tile; the streaming row-block
-variant for larger graphs is a ROADMAP follow-on. Validated in interpret
-mode on CPU (this container); targets real TPU lowering (the accumulator
-gather lowers through Mosaic's dynamic-gather path) in production.
+variant for larger graphs is a ROADMAP follow-on.
+
+The kernel runs in interpret mode only. The TPU (Mosaic) lowering for
+v5e refuses it three ways: the rank-1 output-phase row tiles
+(``out_tile_rows``) are not multiples of the 128-element (int32) /
+512-element (uint8) tiling; the source-vector gather ``gsrc[idx]`` is a
+general 1-D gather ("Only 2D gather is supported"); and the lane ops'
+gather fails its shape check. Compiling it for a TPU therefore raises
+``FusedPullNotLowerable`` instead of interpreting or falling back to the
+jnp reference; ``tests/test_v5e_compile.py`` keeps the refusal on record.
 """
 from __future__ import annotations
 
@@ -59,6 +66,21 @@ LANE_OPS = ("reach_lanes", "min_parent_lanes")
 TILE_SLOTS = 4096  # target int32 adjacency slots per compute tile (16 KiB)
 MIN_TILE_ROWS = 8
 MAX_TILE_ROWS = 256
+
+
+class FusedPullNotLowerable(NotImplementedError):
+    """The fused binned-pull kernel cannot be compiled for a TPU."""
+
+
+def require_lowerable() -> None:
+    """Raise ``FusedPullNotLowerable`` when JAX's backend is a TPU."""
+    if jax.default_backend() == "tpu":
+        raise FusedPullNotLowerable(
+            "the fused binned-pull kernel (backends pull_binned_fused / "
+            "dopt_fused) does not lower for TPU: Mosaic refuses its 1-D "
+            "row tiles and its general VMEM gather; serve pull_binned or "
+            "dopt_binned instead"
+        )
 
 
 def tile_rows(width: int) -> int:
@@ -181,9 +203,7 @@ def _make_kernel(op, plan, lanes, has_w, has_v):
                 sl = (pl.dslice(start, tr),) + (
                     (slice(None),) if lanes else ()
                 )
-                pl.store(
-                    acc_ref, sl, combine(pl.load(acc_ref, sl), part)
-                )
+                acc_ref[sl] = combine(acc_ref[sl], part)
 
         @pl.when(i >= t_compute)
         def _emit():
@@ -209,6 +229,8 @@ def fused_binned_pull(
     """Returns the fused pull result ``[rows_local]`` (or ``[rows_local, L]``
     for the lane ops) — uint8 / int32 / f32 per ``op``."""
     interpret = default_interpret(interpret)
+    if not interpret:
+        require_lowerable()
     assert op in OPS, op
     lanes = op in LANE_OPS
     assert gsrc.ndim == (2 if lanes else 1), (op, gsrc.shape)

@@ -1,4 +1,4 @@
-"""Production mesh construction + version-compatible ``make_mesh``.
+"""Production mesh construction and the repo's one ``make_mesh``.
 
 A FUNCTION, not a module-level constant: importing this module never touches
 jax device state. The dry-run forces 512 host devices before first jax init;
@@ -8,37 +8,22 @@ Single-pod: 16×16 = 256 chips, axes ("data", "model").
 Multi-pod:  2×16×16 = 512 chips, axes ("pod", "data", "model") — the pod axis
 extends data parallelism across pods (DCN-crossing collectives are gradient
 all-reduces only; frontier/TP collectives stay inside a pod).
-
-Supported jax range: 0.4.35 — 0.8.x. ``jax.sharding.AxisType`` and the
-``axis_types=`` kwarg of ``jax.make_mesh`` only exist on the newer end of
-that range; ``make_mesh`` below passes them exactly when available, so every
-mesh in the repo (prod, tests, benchmarks) is built through one helper.
 """
 from __future__ import annotations
 
 from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """Version-compatible ``jax.make_mesh(..., axis_types=Auto)``.
-
-    On jax with ``jax.sharding.AxisType`` the mesh is built with explicit
-    Auto axis types (required for shard_map+auto-sharding interop there);
-    on jax 0.4.x — where the kwarg does not exist and all axes are
-    implicitly Auto — the plain two-argument form is used.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                tuple(axis_shapes), tuple(axis_names),
-                axis_types=(axis_type.Auto,) * len(tuple(axis_names)),
-            )
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with every axis ``Auto`` (the shard_map engines mix
+    manual per-shard code with auto-sharded jit around it)."""
+    names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes), names, axis_types=(AxisType.Auto,) * len(names)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

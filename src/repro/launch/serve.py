@@ -46,8 +46,10 @@ from ..graph.generators import (
     PAPER_DATASETS,
     pick_sources,
 )
+from ..kernels.binned_pull.binned_pull import require_lowerable
 from ..runtime.scheduler import AdaptiveScheduler
 from ..runtime.service import ServingLoop
+from .compile_cache import enable_compile_cache
 from .mesh import make_mesh
 
 
@@ -386,6 +388,9 @@ def main(argv=None) -> int:
                          "(core.extend.BackendCostProbe); 'auto' picks "
                          "measured on TPU, slots on CPU/interpret")
     args = ap.parse_args(argv)
+    if args.backend in ("pull_binned_fused", "dopt_fused"):
+        require_lowerable()
+    enable_compile_cache()
 
     csr = PAPER_DATASETS[args.dataset](args.scale)
     if args.query_kind == "topk_paths" and csr.weights is None:

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import mesh_context
 from ..configs import base as cfgbase
 from ..core.dispatcher import build_engine, pad_sources, _axes_size
 from ..core.policies import POLICIES
@@ -837,5 +836,5 @@ def lower_cell(cell: Cell, mesh: Mesh):
             donate_argnums=cell.donate,
             **kw,
         )
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         return jf.lower(*cell.args)

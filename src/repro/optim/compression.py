@@ -12,8 +12,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
-
-from ..compat import axis_size
+from jax import lax
 
 
 class CompressionState(NamedTuple):
@@ -70,7 +69,7 @@ def compressed_psum(grads, state: CompressionState, axis_name: str):
     """End-to-end compressed DP all-reduce inside shard_map: quantize,
     psum int8 payloads (as int32), dequantize with the psum'd scale."""
     qs, scales, state = compress_grads(grads, state)
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     summed = jax.tree.map(
         lambda q: jax.lax.psum(q.astype(jnp.int32), axis_name), qs
     )

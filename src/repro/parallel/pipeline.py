@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
 
 
 def pipeline_apply(
@@ -74,10 +73,11 @@ def pipeline_apply(
         return outs
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         worker,
-        mesh,
+        mesh=mesh,
         in_specs=(spec_params, P()),
         out_specs=P(),
+        check_vma=False,
     )
     return fn(stage_params, xs)

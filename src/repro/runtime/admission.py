@@ -40,8 +40,6 @@ configuration — ``plan()`` reproduces the legacy ``flush`` batching
 bit-for-bit: same pooled policy decision, same arrival-order source
 concatenation, same per-query spans. The seeded-replay lock in
 tests/test_serving.py pins this.
-
-Supported jax range: 0.4.35 — 0.8.x (host-side module; no jax imports).
 """
 from __future__ import annotations
 

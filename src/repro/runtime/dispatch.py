@@ -41,8 +41,6 @@ blocks, never what any morsel computes. Learning stays host-serial —
 thresholds, traces, and counters are a deterministic function of the batch
 stream regardless of overlap (the seeded-replay lock in
 tests/test_serving.py).
-
-Supported jax range: 0.4.35 — 0.8.x (see repro.compat / repro.launch.mesh).
 """
 from __future__ import annotations
 
@@ -912,6 +910,22 @@ class QueryDispatcher:
         )
         self._cost_rates[n_pad] = rates
         return rates
+
+    def device_report(self) -> dict:
+        """What the dispatcher holds on the device and learned there:
+        ``operand_bytes`` per placed bundle (keyed by the graph axes it is
+        split over; bundles with equal axes are summed), ``cost_rates``
+        per n_pad as probed so far, and the number of engine iterations
+        ``sampled`` for threshold refits."""
+        operand_bytes: dict = {}
+        for key, b in self._graphs.items():
+            n = sum(x.nbytes for x in jax.tree.leaves(b.ops))
+            operand_bytes[key[0]] = operand_bytes.get(key[0], 0) + n
+        return {
+            "operand_bytes": operand_bytes,
+            "cost_rates": {k: dict(v) for k, v in self._cost_rates.items()},
+            "sampled": sum(len(v) for v in self._dir_samples.values()),
+        }
 
     def online_trace(self, cost: str | None = None) -> dict:
         """The accumulated live samples as a ``BENCH_direction_opt``-shaped

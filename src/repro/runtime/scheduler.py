@@ -22,8 +22,6 @@ its ``submit``/``flush`` run the admission layer's planner with no quotas
 and no deadlines, which reproduces the legacy pooled batching bit-for-bit
 (same qid naming, same arrival-order packing, same per-query result rows
 — the replay corpus in tests/test_serving.py pins façade == ServingLoop).
-
-Supported jax range: 0.4.35 — 0.8.x (see repro.compat / repro.launch.mesh).
 """
 from __future__ import annotations
 

@@ -35,8 +35,6 @@ compile time, and the queries it served are excluded from warm percentiles
 — the serving tail must not be reported as compile time (the p99 fix this
 layer exists to make honest). ``overlap_occupancy`` reports how many
 finalizes actually hid behind a later batch's device work.
-
-Supported jax range: 0.4.35 — 0.8.x (see repro.compat / repro.launch.mesh).
 """
 from __future__ import annotations
 

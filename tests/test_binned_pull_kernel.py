@@ -104,6 +104,25 @@ def test_kernel_vs_ref_parity_all_ops(kind):
             )
 
 
+@pytest.mark.parametrize("entry", ["kernel", "serve"])
+def test_fused_refused_on_tpu(monkeypatch, entry):
+    """On a TPU the fused kernel neither interprets nor falls back to its
+    jnp reference: compiling it, or serving a backend built on it, raises
+    the named error (Mosaic refuses the kernel for v5e)."""
+    from repro.kernels.binned_pull.binned_pull import FusedPullNotLowerable
+    from repro.launch import serve
+
+    ops, n_pad = build_operands(fixture("er", seed=3), "pull_binned_fused")
+    pack = ops.rev_binned_pack
+    gsrc, vloc = kernel_inputs("reach", n_pad, pack.rows_local, seed=11)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(FusedPullNotLowerable):
+        if entry == "kernel":
+            binned_pull(pack, gsrc, vloc, op="reach", interpret=False)
+        else:
+            serve.main(["--backend", "dopt_fused"])
+
+
 # every edge compute the binned-pull scan applies to, with its morsel policy
 ENGINE_CASES = [
     ("sp_lengths", policy_ntks),

@@ -551,3 +551,45 @@ def test_extend_spec_validation_and_errors():
         run_ife(ops, jnp.array([0]), "sp_lengths", extend="dopt")
     with pytest.raises(ValueError):
         run_ife(ops, jnp.array([0]), "sp_lengths", extend="block_mxu")
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_cost_probe_rates_one_shard_scan(shards):
+    """The cost="measured" probe times what one device scans: operands
+    stacked for several graph shards are probed on shard 0, so each
+    backend's slot count is its per-shard full scan."""
+    from repro.core.extend import BackendCostProbe
+
+    csr = powerlaw(200, 5.0, seed=2)
+    ops, n_pad = build_operands(
+        csr, "dopt_binned", shards=shards, binned_shards=shards
+    )
+    rates = BackendCostProbe(reps=1).rates(ops, n_pad)
+    assert set(rates) == {"ell_push", "pull_binned"}
+    assert rates["ell_push"]["slots"] == ops.fwd.indices.size // shards
+    assert rates["pull_binned"]["slots"] == ops.rev_binned.capacity_slots
+    for r in rates.values():
+        assert np.isfinite(r["ms_per_slot"]) and r["ms_per_slot"] > 0
+
+
+def test_cost_probe_takes_operands_as_arguments():
+    """The probe's jitted steps read the operands as arguments, not as
+    captured constants: constants put the whole padded forward ELL into
+    the program, which the compiler then holds and folds on the host."""
+    import warnings
+
+    from repro.core.extend import BackendCostProbe
+
+    csr = powerlaw(200, 5.0, seed=2)
+    ops, n_pad = build_operands(csr, "dopt_binned")
+    was = jax.config.jax_captured_constants_warn_bytes
+    jax.config.update("jax_captured_constants_warn_bytes", 1024)
+    try:
+        with warnings.catch_warnings():
+            warnings.filterwarnings(
+                "error", message=".*constants were captured"
+            )
+            rates = BackendCostProbe(reps=1).rates(ops, n_pad)
+    finally:
+        jax.config.update("jax_captured_constants_warn_bytes", was)
+    assert set(rates) == {"ell_push", "pull_binned"}

@@ -10,10 +10,12 @@ import sys
 SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+from functools import partial
+
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+shard_map = partial(jax.shard_map, check_vma=False)
 from repro.core.collectives import or_allreduce, ring_or_u32
 from repro.core import (run_recursive_query, policy_1t1s, policy_nt1s,
                         policy_ntks, policy_ntkms)
@@ -43,8 +45,8 @@ x = (rng.random((8, 1000)) < 0.2)
 def run(impl):
     def f(xs):
         return or_allreduce(xs[0], ("data", "model"), impl)[None]
-    sm = shard_map(f, mesh, P(("data","model"), None),
-                   P(("data","model"), None))
+    sm = shard_map(f, mesh=mesh, in_specs=P(("data","model"), None),
+                   out_specs=P(("data","model"), None))
     return np.asarray(jax.jit(sm)(jnp.asarray(x)))
 ref = np.broadcast_to(x.any(axis=0), (8, 1000))
 for impl in ("pmax", "allgather", "ring"):
@@ -56,8 +58,8 @@ print("collectives OK")
 xu = rng.integers(0, 2**32, size=(8, 37), dtype=np.uint32)
 def fu(xs):
     return ring_or_u32(xs[0], "model")[None]
-sm = shard_map(fu, mesh, P(("data","model"), None),
-               P(("data","model"), None))
+sm = shard_map(fu, mesh=mesh, in_specs=P(("data","model"), None),
+               out_specs=P(("data","model"), None))
 got = np.asarray(jax.jit(sm)(jnp.asarray(xu)))
 expect = np.zeros_like(xu)
 for d in range(2):

@@ -12,6 +12,7 @@ lock — the async overlapped loop replays a seeded stream bit-identically
 strictly serial loop and the synchronous AdaptiveScheduler façade.
 """
 import functools
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -624,3 +625,28 @@ def test_ppr_epsilon_termination_deterministic_across_stack_knobs():
             np.testing.assert_array_equal(m0, m1, err_msg=str(cell))
             np.testing.assert_array_equal(r0, r1, err_msg=str(cell))
             np.testing.assert_array_equal(i0, i1, err_msg=str(cell))
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_dir_fixed_or_from_env(monkeypatch, tmp_path, from_env):
+    """Unset, JAX_COMPILATION_CACHE_DIR leaves the persistent cache at
+    ``.jax_cache`` in the checkout, the same path on every run; set, JAX
+    reads it at start-up and the helper sets no other directory."""
+    import jax
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        expect = str(tmp_path / "cache")
+        monkeypatch.setenv(compile_cache.ENV_VAR, expect)
+        jax.config.update("jax_compilation_cache_dir", expect)
+    else:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        expect = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+    try:
+        assert compile_cache.enable_compile_cache() == expect
+        assert compile_cache.enable_compile_cache() == expect
+        assert jax.config.jax_compilation_cache_dir == expect
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
