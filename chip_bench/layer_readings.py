@@ -1,0 +1,21 @@
+"""Readings shared by the per-layer metric readers in ``metrics/``. Each
+returns None where the run holds nothing to read."""
+from __future__ import annotations
+
+
+def per_batch(ctx: dict, key: str) -> float | None:
+    return ctx[key] / ctx["batches"] if ctx["batches"] else None
+
+
+def device_ms_per(ctx: dict, key: str) -> float | None:
+    trace = ctx["trace"]
+    if trace is None or not ctx[key]:
+        return None
+    return trace["busy_s"] * 1e3 / ctx[key]
+
+
+def idle_share(ctx: dict) -> float | None:
+    trace = ctx["trace"]
+    if trace is None or trace["window_s"] <= 0:
+        return None
+    return 1.0 - trace["busy_s"] / trace["window_s"]
