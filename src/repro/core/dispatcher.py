@@ -71,6 +71,16 @@ def _flat_axis_index(axes: tuple[str, ...]):
     return idx
 
 
+def _name_program(worker, kind: str, policy: MorselPolicy,
+                  spec: ExtendSpec) -> None:
+    """Name an engine's program ``engine_<kind>_<policy>_<backend>``: the
+    jit takes the worker's name, so the profiler's ``XLA Modules`` line
+    tells the phase-1, resume, gang and static engines apart."""
+    backend = (f"dopt_{spec.pull}" if spec.direction == "auto"
+               else spec.backend)
+    worker.__name__ = f"engine_{kind}_{policy.name.lower()}_{backend}"
+
+
 def pad_sources(
     sources: np.ndarray, shards: int, lanes: int, inert_id: int
 ) -> np.ndarray:
@@ -383,6 +393,8 @@ def build_engine(
     if collect_stats:
         # stats stack over morsels like iterations: [m, cap, STATS_WIDTH]
         out_spec = (out_spec, P(sa if sa else None))
+    _name_program(worker, "phase1" if sync == "shard" else "static", policy,
+                  spec)
     fn = jax.jit(
         jax.shard_map(
             worker,
@@ -501,6 +513,7 @@ def build_resume_engine(
     out_spec = IFEResult(state=P(), iterations=P())
     if collect_stats:
         out_spec = (out_spec, P())
+    _name_program(worker, "resume", policy, spec)
     fn = jax.jit(
         jax.shard_map(
             worker,
@@ -671,6 +684,7 @@ def build_gang_resume_engine(
         in_state, out_spec = P(), IFEResult(state=P(), iterations=P())
     if collect_stats:
         out_spec = (out_spec, P())
+    _name_program(worker, "gang", policy, spec)
     fn = jax.jit(
         jax.shard_map(
             worker,

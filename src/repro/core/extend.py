@@ -1326,8 +1326,7 @@ def frontier_stats(ops, state, ctx: ExtendCtx, bin_widths=None):
     *host-filled* column — it stays at the ``-1`` sentinel on device and
     the dispatcher's :class:`BackendCostProbe` converts slot columns to
     per-backend wall estimates when a ``cost="measured"`` consumer asks
-    (device-time via a profiler hook on real TPU, ``time.perf_counter``
-    under interpret/CPU).
+    (host ``time.perf_counter`` around ``block_until_ready``).
 
     This is the sample tap ``build_engine(collect_stats=True)`` (and the
     resume/gang builders') writes into the while_loop carry: a pure
@@ -1492,20 +1491,14 @@ class BackendCostProbe:
     into per-iteration wall estimates without perturbing the engines — the
     probe runs out-of-band on the same device-placed operands.
 
-    Timing source: ``device_timer(fn, *args) -> ms`` when given (on real
-    TPU, a profiler hook reading device time / DMA bytes); otherwise the
-    host fallback — ``block_until_ready`` + ``time.perf_counter`` median of
-    ``reps``, which is what interpret/CPU CI exercises.
+    Timing: the median host wall (``block_until_ready`` +
+    ``time.perf_counter``) of ``reps`` calls after one untimed compile.
     """
 
-    #: probed backends → the slot count their full scan pays
-    def __init__(self, reps: int = 3, device_timer=None):
+    def __init__(self, reps: int = 3):
         self.reps = int(reps)
-        self.device_timer = device_timer
 
     def measure_ms(self, fn, *args) -> float:
-        if self.device_timer is not None:
-            return float(self.device_timer(fn, *args))
         jax.block_until_ready(fn(*args))  # compile outside the timing
         walls = []
         for _ in range(self.reps):

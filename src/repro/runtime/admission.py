@@ -49,6 +49,7 @@ import time
 from typing import Callable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core import QUERY_KINDS, recommend_policy
 from ..core.msbfs import LanePacker
@@ -281,7 +282,14 @@ class AdmissionQueue:
         the queries pack into ONE shared MS-BFS batch — then the deadline
         pass predicts the pack's slowest-lane completion and evicts/sheds
         members that cannot survive it (see module docstring). Otherwise
-        every query is its own solo batch, in arrival order."""
+        every query is its own solo batch, in arrival order.
+
+        Traced as the ``repro.admission.plan`` span (``queued``: queries
+        queued when the round began)."""
+        with TraceAnnotation("repro.admission.plan", queued=len(self._queue)):
+            return self._plan(now)
+
+    def _plan(self, now: float | None) -> AdmissionPlan:
         now = self.clock() if now is None else now
         instant = dict(self._instant)
         self._instant.clear()

@@ -41,10 +41,24 @@ blocks, never what any morsel computes. Learning stays host-serial —
 thresholds, traces, and counters are a deterministic function of the batch
 stream regardless of overlap (the seeded-replay lock in
 tests/test_serving.py).
+
+**Trace spans** (``jax.profiler.TraceAnnotation``, under the serving loop's
+``repro.serve.dispatch``)::
+
+    repro.dispatch.begin          (batch)
+      repro.dispatch.compile      (kind) a launch that compiles
+    repro.dispatch.settle         (batch)
+      repro.dispatch.wait_device  each host block on device results
+      repro.dispatch.compile      (kind) a phase-2 launch that compiles
+      repro.dispatch.refit        a direction-threshold refit
+        repro.dispatch.cost_probe (n_pad) the measured-cost probe
+
+``EngineCache.compile_s`` sums the host seconds of the compile launches.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import time
 from pathlib import Path
@@ -53,6 +67,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import (
@@ -168,6 +183,8 @@ class EngineCache:
         self.shape_misses = 0
         self.evictions = 0  # LRU capacity evictions
         self.invalidations = 0  # entries dropped by invalidate()
+        # host seconds in launches that compiled (see ``launch``)
+        self.compile_s = 0.0
 
     @property
     def compile_events(self) -> int:
@@ -188,6 +205,21 @@ class EngineCache:
         seen.add(shape)
         self.shape_misses += 1
         return True
+
+    @contextlib.contextmanager
+    def launch(self, compiles0: int, kind: str):
+        """Around one engine call. When ``compile_events`` rose since
+        ``compiles0`` (a new engine or morsel shape), the call compiles
+        or loads from the persistent cache: it runs under the
+        ``repro.dispatch.compile`` span and its host seconds add to
+        ``compile_s``."""
+        if self.compile_events == compiles0:
+            yield
+            return
+        t0 = time.perf_counter()
+        with TraceAnnotation("repro.dispatch.compile", kind=kind):
+            yield
+        self.compile_s += time.perf_counter() - t0
 
     def __len__(self) -> int:
         return len(self._engines)
@@ -298,8 +330,6 @@ class SchedulerStats:
     resumed_serial: int = 0
     gangs: int = 0  # gang dispatches issued
     gang_slots: int = 0  # padded gang widths summed over dispatches
-    phase1_ms: float = 0.0
-    phase2_ms: float = 0.0
     budget_too_low: int = 0  # phase-1 budget mispredicts (QueryOutcome)
     budget_too_high: int = 0
     budget_inert_slots: int = 0
@@ -329,8 +359,6 @@ class SchedulerStats:
         self.redispatched += outcome.redispatched
         self.resumed_ganged += outcome.resumed_ganged
         self.resumed_serial += outcome.resumed_serial
-        self.phase1_ms += outcome.phase_ms.get("phase1", 0.0)
-        self.phase2_ms += outcome.phase_ms.get("phase2", 0.0)
         self.budget_too_low += outcome.budget_too_low
         self.budget_too_high += outcome.budget_too_high
         self.budget_inert_slots += outcome.budget_inert_slots
@@ -905,9 +933,11 @@ class QueryDispatcher:
                 best is None or score(ops) > score(best)
             ):
                 best = ops
-        rates = (
-            {} if best is None else self.cost_probe.rates(best, int(n_pad))
-        )
+        with TraceAnnotation("repro.dispatch.cost_probe", n_pad=int(n_pad)):
+            rates = (
+                {} if best is None
+                else self.cost_probe.rates(best, int(n_pad))
+            )
         self._cost_rates[n_pad] = rates
         return rates
 
@@ -990,9 +1020,10 @@ class QueryDispatcher:
         if not any(len(r) for r in self._dir_samples.values()):
             return None
         c = self.cost_mode if cost is None else cost
-        self.direction_thresholds = fit_direction_thresholds(
-            self.online_trace(cost=c), cost=c
-        )
+        with TraceAnnotation("repro.dispatch.refit"):
+            self.direction_thresholds = fit_direction_thresholds(
+                self.online_trace(cost=c), cost=c
+            )
         self.stats.refits += 1
         return self.direction_thresholds
 
@@ -1045,6 +1076,7 @@ class QueryDispatcher:
         )
         budget = self._phase1_budget(buckets)
         collect = bool(self.online_adapt)
+        compiles0 = self.cache.compile_events
         eng1 = self.engine(
             "phase1", p1, ec, n_pad, max_iters=budget,
             state_layout=state_layout, extend=extend, operands=g,
@@ -1053,7 +1085,8 @@ class QueryDispatcher:
         )
         b2 = self._graph_for(p2, extend)
         t0 = time.perf_counter()
-        out1 = eng1(g, morsels)  # async: no block_until_ready
+        with self.cache.launch(compiles0, "phase1"):
+            out1 = eng1(g, morsels)  # async: no block_until_ready
         return {
             "pol": pol, "p2": p2, "ec": ec, "g": g, "n_pad": n_pad,
             "state_layout": state_layout, "extend": extend,
@@ -1073,25 +1106,27 @@ class QueryDispatcher:
         state_layout, extend = inf["state_layout"], inf["extend"]
         n_real, budget, collect = inf["n_real"], inf["budget"], inf["collect"]
         sharded = state_layout == "sharded"
-        out1 = jax.block_until_ready(inf["out1"])
-        t1 = time.perf_counter()
-        res1, stats1 = out1 if collect else (out1, None)
-
-        # survivor test reads ONLY the frontier leaf — and under the
-        # sharded layout only a per-morsel any() reduction (the full state
-        # never gathers to host; the handoff below stays on device)
-        f1 = res1.state.frontier
-        if sharded:
-            active = np.asarray(
-                jnp.any(f1 != 0, axis=tuple(range(1, f1.ndim)))
-            )
-        else:
-            frontier1 = np.asarray(f1)
-            m = frontier1.shape[0]
-            active = frontier1.reshape(m, -1).any(axis=1)
+        with TraceAnnotation("repro.dispatch.wait_device"):
+            out1 = jax.block_until_ready(inf["out1"])
+            t1 = time.perf_counter()
+            res1, stats1 = out1 if collect else (out1, None)
+            # survivor test reads ONLY the frontier leaf — and under the
+            # sharded layout only a per-morsel any() reduction (the full
+            # state never gathers to host; the handoff below stays on
+            # device)
+            f1 = res1.state.frontier
+            if sharded:
+                active = np.asarray(
+                    jnp.any(f1 != 0, axis=tuple(range(1, f1.ndim)))
+                )
+            else:
+                frontier1 = np.asarray(f1)
+                active = frontier1.reshape(frontier1.shape[0], -1).any(axis=1)
+            iters1 = np.asarray(res1.iterations)
+            if stats1 is not None:
+                stats1 = np.asarray(stats1)
         idx = np.nonzero(active)[0]
         phase_ms = {"phase1": (t1 - inf["t0"]) * 1e3, "phase2": 0.0}
-        iters1 = np.asarray(res1.iterations)
         n_real = int(min(n_real, iters1.shape[0]))
         too_low, too_high, inert = count_budget_mispredicts(
             budget, iters1[:n_real], active[:n_real],
@@ -1103,7 +1138,7 @@ class QueryDispatcher:
         )
         if stats1 is not None and n_real > 0:
             self._record_samples(
-                np.asarray(stats1)[:n_real], iters1[:n_real], n_pad,
+                stats1[:n_real], iters1[:n_real], n_pad,
                 push_slots=int(np.prod(g.fwd.indices.shape)),
             )
         if idx.size == 0:
@@ -1129,7 +1164,8 @@ class QueryDispatcher:
 
         state1 = None
         if not sharded:
-            state1 = jax.tree.map(np.asarray, res1.state)
+            with TraceAnnotation("repro.dispatch.wait_device"):
+                state1 = jax.tree.map(np.asarray, res1.state)
 
             def pick(x):
                 out = np.zeros((kp,) + x.shape[1:], np.asarray(x).dtype)
@@ -1145,6 +1181,7 @@ class QueryDispatcher:
                 res1.state, idx, kp, self.mesh, p2.graph_axes
             )
 
+        compiles0 = self.cache.compile_events
         if use_gang:
             eng2 = self.engine(
                 "gang", p2, ec, n_pad, state_layout=state_layout,
@@ -1158,19 +1195,23 @@ class QueryDispatcher:
                 "resume", p2, ec, n_pad, extend=extend, operands=g2,
                 collect_stats=collect, epoch=inf["epoch2"],
             )
-        out2 = eng2(g2, sub_state, jnp.asarray(sub_it))  # async dispatch
+        with self.cache.launch(compiles0, "gang" if use_gang else "resume"):
+            out2 = eng2(g2, sub_state, jnp.asarray(sub_it))  # async
         res2, stats2 = out2 if collect else (out2, None)
         # block only the tiny per-morsel counters: phase 2 has then fully
         # executed on device, but the state leaves stay there — the stitch
         # below is deferred host work
-        iters2 = np.asarray(res2.iterations)
+        with TraceAnnotation("repro.dispatch.wait_device"):
+            iters2 = np.asarray(res2.iterations)
+            if stats2 is not None:
+                stats2 = np.asarray(stats2)
         t2 = time.perf_counter()
         phase_ms["phase2"] = (t2 - t1) * 1e3
         if stats2 is not None and idx.size > 0:
             # survivors' post-budget tails: rows run from each morsel's
             # absolute phase-1 exit counter to its final trip count
             self._record_samples(
-                np.asarray(stats2)[: idx.size], iters2[: idx.size], n_pad,
+                stats2[: idx.size], iters2[: idx.size], n_pad,
                 push_slots=int(np.prod(g.fwd.indices.shape)),
                 start=sub_it[: idx.size], phase=2,
             )
@@ -1234,17 +1275,20 @@ class QueryDispatcher:
 
     def _begin_static(self, pol, ec, g, n_pad, morsels, state_layout,
                       extend=ExtendSpec(), epoch=0):
+        compiles0 = self.cache.compile_events
         eng = self.engine(
             "static", pol, ec, n_pad, state_layout=state_layout,
             extend=extend, operands=g, morsel_shape=morsels.shape[:1],
             epoch=epoch,
         )
         t0 = time.perf_counter()
-        res = eng(g, morsels)  # async: no block_until_ready
+        with self.cache.launch(compiles0, "static"):
+            res = eng(g, morsels)  # async: no block_until_ready
         return {"pol": pol, "res": res, "t0": t0}
 
     def _settle_static(self, inf) -> SettledBatch:
-        res = jax.block_until_ready(inf["res"])
+        with TraceAnnotation("repro.dispatch.wait_device"):
+            res = jax.block_until_ready(inf["res"])
         t1 = time.perf_counter()
         return SettledBatch(QueryOutcome(
             result=res, policy=inf["pol"].name, hybrid=False, redispatched=0,
@@ -1390,7 +1434,17 @@ class QueryDispatcher:
         asynchronously. The returned ``InflightBatch`` MUST be settled via
         ``settle_batch`` before the next ``begin_batch`` — learning is
         host-serial, and the budget/threshold state a later batch reads is
-        only current once the earlier batch has settled."""
+        only current once the earlier batch has settled.
+
+        Traced as ``repro.dispatch.begin``; ``batch`` is the count of
+        batches settled before it (``stats.queries``), which is also the
+        ``batch`` of its ``repro.dispatch.settle``."""
+        with TraceAnnotation("repro.dispatch.begin", batch=self.stats.queries):
+            return self._begin(sources, returns_paths, policy, state_layout,
+                               backend, query_kind)
+
+    def _begin(self, sources, returns_paths, policy, state_layout, backend,
+               query_kind) -> InflightBatch:
         (sources, name, pol, ec, spec, g, n_pad, morsels, chunk, n_real,
          buckets, epoch) = self._plan_query(
              sources, returns_paths, policy, backend, query_kind)
@@ -1422,23 +1476,28 @@ class QueryDispatcher:
         post-batch learning. The result state may still be deferred —
         ``finalize_batch`` (or ``SettledBatch.finalize``) materializes it;
         the serving loop calls that *after* dispatching the next phase 1
-        so the host stitch overlaps device compute."""
-        if inflight.kind == "chunked":
-            p = inflight.payload
-            outcome = self._run_chunked(
-                p["pol"], p["ec"], p["g"], p["n_pad"], p["morsels"],
-                p["chunk"], p["state_layout"], p["spec"],
-                inflight.n_real, inflight.buckets, p.get("epoch", 0),
-            )
-            settled = SettledBatch(outcome)
-        elif inflight.kind == "hybrid":
-            settled = self._settle_hybrid(inflight.payload)
-        else:
-            settled = self._settle_static(inflight.payload)
-        settled.outcome.policy = inflight.name
-        self._learn(settled.outcome, inflight.buckets, inflight.n_real)
-        self.stats.record(settled.outcome)
-        return settled
+        so the host stitch overlaps device compute.
+
+        Traced as ``repro.dispatch.settle``, with the host's blocks on
+        device results as ``repro.dispatch.wait_device`` and a threshold
+        refit as ``repro.dispatch.refit`` inside it."""
+        with TraceAnnotation("repro.dispatch.settle", batch=self.stats.queries):
+            if inflight.kind == "chunked":
+                p = inflight.payload
+                outcome = self._run_chunked(
+                    p["pol"], p["ec"], p["g"], p["n_pad"], p["morsels"],
+                    p["chunk"], p["state_layout"], p["spec"],
+                    inflight.n_real, inflight.buckets, p.get("epoch", 0),
+                )
+                settled = SettledBatch(outcome)
+            elif inflight.kind == "hybrid":
+                settled = self._settle_hybrid(inflight.payload)
+            else:
+                settled = self._settle_static(inflight.payload)
+            settled.outcome.policy = inflight.name
+            self._learn(settled.outcome, inflight.buckets, inflight.n_real)
+            self.stats.record(settled.outcome)
+            return settled
 
     def finalize_batch(self, settled: SettledBatch) -> QueryOutcome:
         """Run the deferred host materialization (idempotent)."""

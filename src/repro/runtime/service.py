@@ -35,6 +35,23 @@ compile time, and the queries it served are excluded from warm percentiles
 — the serving tail must not be reported as compile time (the p99 fix this
 layer exists to make honest). ``overlap_occupancy`` reports how many
 finalizes actually hid behind a later batch's device work.
+
+**Trace spans.** Each step is a ``jax.profiler.TraceAnnotation`` (about a
+microsecond when no profiler runs), nested as the profiler's host plane
+shows them::
+
+    repro.serve.pump
+      repro.admission.plan          (queued)
+      repro.serve.dispatch          (batch)
+        repro.dispatch.begin        (batch; see runtime/dispatch.py)
+        repro.serve.finalize        (batch of the tail, overlapped)
+          repro.serve.fetch         device state to host, deferred stitch
+          repro.serve.callback      ``on_result``: the caller's code
+        repro.dispatch.settle       (batch)
+
+``batch`` is the batch's index in ``ServingStats.batches``; a finalize
+run by ``drain`` (or with ``overlap=False``) sits outside the next
+dispatch.
 """
 from __future__ import annotations
 
@@ -43,6 +60,7 @@ import time
 from typing import Any, Callable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core import QUERY_KINDS
 from .admission import AdmissionQueue, AdmissionTicket, PlannedBatch
@@ -117,6 +135,9 @@ class ServingStats:
     overlapped_finalizes: int = 0
     cold_ms: float = 0.0
     deltas_applied: int = 0  # graph mutations served mid-stream
+    # submit -> launch of its batch, summed over dispatched queries
+    queue_wait_s: float = 0.0
+    dispatched_queries: int = 0
     tenants: dict = dataclasses.field(default_factory=dict)
 
     @property
@@ -275,49 +296,73 @@ class ServingLoop:
         dispatched. The pipeline tail (the last settled batch) stays
         unfinalized so the NEXT pump's first batch can overlap it —
         ``drain()`` flushes it when the stream ends."""
-        plan = self.admission.plan(now=self.clock())
-        for qid, levels in plan.instant.items():
-            self._deliver(qid, levels, cold=False)
-        for qid, reason in plan.shed:
-            meta = self._meta.pop(qid, None)
-            if meta is not None:
-                self.stats.tenant(meta[0]).shed += 1
-        for pb in plan.batches:
-            self._dispatch(pb)
-        return len(plan.batches)
+        with TraceAnnotation("repro.serve.pump"):
+            plan = self.admission.plan(now=self.clock())
+            for qid, levels in plan.instant.items():
+                self._deliver(qid, levels, cold=False)
+            for qid, reason in plan.shed:
+                meta = self._meta.pop(qid, None)
+                if meta is not None:
+                    self.stats.tenant(meta[0]).shed += 1
+            for pb in plan.batches:
+                self._dispatch(pb)
+            return len(plan.batches)
 
     def _dispatch(self, pb: PlannedBatch) -> None:
-        t0 = self.clock()
-        compiles0 = self.dispatcher.cache.compile_events
-        inflight = self.dispatcher.begin_batch(
-            pb.sources, policy=pb.policy, query_kind=pb.query_kind,
-        )
-        if self._tail is not None and self.overlap:
-            # batch i's phase 1 is now in flight on device: the host is
-            # free to stitch batch i-1 — the overlap this loop exists for
-            self._finalize_tail(overlapped=True)
-        settled = self.dispatcher.settle_batch(inflight)
-        # compile_events (builds + first-seen morsel shapes), not misses:
-        # a cached engine retracing on a new morsel count stalls this
-        # batch on XLA exactly like a fresh build would
-        cold = self.dispatcher.cache.compile_events > compiles0
-        self.stats.batches += 1
-        if cold:
-            self.stats.cold_batches += 1
-        self._tail = (settled, pb, t0, cold)
-        if not self.overlap:
-            self._finalize_tail(overlapped=False)
+        batch = self.stats.batches
+        with TraceAnnotation("repro.serve.dispatch", batch=batch):
+            t0 = self.clock()
+            self.stats.queue_wait_s += sum(t0 - q.t_submit for q in pb.queries)
+            self.stats.dispatched_queries += len(pb.queries)
+            compiles0 = self.dispatcher.cache.compile_events
+            inflight = self.dispatcher.begin_batch(
+                pb.sources, policy=pb.policy, query_kind=pb.query_kind,
+            )
+            if self._tail is not None and self.overlap:
+                # batch i's phase 1 is now in flight on device: the host is
+                # free to stitch batch i-1 — the overlap this loop exists for
+                self._finalize_tail(overlapped=True)
+            settled = self.dispatcher.settle_batch(inflight)
+            # compile_events (builds + first-seen morsel shapes), not
+            # misses: a cached engine retracing on a new morsel count
+            # stalls this batch on XLA exactly like a fresh build would
+            cold = self.dispatcher.cache.compile_events > compiles0
+            self.stats.batches += 1
+            if cold:
+                self.stats.cold_batches += 1
+            self._tail = (settled, pb, t0, cold, batch)
+            if not self.overlap:
+                self._finalize_tail(overlapped=False)
 
     def _finalize_tail(self, overlapped: bool) -> None:
-        settled, pb, t0, cold = self._tail
+        settled, pb, t0, cold, batch = self._tail
         self._tail = None
-        outcome = settled.finalize()
-        t1 = self.clock()
+        with TraceAnnotation("repro.serve.finalize", batch=batch,
+                             overlapped=overlapped):
+            self._finalize(settled, pb, t0, cold, overlapped)
+
+    def _finalize(self, settled: SettledBatch, pb: PlannedBatch, t0: float,
+                  cold: bool, overlapped: bool) -> None:
+        with TraceAnnotation("repro.serve.fetch"):
+            outcome = settled.finalize()
+            t1 = self.clock()
+            iters = np.asarray(outcome.result.iterations)
+            if pb.query_kind == "reach":
+                levels = np.asarray(outcome.result.state.levels)
+            else:
+                # non-reach kinds are never lane-packed (admission's
+                # lanes_ok carve-out), so the state leaves are already one
+                # row per source
+                assert not pb.packed, pb.query_kind
+                leaves = QUERY_KINDS[pb.query_kind].result_leaves
+                arrs = {
+                    leaf: np.asarray(getattr(outcome.result.state, leaf))
+                    for leaf in leaves
+                }
         self.stats.finalizes += 1
         if overlapped:
             self.stats.overlapped_finalizes += 1
         wall_ms = (t1 - t0) * 1e3
-        iters = np.asarray(outcome.result.iterations)
         depth = float(iters.max()) if iters.size else 0.0
         if cold:
             self.stats.cold_ms += wall_ms
@@ -330,21 +375,10 @@ class ServingLoop:
             )
         n = self.dispatcher.csr.n_nodes
         if pb.query_kind == "reach":
-            out = unpack_levels(
-                np.asarray(outcome.result.state.levels), pb.spans,
-                n, pb.packed,
-            )
+            out = unpack_levels(levels, pb.spans, n, pb.packed)
         else:
-            # non-reach kinds are never lane-packed (admission's lanes_ok
-            # carve-out), so the state leaves are already one row per
-            # source: slice each query's span and the graph padding off
-            # every result leaf the kind declares
-            assert not pb.packed, pb.query_kind
-            leaves = QUERY_KINDS[pb.query_kind].result_leaves
-            arrs = {
-                leaf: np.asarray(getattr(outcome.result.state, leaf))
-                for leaf in leaves
-            }
+            # slice each query's span and the graph padding off every
+            # result leaf the kind declares
             out = {
                 qid: (
                     arrs[leaves[0]][a:b, :n]
@@ -374,7 +408,8 @@ class ServingLoop:
         self.results[qid] = levels
         self.admission.complete(qid)
         if self.on_result is not None:
-            self.on_result(qid, levels)
+            with TraceAnnotation("repro.serve.callback"):
+                self.on_result(qid, levels)
 
     # ------------------------------------------------------------ mutation
 

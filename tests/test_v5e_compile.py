@@ -100,6 +100,9 @@ def test_served_engine_compiles_for_v5e(mesh11, served_operands):
     compiled = eng.fn.lower(ops, morsels).compile()
     _fits_one_chip(compiled, _nbytes(ops))
     assert "while" in compiled.as_text()
+    # the name the device trace's XLA Modules line gives this program
+    assert compiled.as_text().startswith(
+        "HloModule jit_engine_phase1_ntks_dopt_binned")
 
 
 @pytest.mark.parametrize(
