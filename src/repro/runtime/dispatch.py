@@ -21,7 +21,9 @@ on:
 - ``begin_batch``  — choose policy/backend/budget and *dispatch* phase 1
   asynchronously (no ``block_until_ready``): jax async dispatch returns
   immediately with device futures, so the host is free while the device
-  scans.
+  scans. The host copies of the leaves settle and finalize will read are
+  enqueued behind the launch (``HostReads``), so the host later pays one
+  wait where it paid a round trip per leaf.
 - ``settle_batch`` — block on the phase-1 frontier, re-dispatch survivors
   (phase 2, also async), block only on the tiny per-morsel iteration
   counters, run post-batch learning, and return a ``SettledBatch`` whose
@@ -45,7 +47,7 @@ tests/test_serving.py).
 **Trace spans** (``jax.profiler.TraceAnnotation``, under the serving loop's
 ``repro.serve.dispatch``)::
 
-    repro.dispatch.begin          (batch)
+    repro.dispatch.begin          (batch, prefetch: leaves copied at launch)
       repro.dispatch.compile      (kind) a launch that compiles
     repro.dispatch.settle         (batch)
       repro.dispatch.wait_device  each host block on device results
@@ -53,7 +55,9 @@ tests/test_serving.py).
       repro.dispatch.refit        a direction-threshold refit
         repro.dispatch.cost_probe (n_pad) the measured-cost probe
 
-``EngineCache.compile_s`` sums the host seconds of the compile launches.
+``EngineCache.compile_s`` sums the host seconds of the compile launches;
+``SchedulerStats.d2h_prefetched`` / ``d2h_blocking`` count the result
+leaves read to the host with and without a copy enqueued at launch.
 """
 from __future__ import annotations
 
@@ -336,6 +340,11 @@ class SchedulerStats:
     budget_observed: int = 0
     refits: int = 0  # in-flight direction-threshold refits
     deltas: int = 0  # GraphDeltas applied (apply_delta calls)
+    # result leaves read to the host in settle, _learn or finalize, each
+    # distinct array once a batch (HostReads): copy enqueued at launch,
+    # or read with no prefetch (a full round trip)
+    d2h_prefetched: int = 0
+    d2h_blocking: int = 0
 
     @property
     def gang_occupancy(self) -> float:
@@ -396,6 +405,39 @@ class OperandBundle:
         return iter((self.ops, self.n_pad))
 
 
+class HostReads:
+    """One batch's device-to-host reads of its results.
+
+    ``prefetch`` enqueues the host copy of each named leaf
+    (``copy_to_host_async``) right after the launch: each copy starts as
+    soon as the engine's outputs are ready, the copies run side by side,
+    and the ``np.asarray`` in ``read`` then finds the host value cached.
+    ``read`` counts each distinct array once a batch into
+    ``SchedulerStats``: ``d2h_prefetched`` if its copy was enqueued, else
+    ``d2h_blocking``."""
+
+    def __init__(self, stats: SchedulerStats):
+        self.stats = stats
+        self.prefetched: dict[str, jax.Array] = {}
+        self._seen: list = []  # arrays already counted this batch
+
+    def prefetch(self, leaves: dict) -> None:
+        """Enqueue each leaf's host copy; None entries are skipped."""
+        for name, x in leaves.items():
+            if x is not None:
+                x.copy_to_host_async()
+                self.prefetched[name] = x
+
+    def read(self, x) -> np.ndarray:
+        if not any(x is y for y in self._seen):
+            self._seen.append(x)
+            if any(x is y for y in self.prefetched.values()):
+                self.stats.d2h_prefetched += 1
+            else:
+                self.stats.d2h_blocking += 1
+        return np.asarray(x)
+
+
 @dataclasses.dataclass
 class InflightBatch:
     """A batch whose phase 1 (or static engine) has been *dispatched* but
@@ -413,6 +455,7 @@ class InflightBatch:
     n_real: int
     buckets: np.ndarray
     payload: Any
+    reads: HostReads
 
 
 @dataclasses.dataclass
@@ -424,6 +467,7 @@ class SettledBatch:
 
     outcome: QueryOutcome
     _materialize: Callable[[], IFEResult] | None = None
+    reads: HostReads | None = None  # set by settle_batch
 
     @property
     def finalized(self) -> bool:
@@ -1028,12 +1072,12 @@ class QueryDispatcher:
         return self.direction_thresholds
 
     def _learn(self, outcome: "QueryOutcome", buckets: np.ndarray,
-               n_real: int) -> None:
+               n_real: int, reads: HostReads) -> None:
         """Post-batch learning: feed the budget model (real morsels only
         — the per-bucket form of the pad-morsel guard; skipped entirely
         when ``phase1_iters`` pins the budget) and the global-p90
         fallback, then refit thresholds on the ``refit_every`` cadence."""
-        iters = np.asarray(outcome.result.iterations)[:n_real]
+        iters = reads.read(outcome.result.iterations)[:n_real]
         self._record_iters(iters)
         if (
             self.budget_model is not None
@@ -1057,10 +1101,20 @@ class QueryDispatcher:
     # ------------------------------------------ split-phase hybrid internals
 
     def _begin_hybrid(self, pol, ec, g, n_pad, morsels, state_layout,
-                      extend=ExtendSpec(), n_real=0, buckets=(), epoch=0):
+                      extend=ExtendSpec(), n_real=0, buckets=(), epoch=0,
+                      *, reads: HostReads, result_leaves=None):
         """Choose the budget, then DISPATCH phase 1 without blocking: jax
         async dispatch returns device futures immediately, so the caller's
         host thread is free until ``_settle_hybrid`` blocks on them.
+
+        With ``result_leaves`` (the state fields finalize will read) the
+        host copies settle and finalize need are enqueued behind the
+        launch: the frontier, iterations, stats when collected and the
+        result leaves; under the sharded layout only iterations and
+        stats (its survivor test reads an on-device ``any()``, and its
+        state never gathers to the host before the stitch). ``visited``
+        never: only the survivor path reads it, which launch cannot
+        know. None (the chunked path) prefetches nothing.
 
         The phase-2 operand bundle is resolved and PINNED here, at begin
         time, even though it is only consumed at settle time: resolving
@@ -1087,11 +1141,20 @@ class QueryDispatcher:
         t0 = time.perf_counter()
         with self.cache.launch(compiles0, "phase1"):
             out1 = eng1(g, morsels)  # async: no block_until_ready
+        if result_leaves is not None:
+            res1, stats1 = out1 if collect else (out1, None)
+            leaves = {"iterations": res1.iterations, "stats": stats1}
+            if state_layout != "sharded":
+                leaves = {
+                    "frontier": res1.state.frontier, **leaves,
+                    **{k: getattr(res1.state, k) for k in result_leaves},
+                }
+            reads.prefetch(leaves)
         return {
             "pol": pol, "p2": p2, "ec": ec, "g": g, "n_pad": n_pad,
             "state_layout": state_layout, "extend": extend,
             "n_real": n_real, "budget": budget, "collect": collect,
-            "out1": out1, "t0": t0, "epoch": epoch,
+            "out1": out1, "t0": t0, "epoch": epoch, "reads": reads,
             "g2": b2.ops, "n_pad2": b2.n_pad,
             "epoch2": self._spec_epoch(b2, extend),
         }
@@ -1105,6 +1168,7 @@ class QueryDispatcher:
         g, n_pad = inf["g"], inf["n_pad"]
         state_layout, extend = inf["state_layout"], inf["extend"]
         n_real, budget, collect = inf["n_real"], inf["budget"], inf["collect"]
+        reads = inf["reads"]
         sharded = state_layout == "sharded"
         with TraceAnnotation("repro.dispatch.wait_device"):
             out1 = jax.block_until_ready(inf["out1"])
@@ -1116,15 +1180,15 @@ class QueryDispatcher:
             # device)
             f1 = res1.state.frontier
             if sharded:
-                active = np.asarray(
+                active = reads.read(
                     jnp.any(f1 != 0, axis=tuple(range(1, f1.ndim)))
                 )
             else:
-                frontier1 = np.asarray(f1)
+                frontier1 = reads.read(f1)
                 active = frontier1.reshape(frontier1.shape[0], -1).any(axis=1)
-            iters1 = np.asarray(res1.iterations)
+            iters1 = reads.read(res1.iterations)
             if stats1 is not None:
-                stats1 = np.asarray(stats1)
+                stats1 = reads.read(stats1)
         idx = np.nonzero(active)[0]
         phase_ms = {"phase1": (t1 - inf["t0"]) * 1e3, "phase2": 0.0}
         n_real = int(min(n_real, iters1.shape[0]))
@@ -1165,7 +1229,7 @@ class QueryDispatcher:
         state1 = None
         if not sharded:
             with TraceAnnotation("repro.dispatch.wait_device"):
-                state1 = jax.tree.map(np.asarray, res1.state)
+                state1 = jax.tree.map(reads.read, res1.state)
 
             def pick(x):
                 out = np.zeros((kp,) + x.shape[1:], np.asarray(x).dtype)
@@ -1198,13 +1262,14 @@ class QueryDispatcher:
         with self.cache.launch(compiles0, "gang" if use_gang else "resume"):
             out2 = eng2(g2, sub_state, jnp.asarray(sub_it))  # async
         res2, stats2 = out2 if collect else (out2, None)
+        reads.prefetch({"iterations2": res2.iterations, "stats2": stats2})
         # block only the tiny per-morsel counters: phase 2 has then fully
         # executed on device, but the state leaves stay there — the stitch
         # below is deferred host work
         with TraceAnnotation("repro.dispatch.wait_device"):
-            iters2 = np.asarray(res2.iterations)
+            iters2 = reads.read(res2.iterations)
             if stats2 is not None:
-                stats2 = np.asarray(stats2)
+                stats2 = reads.read(stats2)
         t2 = time.perf_counter()
         phase_ms["phase2"] = (t2 - t1) * 1e3
         if stats2 is not None and idx.size > 0:
@@ -1223,7 +1288,7 @@ class QueryDispatcher:
             if sharded:
                 final_state = gang_scatter_back(res1.state, res2.state, idx)
             else:
-                state2 = jax.tree.map(np.asarray, res2.state)
+                state2 = jax.tree.map(reads.read, res2.state)
 
                 def put(full, sub):
                     out = np.asarray(full).copy()
@@ -1270,11 +1335,17 @@ class QueryDispatcher:
         inf = self._begin_hybrid(
             pol, ec, g, n_pad, morsels, state_layout, extend=extend,
             n_real=n_real, buckets=buckets, epoch=epoch,
+            reads=HostReads(self.stats),
         )
         return self._settle_hybrid(inf).finalize()
 
     def _begin_static(self, pol, ec, g, n_pad, morsels, state_layout,
-                      extend=ExtendSpec(), epoch=0):
+                      extend=ExtendSpec(), epoch=0, reads=None,
+                      result_leaves=None):
+        """Dispatch the single engine without blocking. With
+        ``result_leaves`` the host copies of iterations and those leaves
+        are enqueued behind it, under either layout: a static result is
+        final at launch, and finalize reads all of them."""
         compiles0 = self.cache.compile_events
         eng = self.engine(
             "static", pol, ec, n_pad, state_layout=state_layout,
@@ -1284,6 +1355,11 @@ class QueryDispatcher:
         t0 = time.perf_counter()
         with self.cache.launch(compiles0, "static"):
             res = eng(g, morsels)  # async: no block_until_ready
+        if result_leaves is not None:
+            reads.prefetch({
+                "iterations": res.iterations,
+                **{k: getattr(res.state, k) for k in result_leaves},
+            })
         return {"pol": pol, "res": res, "t0": t0}
 
     def _settle_static(self, inf) -> SettledBatch:
@@ -1436,18 +1512,27 @@ class QueryDispatcher:
         host-serial, and the budget/threshold state a later batch reads is
         only current once the earlier batch has settled.
 
+        The host copies of the leaves settle and finalize will read are
+        enqueued right behind the launch (``HostReads``; see
+        ``_begin_hybrid`` and ``_begin_static`` for which leaves).
+
         Traced as ``repro.dispatch.begin``; ``batch`` is the count of
         batches settled before it (``stats.queries``), which is also the
-        ``batch`` of its ``repro.dispatch.settle``."""
-        with TraceAnnotation("repro.dispatch.begin", batch=self.stats.queries):
-            return self._begin(sources, returns_paths, policy, state_layout,
-                               backend, query_kind)
+        ``batch`` of its ``repro.dispatch.settle``; ``prefetch`` is the
+        number of leaves whose copy was enqueued."""
+        with TraceAnnotation("repro.dispatch.begin",
+                             batch=self.stats.queries) as span:
+            inflight = self._begin(sources, returns_paths, policy,
+                                   state_layout, backend, query_kind)
+            span.set_metadata(prefetch=len(inflight.reads.prefetched))
+            return inflight
 
     def _begin(self, sources, returns_paths, policy, state_layout, backend,
                query_kind) -> InflightBatch:
         (sources, name, pol, ec, spec, g, n_pad, morsels, chunk, n_real,
          buckets, epoch) = self._plan_query(
              sources, returns_paths, policy, backend, query_kind)
+        reads = HostReads(self.stats)
         if morsels.shape[0] > chunk:
             # oversized batch: the in-flight cap splits it into a host-
             # stitched chunk loop — run synchronously at settle time
@@ -1458,18 +1543,25 @@ class QueryDispatcher:
                 "chunk": chunk, "state_layout": state_layout,
                 "epoch": epoch,
             }
-            return InflightBatch("chunked", name, n_real, buckets, payload)
+            return InflightBatch("chunked", name, n_real, buckets, payload,
+                                 reads)
+        # the state fields finalize reads: the kind's result leaves, and
+        # the parent pointers of a path query
+        leaves = QUERY_KINDS[query_kind].result_leaves + (
+            ("parents",) if returns_paths else ()
+        )
         if self._hybrid_eligible(pol, state_layout):
             inf = self._begin_hybrid(
                 pol, ec, g, n_pad, jnp.asarray(morsels), state_layout,
                 extend=spec, n_real=n_real, buckets=buckets, epoch=epoch,
+                reads=reads, result_leaves=leaves,
             )
-            return InflightBatch("hybrid", name, n_real, buckets, inf)
+            return InflightBatch("hybrid", name, n_real, buckets, inf, reads)
         inf = self._begin_static(
             pol, ec, g, n_pad, jnp.asarray(morsels), state_layout,
-            extend=spec, epoch=epoch,
+            extend=spec, epoch=epoch, reads=reads, result_leaves=leaves,
         )
-        return InflightBatch("static", name, n_real, buckets, inf)
+        return InflightBatch("static", name, n_real, buckets, inf, reads)
 
     def settle_batch(self, inflight: InflightBatch) -> SettledBatch:
         """Drive one in-flight batch through its device sync points and
@@ -1495,7 +1587,9 @@ class QueryDispatcher:
             else:
                 settled = self._settle_static(inflight.payload)
             settled.outcome.policy = inflight.name
-            self._learn(settled.outcome, inflight.buckets, inflight.n_real)
+            settled.reads = inflight.reads
+            self._learn(settled.outcome, inflight.buckets, inflight.n_real,
+                        inflight.reads)
             self.stats.record(settled.outcome)
             return settled
 

@@ -45,7 +45,8 @@ shows them::
       repro.serve.dispatch          (batch)
         repro.dispatch.begin        (batch; see runtime/dispatch.py)
         repro.serve.finalize        (batch of the tail, overlapped)
-          repro.serve.fetch         device state to host, deferred stitch
+          repro.serve.fetch         device state to host (copies enqueued
+                                    at begin), deferred stitch
           repro.serve.callback      ``on_result``: the caller's code
         repro.dispatch.settle       (batch)
 
@@ -346,9 +347,11 @@ class ServingLoop:
         with TraceAnnotation("repro.serve.fetch"):
             outcome = settled.finalize()
             t1 = self.clock()
-            iters = np.asarray(outcome.result.iterations)
+            # counted reads: copies begin_batch enqueued are found in place
+            read = settled.reads.read
+            iters = read(outcome.result.iterations)
             if pb.query_kind == "reach":
-                levels = np.asarray(outcome.result.state.levels)
+                levels = read(outcome.result.state.levels)
             else:
                 # non-reach kinds are never lane-packed (admission's
                 # lanes_ok carve-out), so the state leaves are already one
@@ -356,7 +359,7 @@ class ServingLoop:
                 assert not pb.packed, pb.query_kind
                 leaves = QUERY_KINDS[pb.query_kind].result_leaves
                 arrs = {
-                    leaf: np.asarray(getattr(outcome.result.state, leaf))
+                    leaf: read(getattr(outcome.result.state, leaf))
                     for leaf in leaves
                 }
         self.stats.finalizes += 1
