@@ -9,6 +9,10 @@ defaults of ``serve``: ``ServingLoop`` (``submit`` / ``pump`` / ``drain``,
 ``refit_every=16``, no cap on a batch's sources, and a ``(1, chips)`` mesh of
 ``("data", "model")``. The benchmark takes from the program only that path
 and its counters; traffic, clock, reference and trace reduction live here.
+
+``run.py`` arms a ``SetupWatchdog`` at process start: a set-up that has not
+reached the window ``SETUP_DEADLINE_S`` seconds in ends the process, with
+the stage it reached and no result line.
 """
 from __future__ import annotations
 
@@ -19,12 +23,13 @@ import json
 import os
 import shutil
 import sys
+import threading
 import time
 from pathlib import Path
 
 import numpy as np
 
-from . import graphs, oracle, stats, trace_reduce, traffic
+from . import graphs, oracle, program_spans, stats, trace_reduce, traffic
 from .peaks import peaks_for
 
 BENCH_DIR = Path(__file__).resolve().parent
@@ -35,6 +40,10 @@ LATE_WAIT_S = 60.0
 # source rows of a window compared with the reference, at most: past it a
 # reservoir sample drawn from the seed
 MAX_COMPARED_ROWS = 4096
+# seconds from process start to the window, at most: five times the cold
+# (compiling) set-up of ldbc-64src-closed
+SETUP_DEADLINE_S = 300.0
+SETUP_EXPIRED_EXIT = 70
 
 
 class NoChip(SystemExit):
@@ -123,6 +132,54 @@ def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
+class SetupWatchdog:
+    """Ends the process (``os._exit``, no result line) when the window has
+    not opened ``deadline_s`` seconds after ``t_start``, naming the set-up
+    stage it reached: a set-up that would run for tens of minutes fails
+    with its cause instead of hanging. ``run_cell`` reports its stages
+    (``reached``), each logged with its time since ``t_start``, and
+    disarms it as the window opens."""
+
+    def __init__(self, deadline_s: float, t_start: float):
+        self.deadline_s = deadline_s
+        self.t_start = t_start
+        self.stage = "start"
+        self._lock = threading.Lock()
+        self._armed = True
+        self._timer = threading.Timer(
+            max(0.0, t_start + deadline_s - time.perf_counter()), self._expire)
+        self._timer.daemon = True
+        self._timer.start()
+
+    def reached(self, stage: str) -> None:
+        self.stage = stage
+        log(f"set-up: {stage} at {time.perf_counter() - self.t_start:.3f} s")
+
+    def disarm(self) -> None:
+        with self._lock:
+            self._armed = False
+        self._timer.cancel()
+
+    def _expire(self) -> None:
+        with self._lock:
+            if self._armed:
+                log(f"chip_bench: set-up passed its {self.deadline_s:g} s "
+                    f"deadline; stage reached: {self.stage}")
+                os._exit(SETUP_EXPIRED_EXIT)
+
+
+def narrow(levels) -> np.ndarray:
+    """A result row as int16 where every value fits, else in its own dtype:
+    no value changes, so a wrong level (say an int32 sentinel for
+    unreached) cannot wrap onto a right one."""
+    row = np.asarray(levels)
+    small = np.iinfo(np.int16)
+    if (row.dtype.kind in "iu" and row.size
+            and small.min <= row.min() and row.max() <= small.max):
+        return row.astype(np.int16)
+    return np.array(row)
+
+
 class Recorder:
     """``on_result`` of the served loop: delivery times, and the result rows
     of a reservoir sample of the window's queries drawn from the seed
@@ -156,9 +213,7 @@ class Recorder:
                 return
             del self.rows[self._kept[j]]
             self._kept[j] = qid
-        # kept in the dtype delivered: a narrowing cast could wrap a wrong
-        # level (say an int32 sentinel for unreached) onto a right one
-        self.rows[qid] = np.array(levels)
+        self.rows[qid] = narrow(levels)
 
     @property
     def undelivered(self) -> int:
@@ -203,7 +258,7 @@ class LoadGen:
             return False
         return True
 
-    def warm_up(self) -> int:
+    def warm_up(self, reached=lambda stage: None) -> int:
         """Serve warm-up queries back to back until the first threshold
         refit has run (``REFIT_EVERY`` batches) and one batch has been
         served under it, which builds the engines it asks for: a server
@@ -212,10 +267,13 @@ class LoadGen:
         sources are queued; a 64-source query is one 64-lane morsel), so
         that compiles every pow2 morsel count the window pools. Later
         refits, and the compiles they cause, fall in the window as the
-        system's own behaviour and are counted there."""
+        system's own behaviour and are counted there. ``reached`` is told
+        each query's number; the first places the operands."""
         disp = self.loop.dispatcher
         n, after_refit = 0, 0
         while after_refit < 1:
+            reached(f"warm-up query {n}"
+                    + (" (places the operands)" if n == 0 else ""))
             after_refit += disp.stats.refits >= 1
             self.submit(f"warm{n}", self.rec.clock(), keep=False)
             while self.step():
@@ -300,6 +358,17 @@ def build_loadgen(cell: Cell, seed: int
     return LoadGen(loop, recorder, warm_deck, deck), src, dst
 
 
+def program_counters(loop) -> dict:
+    """The program's counters that per-layer readers take, by name; one
+    that this program does not have is left out."""
+    disp = loop.dispatcher
+    owners = {"queue_wait_s": loop.stats, "dispatched_queries": loop.stats,
+              "compile_s": disp.cache, "d2h_prefetched": disp.stats,
+              "d2h_blocking": disp.stats}
+    return {name: getattr(owner, name) for name, owner in owners.items()
+            if hasattr(owner, name)}
+
+
 def _memory_peak(devices) -> int:
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
              for d in devices]
@@ -308,12 +377,16 @@ def _memory_peak(devices) -> int:
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
              t_start: float, require_chip: bool = True,
-             trace_dir: Path | None = None, control: bool = False) -> dict:
+             trace_dir: Path | None = None, control: bool = False,
+             watchdog: SetupWatchdog | None = None) -> dict:
     """One run of one cell; returns the result object (see ``run.py``).
 
     ``control`` also reads the control (``oracle.CONTROL_ROW_CAP``) in the
     program's place on the same compared queries, under ``control`` beside
-    ``checks``; the benchmark's own runs never do."""
+    ``checks``; the benchmark's own runs never do. ``watchdog`` is told the
+    set-up's stages and disarmed as the window opens."""
+    reached = watchdog.reached if watchdog else lambda stage: None
+    reached("devices")
     if require_chip:
         devices = require_devices(cell.chips)
     import jax
@@ -330,9 +403,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     closed = mix["kind"] == "closed"
     offsets = (None if closed
                else traffic.arrival_offsets(mix, seconds, seed))
+    reached("graph")
     gen, src, dst = build_loadgen(cell, seed)
     loop, recorder = gen.loop, gen.rec
-    n_warm = gen.warm_up()
+    n_warm = gen.warm_up(reached)
     disp = loop.dispatcher
     log(f"warm-up: {n_warm} queries, {loop.stats.batches} batches, "
         f"{disp.stats.refits} refits, {disp.cache.compile_events} compile "
@@ -340,12 +414,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     traced = trace_dir is not None and trace
     if traced:
+        reached("profiler start")
         shutil.rmtree(trace_dir, ignore_errors=True)
         jax.profiler.start_trace(str(trace_dir))
     batches0 = loop.stats.batches
     compiles0 = disp.cache.compile_events
     refits0 = disp.stats.refits
+    program = {"open": program_counters(loop)}
     setup_s = clock() - t_start
+    if watchdog:
+        watchdog.disarm()
     if closed:
         t0 = gen.closed_window(int(mix.get("clients", 1)), seconds)
     else:
@@ -354,6 +432,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     batches_w = loop.stats.batches - batches0
     compiles_w = disp.cache.compile_events - compiles0
     refits_w = disp.stats.refits - refits0
+    program["close"] = program_counters(loop)
     gen.finish(close + LATE_WAIT_S)
     if traced:
         jax.profiler.stop_trace()
@@ -393,8 +472,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                 for q in done_in_window)
     missing = [q for q in window_q if q not in delivered]
     compared = list(rows)
-    wrong = {q: oracle.mismatches(reference, gen.queries[q], rows[q])
-             for q in compared}
+    compared_sources = [gen.queries[q] for q in compared]
+    wrong = dict(zip(compared, oracle.mismatches_by_query(
+        reference, compared_sources, [rows[q] for q in compared])))
     checks = {
         "mismatched_levels": {"value": int(sum(wrong.values())), "limit": 0},
         "missing_results": {"value": len(missing), "limit": 0},
@@ -404,24 +484,22 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     if control:
         capped = oracle.Reference(n_nodes, src, dst,
                                   row_cap=oracle.CONTROL_ROW_CAP)
-        control_reading = sum(
-            oracle.mismatches(reference, gen.queries[q],
-                              [capped.bfs(int(s)) for s in gen.queries[q]])
-            for q in compared)
+        control_reading = oracle.control_mismatches(reference, capped,
+                                                    compared_sources)
         log(f"control: mismatched_levels {control_reading} (limit 0)")
     log(f"window: {len(latencies)} latencies, p50 "
         f"{stats.percentile(latencies, 50)} ms, p90 "
         f"{stats.percentile(latencies, 90)} ms; {edges} traversed edges in "
         f"{len(done_in_window)} queries delivered in the window")
-    log(f"compared {sum(len(gen.queries[q]) for q in compared)} source "
-        f"rows of {len(compared)} queries with the reference")
+    log(f"compared {sum(len(s) for s in compared_sources)} source rows "
+        f"of {len(compared)} queries with the reference")
 
     ctx = {
         "cell": cell.name, "seconds": seconds, "setup_s": setup_s,
         "latencies_ms": latencies, "completed": len(done_in_window),
         "source_rows": source_rows, "traversed_edges": edges,
         "batches": batches_w, "compiles": compiles_w, "refits": refits_w,
-        "trace": None,
+        "program": program, "trace": None,
     }
     device = {"platform": devices[0].platform, "kind": kind,
               "count": cell.chips, "memory_peak_bytes": memory_peak}
@@ -430,8 +508,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
               "metrics": {}, "device": device}
     if traced:
         path = trace_reduce.find_xplane(str(trace_dir))
-        reduced = (trace_reduce.reduce_events(
-            trace_reduce.load_events(path), cell.chips) if path else None)
+        reduced = program_spans.report(path, cell.chips) if path else None
         if reduced is None:
             raise RuntimeError(f"no device operations in the trace at "
                                f"{trace_dir}")

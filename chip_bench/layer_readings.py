@@ -19,3 +19,19 @@ def idle_share(ctx: dict) -> float | None:
     if trace is None or trace["window_s"] <= 0:
         return None
     return 1.0 - trace["busy_s"] / trace["window_s"]
+
+
+def span_ms_per_batch(ctx: dict, part: str) -> float | None:
+    """``program_spans.per_batch_ms``'s reading ``part`` of the traced
+    window: milliseconds a batch the program's spans took."""
+    trace = ctx["trace"]
+    return None if trace is None else trace["per_batch_ms"].get(part)
+
+
+def window_delta(ctx: dict, counter: str) -> float | None:
+    """A program counter's rise over the window; None where the program
+    does not have it."""
+    program = ctx["program"]
+    if counter not in program["open"] or counter not in program["close"]:
+        return None
+    return program["close"][counter] - program["open"][counter]

@@ -8,7 +8,9 @@ The last line of standard output is one JSON object: ``correct``,
 with ``--trace 1`` its per-layer metrics), ``device``, with ``--trace 1``
 ``breakdown``, and last ``checks``: each number compared with the
 reference beside its limit. With no TPU, or fewer chips than the cell asks
-for, it exits non-zero and prints no result.
+for, it exits non-zero and prints no result; so it does when the window has
+not opened ``harness.SETUP_DEADLINE_S`` seconds after the process started,
+after naming the set-up stage it reached.
 """
 import time
 
@@ -33,10 +35,12 @@ def main(argv=None) -> int:
 
     from chip_bench import harness
 
+    watchdog = harness.SetupWatchdog(harness.SETUP_DEADLINE_S, T_START)
+    watchdog.reached("cell")
     cell = harness.load_cell(harness.load_benchmark(ROOT), args.workload, ROOT)
     result = harness.run_cell(
         cell, args.seed, args.seconds, bool(args.trace), T_START,
-        trace_dir=ROOT / ".bench_trace" / cell.name,
+        trace_dir=ROOT / ".bench_trace" / cell.name, watchdog=watchdog,
     )
     print(json.dumps(result), flush=True)
     return 0

@@ -59,7 +59,12 @@ def test_new_files_are_found_by_name(root_with_new_cell):
 @pytest.mark.parametrize("cell,e2e,layer", [
     ("ldbc-64src-closed", {"setup_s", "edges_per_s"},
      {"sources_per_batch.tput", "compiles_in_window.tput",
-      "device_ms_per_source.tput", "device_idle_share.tput"}),
+      "device_ms_per_source.tput", "device_idle_share.tput",
+      "admission_ms_per_batch.tput", "dispatch_host_ms_per_batch.tput",
+      "device_wait_ms_per_batch.tput", "refit_ms_per_batch.tput",
+      "finalize_ms_per_batch.tput", "idle_in_program_share.tput",
+      "queue_wait_ms_per_query.tput", "setup_compile_s.tput",
+      "d2h_blocking_per_batch.tput"}),
 ])
 def test_committed_cells_resolve_every_piece(cell, e2e, layer):
     c = harness.load_cell(harness.load_benchmark(), cell)
@@ -79,10 +84,19 @@ def test_unknown_names_are_errors():
         harness.load_reader("metrics", "no_such_metric")
 
 
+COUNTERS = {"queue_wait_s": 0.5, "dispatched_queries": 10,
+            "compile_s": 0.25, "d2h_prefetched": 40, "d2h_blocking": 0}
 CTX = {"seconds": 50.0, "setup_s": 12.5, "latencies_ms": [3.0, 1.0, 2.0],
        "completed": 3, "source_rows": 192, "traversed_edges": 1000,
        "batches": 3, "compiles": 0,
-       "trace": {"busy_s": 0.5, "window_s": 2.0}}
+       "program": {"open": COUNTERS, "close": dict(
+           COUNTERS, queue_wait_s=0.53, dispatched_queries=13,
+           d2h_prefetched=52)},
+       "trace": {"busy_s": 0.5, "window_s": 2.0, "batches": 3,
+                 "idle_in_program_s": 1.25,
+                 "per_batch_ms": {"admission": 1.25, "dispatch_host": 1.75,
+                                  "refit": 0.75, "device_wait": 2.5,
+                                  "finalize": 0.5}}}
 
 
 @pytest.mark.parametrize("path", sorted(
