@@ -59,6 +59,8 @@ sys.path.insert(0, str(Path(__file__).parent.parent / "src"))
 
 from repro.core.edge_compute import EDGE_COMPUTES  # noqa: E402
 from repro.core.extend import (  # noqa: E402
+    BEAMER_ALPHA,
+    BEAMER_BETA,
     ExtendCtx,
     ExtendSpec,
     GraphOperands,
@@ -98,12 +100,13 @@ def _wall_ms(fn, *args, reps: int = 3) -> float:
 
 
 def _use_pull_host(spec, fwd_deg, frontier, visited, n) -> bool:
-    """Host replica of extend.AutoBackend's alpha/beta predicate."""
+    """Host replica of extend.AutoBackend's alpha/beta predicate (under
+    Beamer's pair, which the engines run when no table is served)."""
     act = frontier != 0
     n_f = float(act.sum())
     m_f = float(fwd_deg[act].sum())
     m_u = float(fwd_deg[~(visited != 0)].sum())
-    return bool((m_f * spec.alpha > m_u) and (n_f * spec.beta > n))
+    return bool((m_f * BEAMER_ALPHA > m_u) and (n_f * BEAMER_BETA > n))
 
 
 #: one row-padding unit for every operand bundle the bench builds — the
@@ -152,9 +155,9 @@ def run_backend(
         contribution = ec.extend(be, ops, state, ctx)
         return ec.apply(state, contribution, it)
 
-    fwd_slots = int(np.prod(full_ops.fwd.indices.shape))
+    fwd_slots = full_ops.fwd.slots
     rev_row_w = int(full_ops.rev.indices.shape[1])
-    fwd_deg = np.asarray(full_ops.fwd.degrees)
+    fwd_deg = np.asarray(full_ops.fwd.degrees).reshape(-1)
     # per-row binned slab widths + true in-degrees: the binned-pull scan
     # cost of one iteration is the widths of the still-unvisited rows
     # (the uncapped reverse ELL's degree vector IS the true in-degrees)
@@ -551,8 +554,8 @@ def main(argv=None) -> int:
             "bench": "direction_opt",
             "schema_version": SCHEMA_VERSION,
             "smoke": bool(args.smoke),
-            "alpha": spec.alpha,
-            "beta": spec.beta,
+            "alpha": BEAMER_ALPHA,
+            "beta": BEAMER_BETA,
             "block": spec.block,
             "backend_list": list(BACKENDS),
             "jax": jax.__version__,

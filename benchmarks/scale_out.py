@@ -28,10 +28,10 @@ pack):
   operands, compared across the two modes — the streamed build must be
   bit-identical, not just close;
 - **chunked-hub oracle**: on a hub graph whose widest binned slab blows
-  any reasonable gather budget, the degree-chunked slab gathers
-  (``_slab_gather_lanes`` / ``_slab_min_parent_lanes``) under an
-  artificially tiny ``_deg_chunk`` budget must match the unchunked
-  gather bit-for-bit.
+  any reasonable gather budget, the binned pull's stepped scan
+  (``BinnedPullBackend._reach_lanes`` / ``_min_parent_lanes``) under an
+  artificially tiny ``_deg_chunk`` budget must match its one-step scan
+  bit-for-bit.
 
 Floors (asserted in-process and by ``scripts/ci.sh --bench-smoke``):
 streamed tracemalloc peak strictly below wholesale, digests identical,
@@ -225,6 +225,7 @@ def chunked_hub_oracle(forced_budget: int = 4096) -> dict:
 
     import jax.numpy as jnp
 
+    import repro.core.edge_compute as EC
     import repro.core.extend as E
     from repro.graph.csr import csr_from_edges
 
@@ -243,29 +244,23 @@ def chunked_hub_oracle(forced_budget: int = 4096) -> dict:
         (rng.random((n_pad, L)) < 0.3).astype(np.uint8)
     )
 
+    ctx = E.ExtendCtx(n_out=n_pad)
+
     def run():
-        reach = E._binned_map(
-            bn, lambda b, s: E._slab_gather_lanes(s, gl),
-            lambda r: jnp.zeros((r, L), gl.dtype),
-        )
-        par = E._binned_map(
-            bn, lambda b, s: E._slab_min_parent_lanes(s, gl),
-            lambda r: jnp.full((r, L), E.NO_PARENT, jnp.int32),
-        )
+        reach = E.BinnedPullBackend._reach_lanes(ops, gl, None, ctx)
+        par = E.BinnedPullBackend._min_parent_lanes(ops, gl, None, ctx)
         return np.asarray(reach), np.asarray(par)
 
     ref_reach, ref_par = run()
-    orig = E._deg_chunk
+    orig = EC._deg_chunk
     try:
-        E._deg_chunk = lambda rows, per_slot, budget=0: orig(
+        EC._deg_chunk = lambda rows, per_slot, budget=0: orig(
             rows, per_slot, forced_budget
         )
-        forced_chunk = E._deg_chunk(
-            int(bn.slabs[-1].shape[-2]), L
-        )
+        forced_chunk = EC._deg_chunk(1, L)
         got_reach, got_par = run()
     finally:
-        E._deg_chunk = orig
+        EC._deg_chunk = orig
     return {
         "hub_width": int(max(widths)),
         "forced_chunk": int(forced_chunk),

@@ -43,6 +43,8 @@ from ..graph.csr import CSRGraph, EllGraph, ShardedBlocks
 from .collectives import gang_merge_scatter, merge_contribution, merge_scatter
 from .edge_compute import EDGE_COMPUTES
 from .extend import (
+    BEAMER_ALPHA,
+    BEAMER_BETA,
     STATS_WIDTH,
     ExtendCtx,
     ExtendSpec,
@@ -71,14 +73,42 @@ def _flat_axis_index(axes: tuple[str, ...]):
     return idx
 
 
-def _name_program(worker, kind: str, policy: MorselPolicy,
-                  spec: ExtendSpec) -> None:
-    """Name an engine's program ``engine_<kind>_<policy>_<backend>``: the
-    jit takes the worker's name, so the profiler's ``XLA Modules`` line
-    tells the phase-1, resume, gang and static engines apart."""
+def _engine_program(worker, kind: str, policy: MorselPolicy,
+                    spec: ExtendSpec, mesh: Mesh, arg_specs: tuple,
+                    out_specs):
+    """``jit(shard_map(worker))`` over ``(operands, *args, thresholds)``.
+
+    Every operand leaf shards its leading (row / stacked-shard) axis over
+    the policy's graph axes and replicates the rest; the in_specs are
+    derived from the bundle's structure when the program traces, so one
+    builder serves any bundle (binned slab counts are graph-dependent).
+    ``arg_specs`` are the specs of the engine's other inputs; the
+    direction pair ``thresholds`` (float32 ``[2]``) is replicated.
+
+    The program is named ``engine_<kind>_<policy>_<backend>``: the jit
+    takes that name, so the profiler's ``XLA Modules`` line tells the
+    phase-1, resume, gang and static engines apart."""
+    ga = policy.graph_axes
+    row_leaf = lambda x: P(ga if ga else None, *(None,) * (x.ndim - 1))
+
+    def program(graph, *args):
+        return jax.shard_map(
+            worker,
+            mesh=mesh,
+            in_specs=(jax.tree.map(row_leaf, graph), *arg_specs, P()),
+            out_specs=out_specs,
+            check_vma=False,
+        )(graph, *args)
+
     backend = (f"dopt_{spec.pull}" if spec.direction == "auto"
                else spec.backend)
-    worker.__name__ = f"engine_{kind}_{policy.name.lower()}_{backend}"
+    program.__name__ = f"engine_{kind}_{policy.name.lower()}_{backend}"
+    return jax.jit(program)
+
+
+def default_thresholds() -> jax.Array:
+    """Beamer's (alpha, beta) as the engines' float32 pair operand."""
+    return jnp.asarray((BEAMER_ALPHA, BEAMER_BETA), jnp.float32)
 
 
 def pad_sources(
@@ -109,23 +139,23 @@ class QueryEngine:
     extend: ExtendSpec = ExtendSpec()
 
     def _coerce(self, graph):
-        """Accept an EllGraph or any GraphOperands bundle and hand ``fn``
-        exactly the operand structure its in_specs declare (push engines
-        keep the historical bare-EllGraph calling convention)."""
+        """Accept bare forward slabs or any GraphOperands bundle and hand
+        ``fn`` exactly the operand structure this engine scans."""
         return strip_operands(self.extend, as_operands(graph))
 
-    def __call__(self, graph, *args) -> IFEResult:
+    def __call__(self, graph, *args, thresholds=None) -> IFEResult:
         """Static/phase-1 engines: ``engine(graph, source_morsels)``.
-        Resume engines: ``engine(graph, state0, it0)``."""
-        return self.fn(self._coerce(graph), *args)
+        Resume engines: ``engine(graph, state0, it0)``. ``thresholds``:
+        the direction switch's ``(alpha, beta)`` for this launch (None =
+        Beamer's); an operand, so every pair runs the same program."""
+        pair = (default_thresholds() if thresholds is None
+                else jnp.asarray(thresholds, jnp.float32))
+        return self.fn(self._coerce(graph), *args, pair)
 
 
-def strip_operands(spec: ExtendSpec, ops: GraphOperands):
-    """Exactly the operands ``spec`` scans (push engines keep the
-    historical bare-EllGraph calling convention) — the structure shard_map
-    in_specs are derived from, so treedefs always match."""
-    if not (spec.needs_rev or spec.needs_binned or spec.needs_blocks):
-        return ops.fwd
+def strip_operands(spec: ExtendSpec, ops: GraphOperands) -> GraphOperands:
+    """Exactly the operands ``spec`` scans — the structure the engines'
+    shard_map in_specs are derived from, so treedefs always match."""
     if spec.needs_rev and ops.rev is None:
         raise ValueError(
             f"engine extend={spec.backend}/{spec.direction} needs reverse "
@@ -159,44 +189,6 @@ def strip_operands(spec: ExtendSpec, ops: GraphOperands):
     )
 
 
-def _operand_specs(spec: ExtendSpec, ga: tuple[str, ...], operands=None):
-    """shard_map in_specs for the operand bundle an engine scans.
-
-    Every operand leaf shards its leading (row / stacked-shard) axis over
-    the graph axes and replicates the rest. When the actual ``operands``
-    bundle is given the spec pytree is derived from its stripped
-    structure leaf-by-leaf — required for binned slabs, whose bucket
-    count (treedef) is graph-dependent; the hand-built fallback keeps the
-    historical operand-free ``build_engine`` calling convention alive for
-    specs with graph-independent treedefs."""
-    row_leaf = lambda x: P(ga if ga else None, *(None,) * (x.ndim - 1))
-    if operands is not None:
-        return jax.tree.map(row_leaf, strip_operands(spec, as_operands(operands)))
-    if spec.needs_binned:
-        raise ValueError(
-            "binned-pull engines need the operand bundle to derive "
-            "shard_map specs (slab count is graph-dependent); pass "
-            "operands=... to build_engine/build_resume_engine"
-        )
-    ell = EllGraph(
-        indices=P(ga if ga else None, None),
-        degrees=P(ga if ga else None),
-        weights=None,
-    )
-    if not (spec.needs_rev or spec.needs_blocks):
-        return ell
-    blocks = None
-    if spec.needs_blocks:
-        blocks = ShardedBlocks(
-            blocks=P(ga if ga else None, None, None, None),
-            block_rows=P(ga if ga else None, None),
-            block_cols=P(ga if ga else None, None),
-        )
-    return GraphOperands(
-        fwd=ell, rev=ell if spec.needs_rev else None, blocks=blocks
-    )
-
-
 def _stats_bin_widths(ops: GraphOperands):
     """Per-local-row binned slab widths for the stats tap's pull-cost
     columns, derived from the CALL-TIME operands (inv is data, not shape:
@@ -206,8 +198,7 @@ def _stats_bin_widths(ops: GraphOperands):
         return None
     bn = ops.rev_binned
     wvec = jnp.concatenate([
-        jnp.full((s.shape[-2],), s.shape[-1], jnp.float32)
-        for s in bn.slabs
+        jnp.full((r,), w, jnp.float32) for r, w in bn.shapes
     ])  # slab width per binned position (this shard's slice)
     return wvec[bn.inv[0]]
 
@@ -221,13 +212,11 @@ def build_engine(
     state_layout: str = "replicated",
     sync: str = "global",
     extend="ell_push",
-    operands=None,
     collect_stats: bool = False,
 ) -> QueryEngine:
-    """``operands``: the graph's GraphOperands bundle (or any graph whose
-    stripped structure matches what the engine will be called with). Needed
-    to derive shard_map specs for graph-dependent operand treedefs (binned
-    pull slabs); optional for the other backends.
+    """The engine's ``fn(operands, source_morsels, thresholds)`` shards
+    the operand bundle it is called with over the policy's graph axes
+    (``_engine_program``); ``QueryEngine.__call__`` fills ``thresholds``.
 
     ``collect_stats``: the online-policy sample tap. The engine's fn
     returns ``(IFEResult, stats)`` where ``stats[m, cap, STATS_WIDTH]``
@@ -304,10 +293,10 @@ def build_engine(
     )
     scatter_impl = "allgather" if divergent else "ring"
 
-    def worker(graph_in, sources_local: jax.Array):
+    def worker(graph_in, sources_local: jax.Array, thresholds):
         ops = as_operands(graph_in)
-        be = make_backend(spec)
-        rows_local = ops.fwd.indices.shape[0]
+        be = make_backend(spec, thresholds)
+        rows_local = ops.fwd.rows_local
         offset = (
             _flat_axis_index(ga) * rows_local if ga else None
         )
@@ -374,7 +363,6 @@ def build_engine(
 
         return lax.map(one_morsel, sources_local)
 
-    g_specs = _operand_specs(spec, ga, operands)
     src_spec = P(sa if sa else None, None)
     if sharded:
         # state rows live on the graph axes: leaves are [morsel, rows, ...]
@@ -393,16 +381,9 @@ def build_engine(
     if collect_stats:
         # stats stack over morsels like iterations: [m, cap, STATS_WIDTH]
         out_spec = (out_spec, P(sa if sa else None))
-    _name_program(worker, "phase1" if sync == "shard" else "static", policy,
-                  spec)
-    fn = jax.jit(
-        jax.shard_map(
-            worker,
-            mesh=mesh,
-            in_specs=(g_specs, src_spec),
-            out_specs=out_spec,
-            check_vma=False,
-        )
+    fn = _engine_program(
+        worker, "phase1" if sync == "shard" else "static", policy, spec,
+        mesh, (src_spec,), out_spec,
     )
     return QueryEngine(
         mesh=mesh,
@@ -422,7 +403,6 @@ def build_resume_engine(
     n_nodes_padded: int,
     max_iters: int | None = None,
     extend="ell_push",
-    operands=None,
     collect_stats: bool = False,
 ) -> QueryEngine:
     """Phase-2 (re-dispatch) engine of the adaptive hybrid.
@@ -443,7 +423,8 @@ def build_resume_engine(
     (``it``, which starts at ``it0``), so rows below ``it0`` stay zero
     and phase-1/phase-2 samples for a morsel never collide.
 
-    The returned engine's ``fn`` signature is ``fn(graph, state0, it0)``.
+    The returned engine's ``fn`` signature is
+    ``fn(graph, state0, it0, thresholds)``.
     """
     ec = EDGE_COMPUTES[edge_compute]
     spec = as_spec(extend)
@@ -457,10 +438,10 @@ def build_resume_engine(
     cap = int(max_iters if max_iters is not None else n_nodes_padded)
     sync_axes = tuple(ga)
 
-    def worker(graph_in, state0, it0):
+    def worker(graph_in, state0, it0, thresholds):
         ops = as_operands(graph_in)
-        be = make_backend(spec)
-        rows_local = ops.fwd.indices.shape[0]
+        be = make_backend(spec, thresholds)
+        rows_local = ops.fwd.rows_local
         offset = _flat_axis_index(ga) * rows_local if ga else None
         ctx = ExtendCtx(
             n_out=n_nodes_padded,
@@ -507,22 +488,13 @@ def build_resume_engine(
 
         return lax.map(one_morsel, (state0, it0))
 
-    g_specs = _operand_specs(spec, ga, operands)
     # state/it0 replicated in, outputs replicated (post-merge state is
     # identical on every device of the graph group)
     out_spec = IFEResult(state=P(), iterations=P())
     if collect_stats:
         out_spec = (out_spec, P())
-    _name_program(worker, "resume", policy, spec)
-    fn = jax.jit(
-        jax.shard_map(
-            worker,
-            mesh=mesh,
-            in_specs=(g_specs, P(), P()),
-            out_specs=out_spec,
-            check_vma=False,
-        )
-    )
+    fn = _engine_program(worker, "resume", policy, spec, mesh, (P(), P()),
+                         out_spec)
     return QueryEngine(
         mesh=mesh,
         policy=policy,
@@ -541,7 +513,6 @@ def build_gang_resume_engine(
     n_nodes_padded: int,
     max_iters: int | None = None,
     extend="ell_push",
-    operands=None,
     state_layout: str = "replicated",
     collect_stats: bool = False,
 ) -> QueryEngine:
@@ -583,7 +554,8 @@ def build_gang_resume_engine(
     left untouched, so the gang tap is sample-identical to draining the
     survivors one-at-a-time through the serial resume tap.
 
-    The returned engine's ``fn`` signature is ``fn(graph, state0, it0)``.
+    The returned engine's ``fn`` signature is
+    ``fn(graph, state0, it0, thresholds)``.
     """
     ec = EDGE_COMPUTES[edge_compute]
     spec = as_spec(extend)
@@ -599,10 +571,10 @@ def build_gang_resume_engine(
     sharded = state_layout == "sharded" and bool(ga)
     sync_axes = tuple(ga)
 
-    def worker(graph_in, state0, it0):
+    def worker(graph_in, state0, it0, thresholds):
         ops = as_operands(graph_in)
-        be = make_backend(spec)
-        rows_local = ops.fwd.indices.shape[0]
+        be = make_backend(spec, thresholds)
+        rows_local = ops.fwd.rows_local
         offset = _flat_axis_index(ga) * rows_local if ga else None
         ctx = ExtendCtx(
             n_out=n,
@@ -669,7 +641,6 @@ def build_gang_resume_engine(
         res = IFEResult(state=carry[0], iterations=carry[1])
         return (res, carry[2]) if collect_stats else res
 
-    g_specs = _operand_specs(spec, ga, operands)
     if sharded:
         # state rows live on the graph axes: leaves are [gang, rows, ...]
         lanes = getattr(ec, "LANES", 0)
@@ -684,16 +655,8 @@ def build_gang_resume_engine(
         in_state, out_spec = P(), IFEResult(state=P(), iterations=P())
     if collect_stats:
         out_spec = (out_spec, P())
-    _name_program(worker, "gang", policy, spec)
-    fn = jax.jit(
-        jax.shard_map(
-            worker,
-            mesh=mesh,
-            in_specs=(g_specs, in_state, P()),
-            out_specs=out_spec,
-            check_vma=False,
-        )
-    )
+    fn = _engine_program(worker, "gang", policy, spec, mesh,
+                         (in_state, P()), out_spec)
     return QueryEngine(
         mesh=mesh,
         policy=policy,
@@ -716,8 +679,9 @@ def prepare_graph(
     stream: bool | None = None,
 ) -> tuple[GraphOperands, int]:
     """Host-side: CSR → padded, device-placed extension operands for this
-    policy's mesh: the forward ELL always, plus the reverse ELL, the
-    degree-binned reverse slabs, and/or the per-shard block adjacency when
+    policy's mesh: the degree-binned forward slabs always, plus the
+    reverse ELL, the degree-binned reverse slabs, and/or the per-shard
+    block adjacency when
     the ``extend`` spec scans them (all derived from the same truncated
     edge set — backend bit-parity).
 
@@ -777,18 +741,18 @@ def prepare_graph(
     leaf_sharding = lambda x: NamedSharding(
         mesh, P(ga if ga else None, *(None,) * (x.ndim - 1))
     )
+    # binned slabs stack over the policy's shards on axis 0
+    put_stacked = lambda st: jax.tree.map(
+        lambda x: jax.device_put(x, leaf_sharding(x)), st
+    )
+    assert ops.fwd.rows_local * k_shards == n_pad, (
+        ops.fwd.rows_local, k_shards)
+    fwd = put_stacked(ops.fwd)
     if ops.rev_binned is not None:
-        bn = ops.rev_binned
-        assert bn.rows_local * k_shards == n_pad, (bn.rows_local, k_shards)
-        rev_binned = jax.tree.map(
-            lambda x: jax.device_put(x, leaf_sharding(x)), bn
-        )
+        rev_binned = put_stacked(ops.rev_binned)
     if ops.rev_binned_pack is not None:
         # same stacked-shard leading-axis layout as the jnp slabs
-        rev_binned_pack = jax.tree.map(
-            lambda x: jax.device_put(x, leaf_sharding(x)),
-            ops.rev_binned_pack,
-        )
+        rev_binned_pack = put_stacked(ops.rev_binned_pack)
     blocks = None
     if ops.blocks is not None:
         sb = ops.blocks
@@ -816,7 +780,7 @@ def prepare_graph(
             ),
         )
     ops = GraphOperands(
-        fwd=put_ell(ops.fwd),
+        fwd=fwd,
         rev=None if ops.rev is None else put_ell(ops.rev),
         rev_binned=rev_binned,
         rev_binned_pack=rev_binned_pack,
@@ -929,6 +893,6 @@ def run_recursive_query(
     )
     engine = build_engine(
         mesh, policy, edge_compute, n_pad, max_iters,
-        state_layout=state_layout, extend=spec, operands=g,
+        state_layout=state_layout, extend=spec,
     )
     return engine(g, morsels)

@@ -30,8 +30,9 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from ..graph.csr import EllGraph
+from ..graph.csr import BinnedEll, EllGraph
 
 INF_U8 = jnp.uint8(255)
 NO_PARENT = jnp.int32(2**31 - 1)
@@ -53,12 +54,12 @@ def _gang_unpack(y: jax.Array, gang: int, lanes: int = 0) -> jax.Array:
 # Extension primitives over ELL (pure jnp; Pallas kernels mirror these).
 # ---------------------------------------------------------------------------
 
-def _local_rows(frontier: jax.Array, g: EllGraph, row_offset) -> jax.Array:
-    """Slice the global per-node array down to this graph shard's rows."""
-    rows = g.indices.shape[0]
+def _local_rows(x: jax.Array, rows: int, row_offset) -> jax.Array:
+    """This shard's ``rows`` rows of a global per-node array (identity when
+    the array is already local: sharded layout or a single shard)."""
     if row_offset is None:
-        return frontier
-    return jax.lax.dynamic_slice_in_dim(frontier, row_offset, rows, axis=0)
+        return x
+    return jax.lax.dynamic_slice_in_dim(x, row_offset, rows, axis=0)
 
 
 def ell_reach_dense(
@@ -75,7 +76,7 @@ def ell_reach_dense(
     always global-[n_out]-sized (padding sentinel drops).
     """
     n = frontier.shape[0] if n_out is None else n_out
-    local_f = _local_rows(frontier, g, row_offset)
+    local_f = _local_rows(frontier, g.indices.shape[0], row_offset)
     contrib = jnp.broadcast_to(local_f[:, None], g.indices.shape)
     out = jnp.zeros((n,), dtype=jnp.bool_)
     return out.at[g.indices].max(contrib, mode="drop")
@@ -88,7 +89,7 @@ def _deg_chunk(rows: int, width: int, budget: int = 2 << 30) -> int:
 
     Returns the largest power of two that fits the budget, so the chunk
     divides every pow2-padded slab width exactly. Widths that are NOT a
-    chunk multiple (the forward ELL pads to a multiple of 8, not a pow2;
+    chunk multiple (a padded ELL pads to a multiple of 8, not a pow2;
     refined degree buckets can have arbitrary widths) are handled by
     ``chunk_fold``'s static remainder tail — the historical round-to-8
     chunk could land on e.g. 24 against a 32-wide slab and trip the
@@ -149,7 +150,7 @@ def ell_reach_lanes(
     lanes). Layout contract as in ``ell_reach_dense``."""
     L = lanes.shape[-1]
     n = lanes.shape[0] if n_out is None else n_out
-    local = _local_rows(lanes, g, row_offset)
+    local = _local_rows(lanes, g.indices.shape[0], row_offset)
     out = jnp.zeros((n, L), dtype=jnp.uint8)
     chunk = _deg_chunk(local.shape[0], L)
     return _chunked_scatter(g, out, local, chunk, "max")
@@ -164,7 +165,8 @@ def ell_min_dist(
     w = g.weights if g.weights is not None else jnp.ones_like(
         g.indices, dtype=jnp.float32
     )
-    du = _local_rows(jnp.where(frontier, dist, jnp.inf), g, row_offset)
+    du = _local_rows(jnp.where(frontier, dist, jnp.inf), g.indices.shape[0],
+                     row_offset)
     cand = du[:, None] + w
     out = jnp.full((n,), jnp.inf, dtype=jnp.float32)
     return out.at[g.indices].min(cand, mode="drop")
@@ -180,7 +182,7 @@ def ell_push_sum(
     computes, restricted to this shard's rows; padding rows/slots carry the
     sentinel index and drop. Layout contract as in ``ell_reach_dense``."""
     n = values.shape[0] if n_out is None else n_out
-    vloc = _local_rows(values, g, row_offset)
+    vloc = _local_rows(values, g.indices.shape[0], row_offset)
     if normalize:
         vloc = vloc / jnp.maximum(g.degrees, 1).astype(vloc.dtype)
     out = jnp.zeros((n, 1), vloc.dtype)
@@ -228,9 +230,8 @@ def ell_min_topk(
     return chunk_fold(D, chunk, step, acc0)
 
 
-def _row_ids(g: EllGraph, row_offset, row_base) -> jax.Array:
-    """Global node ids of this shard's rows (after any slicing)."""
-    rows = g.indices.shape[0]
+def _row_ids(rows: int, row_offset, row_base) -> jax.Array:
+    """Global node ids of this shard's ``rows`` rows (after any slicing)."""
     base = row_offset if row_offset is not None else row_base
     ids = jnp.arange(rows, dtype=jnp.int32)
     return ids if base is None else ids + base
@@ -243,8 +244,10 @@ def ell_min_parent(
     """cand_parent[v] = min active u with edge u->v (NO_PARENT if none).
     ``row_base``: global id of the first local row (sharded layout)."""
     n = frontier.shape[0] if n_out is None else n_out
-    local_f = _local_rows(frontier, g, row_offset)
-    cand = jnp.where(local_f, _row_ids(g, row_offset, row_base), NO_PARENT)
+    rows = g.indices.shape[0]
+    local_f = _local_rows(frontier, rows, row_offset)
+    cand = jnp.where(local_f, _row_ids(rows, row_offset, row_base),
+                     NO_PARENT)
     cand = jnp.broadcast_to(cand[:, None], g.indices.shape)
     out = jnp.full((n,), NO_PARENT, jnp.int32)
     return out.at[g.indices].min(cand, mode="drop")
@@ -256,12 +259,144 @@ def ell_min_parent_lanes(
     """Per-lane min-parent: [*, L] uint8 -> [n_out, L] int32."""
     L = lanes.shape[-1]
     n = lanes.shape[0] if n_out is None else n_out
-    local = _local_rows(lanes, g, row_offset)
-    u_ids = _row_ids(g, row_offset, row_base)[:, None]
+    rows = g.indices.shape[0]
+    local = _local_rows(lanes, rows, row_offset)
+    u_ids = _row_ids(rows, row_offset, row_base)[:, None]
     cand_row = jnp.where(local != 0, u_ids, NO_PARENT)
     out = jnp.full((n, L), NO_PARENT, jnp.int32)
     chunk = _deg_chunk(local.shape[0], 4 * L)
     return _chunked_scatter(g, out, cand_row, chunk, "min")
+
+
+# ---------------------------------------------------------------------------
+# Scans of degree-binned slabs: the served push (forward slabs) and the
+# binned pull's gathers (reverse slabs).
+# ---------------------------------------------------------------------------
+
+
+#: slots one step of a binned scan reads and writes. A TPU gather or
+#: scatter compiles in time that grows with its update count (tens of
+#: seconds for a few hundred thousand 64-lane rows); a loop of fixed-size
+#: steps compiles one step, whatever the graph's size
+SCAN_SLOTS = 1 << 14
+
+
+def binned_scan(bn: BinnedEll, src: jax.Array, out: jax.Array,
+                reducer: str, fill, *, pull: bool = False,
+                value=None, slot_add=None) -> jax.Array:
+    """One pass over every slot of ``bn`` (its slabs in bucket order,
+    each row-major: the flat ``nbrs`` / ``rows`` arrays) in fixed steps of
+    at most ``SCAN_SLOTS`` slots — a ``fori_loop`` plus one static tail;
+    the steps, and the ``_deg_chunk`` budget, bound the temps.
+
+    Push (``pull=False``): each slot reads ``src`` (``[rows_local, ...]``
+    per-row values) at its own row — slab-padding slots read ``fill`` —
+    and reduces into ``out[neighbour]`` (sentinel ids drop). Pull: each
+    slot reads ``src`` (a global ``[n_out, ...]`` activation) at its
+    neighbour (sentinels read ``fill``) and reduces into ``out[row]``
+    (``out`` is ``[rows_local, ...]``; padding slots drop).
+    ``value(got, nbr)`` maps the read values to the reduced ones;
+    ``slot_add`` (per-slot weights, ``[K, S]``) is added to them.
+
+    The slots and the reduction equal a single padded scan's, so the
+    order-invariant reductions (max / min / integer add) are bitwise
+    equal to it, and a float add sums each node's contributions in one
+    fixed order."""
+    nbr, own = bn.nbrs[0], bn.rows[0]
+    wts = None if slot_add is None else slot_add[0]
+    total = int(nbr.shape[0])
+    if not total:
+        return out
+    item = max(int(np.prod(out.shape[1:], dtype=np.int64)), 1) * max(
+        jnp.dtype(out.dtype).itemsize, 1)
+    step = min(SCAN_SLOTS, total, _deg_chunk(1, item))
+    read_at, write_at = (nbr, own) if pull else (own, nbr)
+
+    def part(start, width, acc):
+        cut = lambda a: jax.lax.dynamic_slice_in_dim(a, start, width)
+        got = src.at[cut(read_at)].get(mode="fill", fill_value=fill)
+        if value is not None:
+            got = value(got, cut(nbr))
+        if wts is not None:
+            got = got + cut(wts)
+        return getattr(acc.at[cut(write_at)], reducer)(got, mode="drop")
+
+    return chunk_fold(total, step, part, out)
+
+
+def binned_reach_dense(bn: BinnedEll, frontier: jax.Array, row_offset=None,
+                       n_out=None) -> jax.Array:
+    """``ell_reach_dense`` over the binned forward slabs (same layout
+    contract: replicated state slices at ``row_offset``, sharded state
+    is already local; destinations are global ids). The scatter runs in
+    int32: a one-byte scatter compiles far slower on a TPU."""
+    n = frontier.shape[0] if n_out is None else n_out
+    local = _local_rows(frontier, bn.rows_local, row_offset)
+    out = binned_scan(bn, local.astype(jnp.int32),
+                      jnp.zeros((n,), jnp.int32), "max", 0)
+    return out > 0
+
+
+def binned_reach_lanes(bn: BinnedEll, lanes: jax.Array, row_offset=None,
+                       n_out=None) -> jax.Array:
+    """``ell_reach_lanes`` over the binned forward slabs: one scan of
+    each neighbour list serves all L lanes."""
+    n = lanes.shape[0] if n_out is None else n_out
+    local = _local_rows(lanes, bn.rows_local, row_offset)
+    out = jnp.zeros((n, lanes.shape[-1]), jnp.uint8)
+    return binned_scan(bn, local, out, "max", 0)
+
+
+def binned_min_parent(bn: BinnedEll, frontier: jax.Array, row_offset=None,
+                      n_out=None, row_base=None) -> jax.Array:
+    n = frontier.shape[0] if n_out is None else n_out
+    local = _local_rows(frontier, bn.rows_local, row_offset)
+    cand = jnp.where(local, _row_ids(bn.rows_local, row_offset, row_base),
+                     NO_PARENT)
+    out = jnp.full((n,), NO_PARENT, jnp.int32)
+    return binned_scan(bn, cand, out, "min", int(NO_PARENT))
+
+
+def binned_min_parent_lanes(bn: BinnedEll, lanes: jax.Array,
+                            row_offset=None, n_out=None,
+                            row_base=None) -> jax.Array:
+    n = lanes.shape[0] if n_out is None else n_out
+    local = _local_rows(lanes, bn.rows_local, row_offset)
+    ids = _row_ids(bn.rows_local, row_offset, row_base)[:, None]
+    cand = jnp.where(local != 0, ids, NO_PARENT)
+    out = jnp.full((n, lanes.shape[-1]), NO_PARENT, jnp.int32)
+    return binned_scan(bn, cand, out, "min", int(NO_PARENT))
+
+
+def binned_min_dist(bn: BinnedEll, dist: jax.Array, frontier: jax.Array,
+                    row_offset=None, n_out=None) -> jax.Array:
+    """Weighted relax over the binned slabs (unit weights when the slabs
+    carry none)."""
+    n = dist.shape[0] if n_out is None else n_out
+    du = _local_rows(jnp.where(frontier, dist, jnp.inf), bn.rows_local,
+                     row_offset)
+    out = jnp.full((n,), jnp.inf, jnp.float32)
+    if bn.slot_weights is None:
+        return binned_scan(bn, du, out, "min", jnp.inf,
+                           value=lambda got, nbr: got + 1.0)
+    return binned_scan(bn, du, out, "min", jnp.inf,
+                       slot_add=bn.slot_weights)
+
+
+def binned_push_sum(bn: BinnedEll, values: jax.Array, row_offset=None,
+                    n_out=None, normalize: bool = False) -> jax.Array:
+    """``ell_push_sum`` over the binned slabs (``normalize`` divides by
+    each row's out-degree, the ``degrees`` leaf)."""
+    n = values.shape[0] if n_out is None else n_out
+    vloc = _local_rows(values, bn.rows_local, row_offset)
+    if normalize:
+        vloc = vloc / jnp.maximum(bn.degrees[0], 1).astype(vloc.dtype)
+    # a [n, 1] accumulator sliced at the end, as ``ell_push_sum`` has:
+    # the slice keeps XLA from folding a consumer's ``x + pushed`` into
+    # the scan's zero start, which would re-associate the float sums
+    # differently in the replicated and sharded state layouts
+    out = jnp.zeros((n, 1), vloc.dtype)
+    return binned_scan(bn, vloc[:, None], out, "add", 0)[:, 0]
 
 
 # ---------------------------------------------------------------------------

@@ -7,9 +7,11 @@ density-adaptive set layouts; Kuzu's per-operator physical scan selection),
 with three backends sharing one contract plus a Beamer-style
 direction-optimizing switch:
 
-- ``ell_push``  — forward-ELL scatter (the original path): every local row
-  broadcasts its frontier bit down its out-neighbor list. Scan cost is the
-  whole ``[rows, max_deg]`` tensor regardless of frontier density.
+- ``ell_push``  — forward scatter over the **degree-binned forward slabs**
+  (``GraphOperands.fwd``, a ``graph.csr.BinnedEll``): every local row
+  broadcasts its frontier bit down its out-neighbor list, bucket by
+  bucket. Scan cost is the whole binned structure (~``sum(out_deg)``
+  slots) regardless of frontier density.
 - ``ell_pull``  — gather over the *reverse* ELL with visited-suppression:
   each unvisited v scans its in-neighbor list and ORs the frontier bits it
   finds — the classic bottom-up win when frontiers are large, because the
@@ -18,13 +20,14 @@ direction-optimizing switch:
   graphs (power-law: rev max_deg ≫ mean) each scan still pays
   ``n × max_in_deg`` slots.
 - ``pull_binned`` — the same pull contract over **degree-binned reverse
-  slabs** (``graph.csr.BinnedRevEll``): reverse rows are permuted into
-  pow2-bounded degree buckets, each bucket padded only to its own width,
-  and the per-slab gather results are un-permuted back to row order. A
+  slabs** (``graph.csr.BinnedEll`` of the reverse rows): reverse rows are
+  permuted into pow2-bounded degree buckets, each bucket padded only to
+  its own width, and every slot's gather reduces into its own row. A
   full scan costs ~``sum(in_deg)`` slots instead of ``n × max_in_deg`` —
   the EmptyHeaded lesson (degree-specialized physical layouts) applied to
   the bottom-up direction, which is what makes pull (and therefore the
-  Beamer switch) profitable on skewed graphs.
+  Beamer switch) profitable on skewed graphs. The push's forward slabs
+  are the same structure built from the forward rows.
 - ``pull_binned_fused`` — the same contract and the same binned slabs,
   realized by the fused Pallas kernel (``kernels.binned_pull``): per-slab
   gathers, reductions, the un-permute, and the visited suppression in one
@@ -42,10 +45,12 @@ replicated and sharded state layouts. ``ExtendSpec.pull`` selects the
 bottom-up flavor of the switch — ``"ell"`` (padded reverse ELL) or
 ``"binned"`` (degree-binned slabs; the ``"dopt_binned"`` alias and the
 default ``recommend_backend`` path). The decision is a pure, stateless
-function of (frontier, visited): pull when the frontier's out-edge mass
-exceeds the unexplored edge mass / alpha AND the frontier holds more than
-n / beta nodes — alpha/beta default to Beamer's CPU constants and can be
-replaced per (dataset-family, degree-bucket) by
+function of (frontier, visited) and the (alpha, beta) pair: pull when the
+frontier's out-edge mass exceeds the unexplored edge mass / alpha AND the
+frontier holds more than n / beta nodes. The pair is an engine OPERAND (a
+traced float32 ``[2]`` the engines take on every launch), not part of the
+spec, so a refit that moves it compiles nothing; it defaults to Beamer's
+CPU constants and is replaced per (dataset-family, degree-bucket) by
 ``core.policies.fit_direction_thresholds``. Collectives (global-frontier
 union, stat psums) are hoisted *outside* the cond so both branches are
 collective-free and every device in a sync group takes the same branch.
@@ -56,8 +61,9 @@ graph — see ``graph.csr.truncate_csr``), OR/min merges are order-invariant,
 and visited-suppression only changes contribution values that
 ``ec.apply``'s ``& ~visited`` masks away.
 
-Backends consume a ``GraphOperands`` bundle (forward ELL + optional reverse
-ELL + optional degree-binned reverse slabs + optional per-shard blocks)
+Backends consume a ``GraphOperands`` bundle (degree-binned forward slabs +
+optional reverse ELL + optional degree-binned reverse slabs + optional
+per-shard blocks)
 built once host-side by ``core.dispatcher.prepare_graph`` /
 ``build_operands``.
 """
@@ -78,14 +84,15 @@ from ..kernels.binned_pull.ops import (
     build_pack as build_binned_pack,
 )
 from ..graph.csr import (
+    BinnedEll,
     BinnedPlan,
-    BinnedRevEll,
     CSRGraph,
     EllGraph,
     ShardedBlocks,
+    binned_ell,
     binned_plan,
-    binned_rev_csr,
-    binned_rev_shard,
+    binned_shard,
+    csr_rows,
     ell_from_csr,
     ell_shard,
     sharded_blocks_from_csr,
@@ -98,31 +105,35 @@ from .collectives import min_allreduce, or_allreduce
 from .edge_compute import (
     NO_PARENT,
     _deg_chunk,
-    _local_rows,
+    binned_min_dist,
+    binned_min_parent,
+    binned_min_parent_lanes,
+    binned_push_sum,
+    binned_reach_dense,
+    binned_reach_lanes,
+    binned_scan,
     chunk_fold,
-    ell_min_dist,
-    ell_min_parent,
-    ell_min_parent_lanes,
     ell_min_topk,
-    ell_push_sum,
-    ell_reach_dense,
-    ell_reach_lanes,
 )
 
 BACKENDS = (
     "ell_push", "ell_pull", "pull_binned", "pull_binned_fused", "block_mxu"
 )
+#: Beamer's CPU constants: the (alpha, beta) pair the direction switch runs
+#: when no fitted table serves one
+BEAMER_ALPHA = 14.0
+BEAMER_BETA = 24.0
 
 
 @dataclasses.dataclass(frozen=True)
 class ExtendSpec:
     """Static configuration of the extension step (hashable: engine-cache
-    key material and jit static argument)."""
+    key material and jit static argument). The direction switch's
+    (alpha, beta) pair is not part of it: engines take it as an operand
+    (``AutoBackend``)."""
 
     backend: str = "ell_push"  # one of BACKENDS
     direction: str = "fixed"  # fixed | auto (Beamer push/pull switch)
-    alpha: float = 14.0  # pull when m_frontier > m_unexplored / alpha
-    beta: float = 24.0  # ... and n_frontier > n / beta
     block: int = 128  # tile size of the block_mxu operand
     pull: str = "binned"  # auto's bottom-up flavor:
     #                       binned slabs | fused-kernel slabs | padded ell
@@ -205,7 +216,8 @@ def as_spec(extend) -> ExtendSpec:
 class GraphOperands:
     """The physical scan operands of one graph (or one graph shard).
 
-    ``fwd`` is always present; ``rev`` / ``rev_binned`` / ``blocks`` are
+    ``fwd`` — the degree-binned forward slabs every push scans — is
+    always present; ``rev`` / ``rev_binned`` / ``blocks`` are
     materialized only when the engine's ExtendSpec needs them (treedefs
     must match shard_map in_specs exactly, so engines carry precisely the
     operands they scan).
@@ -220,16 +232,16 @@ class GraphOperands:
     without it, so traced code only ever sees ``version=0``.
     """
 
-    fwd: EllGraph
+    fwd: BinnedEll
     rev: Optional[EllGraph] = None
-    rev_binned: Optional[BinnedRevEll] = None
+    rev_binned: Optional[BinnedEll] = None
     rev_binned_pack: Optional[BinnedPullPack] = None
     blocks: Optional[ShardedBlocks] = None
     version: int = 0
 
     @property
     def n_nodes(self) -> int:
-        return self.fwd.n_nodes
+        return self.fwd.n_rows
 
 
 jax.tree_util.register_dataclass(
@@ -240,8 +252,14 @@ jax.tree_util.register_dataclass(
 
 
 def as_operands(graph) -> GraphOperands:
+    """A ``GraphOperands`` bundle, or bare forward slabs wrapped in one."""
     if isinstance(graph, GraphOperands):
         return graph
+    if not isinstance(graph, BinnedEll):
+        raise TypeError(
+            f"expected GraphOperands or BinnedEll forward slabs, got "
+            f"{type(graph).__name__} (build operands with build_operands)"
+        )
     return GraphOperands(fwd=graph)
 
 
@@ -257,18 +275,20 @@ def build_operands(
     """Host-side operand construction (single-host variant; the mesh-aware
     path in ``dispatcher.prepare_graph`` adds device placement).
 
-    Pads rows to a multiple of ``shards * pad_block`` and derives reverse /
-    binned / block operands from the *truncated* forward graph so every
-    backend scans the identical edge set. ``binned_shards`` overrides the
-    shard count the binned slabs are built for (binning is per shard, so
+    Pads rows to a multiple of ``shards * pad_block`` and derives the
+    forward slabs and the reverse / binned / block operands from the
+    *truncated* forward graph so every backend scans the identical edge
+    set. ``binned_shards`` overrides the shard count the binned slabs
+    (forward and reverse) are built for (binning is per shard, so
     ``prepare_graph`` bins at the policy's own shard count even when rows
     pad for a larger ``pad_shards`` lcm). Returns (operands, n_pad).
     """
     spec = as_spec(extend)
     pad_block = block or spec.pad_block
     eff = effective_csr(csr, max_deg)
-    fwd = pad_ell(ell_from_csr(eff), shards, block=pad_block)
-    n_pad = fwd.n_nodes
+    n_pad = padded_n(eff.n_nodes, shards, pad_block)
+    k = shards if binned_shards is None else int(binned_shards)
+    fwd = binned_ell(eff, n_pad, k)
     rev = None
     if spec.needs_rev:
         rev = pad_ell(ell_from_csr(eff.reverse()), shards, block=pad_block)
@@ -276,8 +296,7 @@ def build_operands(
     rev_binned = None
     rev_binned_pack = None
     if spec.needs_binned:
-        k = shards if binned_shards is None else int(binned_shards)
-        rev_binned = binned_rev_csr(eff, n_pad, k)
+        rev_binned = binned_ell(eff.reverse(), n_pad, k)
         if spec.needs_binned_pack:
             rev_binned_pack = build_binned_pack(rev_binned, n_pad)
     blocks = None
@@ -309,20 +328,40 @@ def _round8(cap: int) -> int:
     return -(-cap // 8) * 8 if cap > 0 else 0
 
 
+def _binned_leaves(prefix: str, bn: BinnedEll) -> dict:
+    """Flat ``name -> array`` leaves of one binned structure."""
+    leaves = {f"{prefix}.{f}": getattr(bn, f)
+              for f in ("nbrs", "rows", "perm", "inv", "degrees")}
+    if bn.slot_weights is not None:
+        leaves[f"{prefix}.w"] = bn.slot_weights
+    return leaves
+
+
+def _binned_from_leaves(prefix: str, g: dict, plan: BinnedPlan) -> BinnedEll:
+    return BinnedEll(
+        **{f: g[f"{prefix}.{f}"]
+           for f in ("nbrs", "rows", "perm", "inv", "degrees")},
+        slot_weights=g.get(f"{prefix}.w"),
+        shapes=tuple((int(r), int(w))
+                     for r, w in zip(plan.rows_b, plan.widths)),
+    )
+
+
 @dataclasses.dataclass(frozen=True)
 class OperandStream:
     """Shard-at-a-time operand construction (the streamed half of
     ``build_operands``).
 
     ``operand_stream`` runs the global O(n) planning passes once (row
-    padding, ELL widths, the binned-slab plan, the common block tile
-    count); ``build_shard(k)`` then materializes only policy shard ``k``'s
-    leaves as host numpy arrays — peak host memory is one shard's operand
-    bytes plus the resident CSR, instead of the whole padded structure.
-    Every leaf's axis 0 is the sharded axis (rows for ELL leaves, the
-    stacked shard axis for binned/pack/block leaves), and a shard's piece
-    is exactly ``global_shape[0] // k_shards`` entries of it, so the
-    caller can place pieces per device and assemble global arrays
+    padding, the reverse ELL width, the forward and reverse binned-slab
+    plans, the common block tile count); ``build_shard(k)`` then
+    materializes only policy shard ``k``'s leaves as host numpy arrays —
+    peak host memory is one shard's operand bytes plus the resident CSR,
+    instead of the whole structure. Every leaf's axis 0 is the sharded
+    axis (rows for ELL leaves, the stacked shard axis for binned/pack/
+    block leaves), and a shard's piece is exactly
+    ``global_shape[0] // k_shards`` entries of it, so the caller can place
+    pieces per device and assemble global arrays
     (``dispatcher.prepare_graph(stream=True)``) or concatenate them into
     the wholesale host structure. Bitwise-identical to ``build_operands``
     by construction — see the per-shard builders' docstrings for why.
@@ -333,9 +372,9 @@ class OperandStream:
     n_pad: int
     k_shards: int  # policy shard count — the build granularity
     fine_shards: int  # row-padding (lcm) shard count; blocks built fine
-    cap_fwd: int
+    plan_fwd: BinnedPlan
     cap_rev: Optional[int] = None
-    plan: Optional[BinnedPlan] = None
+    plan: Optional[BinnedPlan] = None  # reverse binned slabs
     nb: Optional[int] = None
 
     @property
@@ -347,11 +386,9 @@ class OperandStream:
         numpy array (the key set is identical across shards)."""
         rl = self.rows_local
         lo, hi = k * rl, (k + 1) * rl
-        leaves = {}
-        idx, degs, w = ell_shard(self.csr, lo, hi, self.cap_fwd, self.n_pad)
-        leaves["fwd.indices"], leaves["fwd.degrees"] = idx, degs
-        if w is not None:
-            leaves["fwd.weights"] = w
+        leaves = _binned_leaves(
+            "fwd", binned_shard(self.plan_fwd, k, csr_rows(self.csr, lo, hi))
+        )
         rev_local = None
         if self.spec.needs_rev or self.spec.needs_binned:
             rev_local = reverse_shard(self.csr, lo, hi)
@@ -362,13 +399,8 @@ class OperandStream:
             if w is not None:
                 leaves["rev.weights"] = w
         if self.spec.needs_binned:
-            bn = binned_rev_shard(self.plan, k, rev_local)
-            leaves["bn.perm"], leaves["bn.inv"] = bn.perm, bn.inv
-            for b, s in enumerate(bn.slabs):
-                leaves[f"bn.slab{b}"] = s
-            if bn.slab_weights is not None:
-                for b, s in enumerate(bn.slab_weights):
-                    leaves[f"bn.w{b}"] = s
+            bn = binned_shard(self.plan, k, rev_local)
+            leaves.update(_binned_leaves("bn", bn))
             if self.spec.needs_binned_pack:
                 pk = build_binned_pack(bn, self.n_pad, as_numpy=True)
                 leaves["pack.inv_pad"] = pk.inv_pad
@@ -399,30 +431,18 @@ class OperandStream:
     def assemble(self, g: dict, version: int = 0) -> GraphOperands:
         """Rebuild ``GraphOperands`` from assembled global leaves (same
         key set ``build_shard`` emits; values may be jax or numpy)."""
-
-        def ell(p):
-            if f"{p}.indices" not in g:
-                return None
-            return EllGraph(
-                indices=g[f"{p}.indices"],
-                degrees=g[f"{p}.degrees"],
-                weights=g.get(f"{p}.weights"),
+        rev = None
+        if "rev.indices" in g:
+            rev = EllGraph(
+                indices=g["rev.indices"],
+                degrees=g["rev.degrees"],
+                weights=g.get("rev.weights"),
             )
-
         bn = None
         pack = None
         if "bn.inv" in g:
             nb = len(self.plan.widths)
-            bn = BinnedRevEll(
-                slabs=tuple(g[f"bn.slab{b}"] for b in range(nb)),
-                perm=g["bn.perm"],
-                inv=g["bn.inv"],
-                slab_weights=(
-                    tuple(g[f"bn.w{b}"] for b in range(nb))
-                    if "bn.w0" in g
-                    else None
-                ),
-            )
+            bn = _binned_from_leaves("bn", g, self.plan)
             if "pack.inv_pad" in g:
                 nnz = nb - 1
                 pack = BinnedPullPack(
@@ -445,8 +465,8 @@ class OperandStream:
                 block_cols=g["blocks.cols"],
             )
         return GraphOperands(
-            fwd=ell("fwd"),
-            rev=ell("rev"),
+            fwd=_binned_from_leaves("fwd", g, self.plan_fwd),
+            rev=rev,
             rev_binned=bn,
             rev_binned_pack=pack,
             blocks=blocks,
@@ -476,7 +496,6 @@ def operand_stream(
     k = fine if binned_shards is None else int(binned_shards)
     assert fine % k == 0, (fine, k)
     n_pad = padded_n(n, fine, pad_block)
-    cap_fwd = _round8(int(eff.degrees.max()) if n else 0)
     cap_rev = None
     plan = None
     nb = None
@@ -498,7 +517,7 @@ def operand_stream(
         n_pad=n_pad,
         k_shards=k,
         fine_shards=fine,
-        cap_fwd=cap_fwd,
+        plan_fwd=binned_plan(eff.degrees, n_pad, k),
         cap_rev=cap_rev,
         plan=plan,
         nb=nb,
@@ -554,7 +573,7 @@ def _local_state(x: jax.Array, rows: int, ctx: ExtendCtx) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# ell_push — forward scatter (the original primitives, unchanged math).
+# ell_push — forward scatter over the degree-binned forward slabs.
 # ---------------------------------------------------------------------------
 
 
@@ -579,15 +598,20 @@ def _min_topk_pull(ops, dists, src_mask, ctx):
 
 
 class PushBackend:
+    """Scatter every local row's contribution down its out-neighbours,
+    bucket by bucket over the binned forward slabs (``ops.fwd``)."""
+
     name = "ell_push"
 
     @staticmethod
     def reach_dense(ops, frontier, visited, ctx):
-        return ell_reach_dense(ops.fwd, frontier, ctx.row_offset, ctx.n_out)
+        return binned_reach_dense(
+            ops.fwd, frontier, ctx.row_offset, ctx.n_out
+        )
 
     @staticmethod
     def push_sum(ops, values, ctx, normalize=False):
-        return ell_push_sum(
+        return binned_push_sum(
             ops.fwd, values, ctx.row_offset, ctx.n_out, normalize
         )
 
@@ -595,23 +619,23 @@ class PushBackend:
 
     @staticmethod
     def reach_lanes(ops, lanes, visited, ctx):
-        return ell_reach_lanes(ops.fwd, lanes, ctx.row_offset, ctx.n_out)
+        return binned_reach_lanes(ops.fwd, lanes, ctx.row_offset, ctx.n_out)
 
     @staticmethod
     def min_parent(ops, frontier, visited, ctx):
-        return ell_min_parent(
+        return binned_min_parent(
             ops.fwd, frontier, ctx.row_offset, ctx.n_out, ctx.row_base
         )
 
     @staticmethod
     def min_parent_lanes(ops, lanes, visited, ctx):
-        return ell_min_parent_lanes(
+        return binned_min_parent_lanes(
             ops.fwd, lanes, ctx.row_offset, ctx.n_out, ctx.row_base
         )
 
     @staticmethod
     def min_dist(ops, dist, frontier, ctx):
-        return ell_min_dist(
+        return binned_min_dist(
             ops.fwd, dist, frontier, ctx.row_offset, ctx.n_out
         )
 
@@ -833,67 +857,16 @@ class PullBackend:
 # ---------------------------------------------------------------------------
 
 
-def _binned_map(bn: BinnedRevEll, per_slab, neutral):
-    """Run ``per_slab(slab_idx, slab)`` over every nonempty slab, produce
-    the ``neutral(rows_b)`` value for zero-width/zero-row slabs, and
-    un-permute the concatenated per-binned-row results back to original
-    local-row order. ``per_slab`` maps ``[rows_b, width_b]`` indices to a
-    ``[rows_b, ...]`` reduction; padding rows/slots carry the sentinel
-    index so gathers fill with the reduction's neutral element."""
-    parts = []
-    for b, slab in enumerate(bn.slabs):
-        s = slab[0]  # shard-local slice: [rows_b, width_b]
-        if s.shape[0] == 0 or s.shape[1] == 0:
-            parts.append(neutral(s.shape[0]))
-        else:
-            parts.append(per_slab(b, s))
-    cat = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
-    return cat[bn.inv[0]]
-
-
-def _slab_gather_lanes(s: jax.Array, gl: jax.Array) -> jax.Array:
-    """[rows_b, width_b] slab indices × [n_out, L] lanes -> [rows_b, L]
-    OR-reduction, degree-chunked so the gather temp stays under the
-    ``_deg_chunk`` budget even on the hub bucket's widest slab."""
-    rows, D = s.shape
-    L = gl.shape[-1]
-    chunk = _deg_chunk(rows, L)
-    if chunk >= D:
-        return gl.at[s].get(mode="fill", fill_value=0).max(axis=1)
-
-    def step(start, width, acc):
-        idx = lax.dynamic_slice_in_dim(s, start, width, 1)
-        got = gl.at[idx].get(mode="fill", fill_value=0)
-        return jnp.maximum(acc, got.max(axis=1))
-
-    return chunk_fold(D, chunk, step, jnp.zeros((rows, L), gl.dtype))
-
-
-def _slab_min_parent_lanes(s: jax.Array, gl: jax.Array) -> jax.Array:
-    """Per-lane min-parent over one binned slab, degree-chunked like
-    ``_slab_gather_lanes`` (candidate temp is [rows_b, chunk, L] int32)."""
-    rows, D = s.shape
-    L = gl.shape[-1]
-    chunk = _deg_chunk(rows, 4 * L)
-
-    def step(start, width, acc):
-        idx = (
-            s if width == D else lax.dynamic_slice_in_dim(s, start, width, 1)
-        )
-        act = gl.at[idx].get(mode="fill", fill_value=0)
-        cand = jnp.where(
-            act != 0, idx[:, :, None].astype(jnp.int32), NO_PARENT
-        )
-        return jnp.minimum(acc, cand.min(axis=1))
-
-    acc0 = jnp.full((rows, L), NO_PARENT, jnp.int32)
-    if chunk >= D:
-        return step(0, D, acc0)
-    return chunk_fold(D, chunk, step, acc0)
+def _binned_pull(bn: BinnedEll, src: jax.Array, acc0: jax.Array,
+                 reducer: str, fill, **kw) -> jax.Array:
+    """Reduce every reverse slot's read of ``src`` (the global activation)
+    into its row: ``binned_scan`` in pull form, over ``acc0``
+    (``[rows_local, ...]``, local row order)."""
+    return binned_scan(bn, src, acc0, reducer, fill, pull=True, **kw)
 
 
 class BinnedPullBackend:
-    """The ``ell_pull`` contract over ``BinnedRevEll`` slabs.
+    """The ``ell_pull`` contract over the reverse ``BinnedEll`` slabs.
 
     Identical math to PullBackend — same reverse edge set (both derive
     from the truncated forward graph), same OR/min merges, same
@@ -910,13 +883,9 @@ class BinnedPullBackend:
     def _reach_dense(ops, gf, visited, ctx):
         bn = ops.rev_binned
         rows = bn.rows_local
-        reached = _binned_map(
-            bn,
-            lambda b, s: gf.at[s]
-            .get(mode="fill", fill_value=False)
-            .any(axis=1),
-            lambda r: jnp.zeros((r,), jnp.bool_),
-        )
+        # int32 rows: a one-byte scatter compiles far slower on a TPU
+        acc = jnp.zeros((rows,), jnp.int32)
+        reached = _binned_pull(bn, gf.astype(jnp.int32), acc, "max", 0) > 0
         if visited is not None:
             reached &= ~_local_state(visited, rows, ctx)
         return _place_rows(reached, ctx, False)
@@ -925,12 +894,8 @@ class BinnedPullBackend:
     def _reach_lanes(ops, gl, visited, ctx):
         bn = ops.rev_binned
         rows = bn.rows_local
-        L = gl.shape[-1]
-        reached = _binned_map(
-            bn,
-            lambda b, s: _slab_gather_lanes(s, gl),
-            lambda r: jnp.zeros((r, L), gl.dtype),
-        )
+        acc = jnp.zeros((rows, gl.shape[-1]), gl.dtype)
+        reached = _binned_pull(bn, gl, acc, "max", 0)
         if visited is not None:
             vloc = _local_state(visited, rows, ctx)
             reached = jnp.where(vloc != 0, 0, reached)
@@ -940,12 +905,10 @@ class BinnedPullBackend:
     def _min_parent(ops, gf, visited, ctx):
         bn = ops.rev_binned
         rows = bn.rows_local
-        cand = _binned_map(
-            bn,
-            lambda b, s: jnp.where(
-                gf.at[s].get(mode="fill", fill_value=False), s, NO_PARENT
-            ).min(axis=1),
-            lambda r: jnp.full((r,), NO_PARENT, jnp.int32),
+        acc = jnp.full((rows,), NO_PARENT, jnp.int32)
+        cand = _binned_pull(
+            bn, gf, acc, "min", False,
+            value=lambda got, nbr: jnp.where(got, nbr, NO_PARENT),
         )
         if visited is not None:
             cand = jnp.where(
@@ -957,12 +920,11 @@ class BinnedPullBackend:
     def _min_parent_lanes(ops, gl, visited, ctx):
         bn = ops.rev_binned
         rows = bn.rows_local
-        L = gl.shape[-1]
-
-        cand = _binned_map(
-            bn,
-            lambda b, s: _slab_min_parent_lanes(s, gl),
-            lambda r: jnp.full((r, L), NO_PARENT, jnp.int32),
+        acc = jnp.full((rows, gl.shape[-1]), NO_PARENT, jnp.int32)
+        cand = _binned_pull(
+            bn, gl, acc, "min", 0,
+            value=lambda got, nbr: jnp.where(got != 0, nbr[:, None],
+                                             NO_PARENT),
         )
         if visited is not None:
             vloc = _local_state(visited, rows, ctx)
@@ -972,19 +934,13 @@ class BinnedPullBackend:
     @staticmethod
     def _min_dist(ops, gdu, ctx):
         bn = ops.rev_binned
-
-        def per_slab(b, s):
-            w = (
-                bn.slab_weights[b][0]
-                if bn.slab_weights is not None
-                else jnp.ones(s.shape, jnp.float32)
-            )
-            got = gdu.at[s].get(mode="fill", fill_value=jnp.inf)
-            return (got + w).min(axis=1)
-
-        cand = _binned_map(
-            bn, per_slab, lambda r: jnp.full((r,), jnp.inf, jnp.float32)
-        )
+        acc = jnp.full((bn.rows_local,), jnp.inf, jnp.float32)
+        if bn.slot_weights is None:
+            cand = _binned_pull(bn, gdu, acc, "min", jnp.inf,
+                                value=lambda got, nbr: got + 1.0)
+        else:
+            cand = _binned_pull(bn, gdu, acc, "min", jnp.inf,
+                                slot_add=bn.slot_weights)
         return _place_rows(cand, ctx, jnp.float32(jnp.inf))
 
     # -- public contract ----------------------------------------------------
@@ -1053,7 +1009,7 @@ class FusedBinnedPullBackend:
     suppression happen in one VMEM pass per row tile
     (``kernels.binned_pull``), with fully-visited row tiles skipped via the
     scalar-prefetched activity bitmap. Scans ``ops.rev_binned_pack``, the
-    row-padded kernel repack of the same ``BinnedRevEll``.
+    row-padded kernel repack of the same reverse ``BinnedEll``.
     """
 
     name = "pull_binned_fused"
@@ -1182,7 +1138,7 @@ def block_stripe_activity(lane_blocks: jax.Array) -> jax.Array:
 
 class BlockBackend:
     """OR-reach on the MXU block path; candidate-parent / weighted-relax
-    scans have no saturating-0/1 formulation and stay on the push ELL
+    scans have no saturating-0/1 formulation and stay on the binned push
     (same merged values either way, so results remain bit-identical)."""
 
     name = "block_mxu"
@@ -1194,7 +1150,7 @@ class BlockBackend:
         brows = sb.block_rows[0]
         bcols = sb.block_cols[0]
         B = sb.block_size
-        rows = ops.fwd.indices.shape[0]
+        rows = ops.fwd.rows_local
         local = _local_state(lanes, rows, ctx)
         L = local.shape[-1]
         lane_blocks = local.reshape(rows // B, B, L)
@@ -1232,10 +1188,10 @@ class BlockBackend:
         brows = sb.block_rows[0]
         bcols = sb.block_cols[0]
         B = sb.block_size
-        rows = ops.fwd.indices.shape[0]
+        rows = ops.fwd.rows_local
         local = _local_state(values, rows, ctx)
         if normalize:
-            local = local / jnp.maximum(ops.fwd.degrees, 1).astype(
+            local = local / jnp.maximum(ops.fwd.degrees[0], 1).astype(
                 local.dtype
             )
         src = jnp.take(local.reshape(rows // B, B), brows, axis=0)
@@ -1283,10 +1239,10 @@ def _predicate_locals(ops, frontier, visited, ctx: ExtendCtx):
     no visited set — nothing is ever suppressed, so m_u degrades to
     total minus frontier mass)."""
     g = ops.fwd
-    rows = g.indices.shape[0]
+    rows = g.rows_local
     floc = _local_state(frontier, rows, ctx)
     act = (floc != 0) if floc.ndim == 1 else (floc != 0).any(axis=-1)
-    deg = g.degrees.astype(jnp.float32)
+    deg = g.degrees[0].astype(jnp.float32)
     n_f = act.sum(dtype=jnp.float32)
     m_f = jnp.sum(deg * act)
     if visited is not None:
@@ -1358,16 +1314,20 @@ class AutoBackend:
     """Per-iteration push/pull choice under fixed shapes.
 
     The predicate is a pure function of (frontier, visited) reduced over the
-    graph axes, so every device of a sync group agrees; the pull branch's
-    global activation tensors are computed *before* the ``lax.cond`` so the
-    branches themselves hold no collectives (deadlock-free under shard_map).
+    graph axes and of the ``thresholds`` pair ``(alpha, beta)`` — a traced
+    float32 ``[2]`` operand inside the engines, so every pair runs one
+    compiled program — so every device of a sync group agrees; the pull
+    branch's global activation tensors are computed *before* the
+    ``lax.cond`` so the branches themselves hold no collectives
+    (deadlock-free under shard_map).
     """
 
     name = "dopt"
 
-    def __init__(self, spec: ExtendSpec):
-        self.alpha = spec.alpha
-        self.beta = spec.beta
+    def __init__(self, spec: ExtendSpec, thresholds=None):
+        if thresholds is None:
+            thresholds = (BEAMER_ALPHA, BEAMER_BETA)
+        self.alpha, self.beta = thresholds[0], thresholds[1]
         # bottom-up flavor of the switch: degree-binned slabs (default),
         # the fused kernel over the same slabs, or the single padded
         # reverse ELL — same math, different scan
@@ -1473,10 +1433,12 @@ _FIXED = {
 }
 
 
-def make_backend(spec: ExtendSpec):
-    """ExtendSpec -> backend object implementing the primitive contract."""
+def make_backend(spec: ExtendSpec, thresholds=None):
+    """ExtendSpec -> backend object implementing the primitive contract.
+    ``thresholds``: the direction switch's ``(alpha, beta)`` (None =
+    Beamer's); the fixed backends take none."""
     if spec.direction == "auto":
-        return AutoBackend(spec)
+        return AutoBackend(spec, thresholds)
     return _FIXED[spec.backend]
 
 
@@ -1515,13 +1477,10 @@ class BackendCostProbe:
 
         Operands stacked for K > 1 graph shards are probed on shard 0
         alone — the slice one device scans inside the engines — since the
-        backends' binned views read only their first shard."""
+        backends' binned views (forward and reverse) read only their first
+        shard."""
         ops = as_operands(ops)
-        binned = (
-            ops.rev_binned if ops.rev_binned is not None
-            else ops.rev_binned_pack
-        )
-        k = 1 if binned is None else n_pad // binned.rows_local
+        k = n_pad // ops.fwd.rows_local
         if k > 1:
             ops = jax.tree.map(lambda x: x[: x.shape[0] // k], ops)
         ctx = ExtendCtx(n_out=n_pad, row_offset=0)
@@ -1529,7 +1488,7 @@ class BackendCostProbe:
             jnp.arange(n_pad) < max(n_pad // 2, 1)
         )  # half-full: both directions do real work
         visited = jnp.zeros(n_pad, jnp.bool_)
-        probes = {"ell_push": (PushBackend, int(ops.fwd.indices.size))}
+        probes = {"ell_push": (PushBackend, ops.fwd.capacity_slots)}
         if ops.rev_binned is not None:
             probes["pull_binned"] = (
                 BinnedPullBackend, ops.rev_binned.capacity_slots
@@ -1540,8 +1499,8 @@ class BackendCostProbe:
             )
         out = {}
         for name, (be, slots) in probes.items():
-            # operands go in as arguments: a closure would bake the padded
-            # ELL into the program as constants (GiBs at a real size)
+            # operands go in as arguments: a closure would bake the slabs
+            # into the program as constants (GiBs at a real size)
             fn = jax.jit(
                 lambda o, f, v, be=be: be.reach_dense(o, f, v, ctx)
             )

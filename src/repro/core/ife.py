@@ -23,7 +23,7 @@ import jax.numpy as jnp
 
 from ..graph.csr import EllGraph
 from .edge_compute import EDGE_COMPUTES, NO_PARENT
-from .extend import ExtendCtx, as_operands, as_spec, make_backend
+from .extend import ExtendCtx, ExtendSpec, as_operands, as_spec, make_backend
 
 
 class IFEResult(NamedTuple):
@@ -48,13 +48,25 @@ def run_ife(
     frontier (a multi-source *query*); for msbfs_* computes sources[l] seeds
     lane l. Out-of-range ids are inert (empty lanes).
 
-    ``graph``: an ``EllGraph`` (push-only) or a ``GraphOperands`` bundle
-    (see ``core.extend.build_operands``). ``extend`` selects the extension
-    backend ("ell_push" | "ell_pull" | "block_mxu" | "dopt" or an
-    ``ExtendSpec``); all choices are bit-identical in final state.
+    ``graph``: a padded ``EllGraph`` (the seed engine: each iteration is
+    the edge compute's own ``local_extend`` scatter over it; ``extend``
+    must stay the push) or a ``GraphOperands`` bundle (see
+    ``core.extend.build_operands``), whose ``extend`` selects the
+    extension backend ("ell_push" | "ell_pull" | "block_mxu" | "dopt" or
+    an ``ExtendSpec``); all choices are bit-identical in final state.
     """
     ec = EDGE_COMPUTES[edge_compute]
     spec = as_spec(extend)
+    if isinstance(graph, EllGraph):
+        if spec != ExtendSpec():
+            raise ValueError(
+                f"a bare EllGraph runs the push only (extend={extend!r}); "
+                "build the graph with core.extend.build_operands"
+            )
+        return _run_loop(
+            ec, graph.n_nodes, sources, max_iters,
+            lambda state: ec.local_extend(graph, state),
+        )
     ops = as_operands(graph)
     if spec.needs_rev and ops.rev is None:
         raise ValueError(f"extend={spec.backend!r} needs reverse operands; "
@@ -71,6 +83,13 @@ def run_ife(
     be = make_backend(spec)
     n = ops.n_nodes
     ctx = ExtendCtx(n_out=n)
+    return _run_loop(
+        ec, n, sources, max_iters,
+        lambda state: ec.extend(be, ops, state, ctx),
+    )
+
+
+def _run_loop(ec, n: int, sources, max_iters, extend_fn) -> IFEResult:
     cap = jnp.int32(n if max_iters is None else max_iters)
     state0 = ec.init(n, sources)
 
@@ -80,8 +99,7 @@ def run_ife(
 
     def body(carry):
         state, it = carry
-        contribution = ec.extend(be, ops, state, ctx)
-        state = ec.apply(state, contribution, it)
+        state = ec.apply(state, extend_fn(state), it)
         return state, it + 1
 
     state, iters = jax.lax.while_loop(cond, body, (state0, jnp.int32(0)))

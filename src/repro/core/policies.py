@@ -54,7 +54,7 @@ from typing import Mapping, Sequence
 import numpy as np
 
 from .collectives import REDISPATCH_OR_IMPL
-from .extend import ExtendSpec
+from .extend import BEAMER_ALPHA, BEAMER_BETA
 
 
 def pow2ceil(x: int) -> int:
@@ -171,8 +171,6 @@ def recommend_policy(
 # Direction thresholds: Beamer's constants, optionally re-fitted from traces.
 # ---------------------------------------------------------------------------
 
-BEAMER_ALPHA = 14.0
-BEAMER_BETA = 24.0
 
 
 def degree_bucket(avg_degree: float) -> int:
@@ -544,8 +542,6 @@ def recommend_backend(
     n_nodes: int | None = None,
     lanes: int = 1,
     block: int = 128,
-    family: str | None = None,
-    thresholds: DirectionThresholds | None = None,
     operands=None,
 ):
     """Physical scan layout for the extension step (core.extend backends).
@@ -560,17 +556,18 @@ def recommend_backend(
       saturating-matmul block path amortizes one adjacency scan over all
       lanes on the MXU and skips frontier-empty stripes.
     - everything else (BFS-family traversals): the Beamer alpha/beta
-      direction switch over **degree-binned** pull slabs — push while
+      direction switch over **degree-binned** slabs — binned push while
       frontiers are sparse, binned pull with visited-suppression once the
-      frontier's edge mass dominates. With a fitted ``thresholds`` table
-      the switch runs the trace-fitted alpha/beta for this
-      (``family``, degree-bucket) instead of Beamer's CPU constants.
+      frontier's edge mass dominates. The (alpha, beta) pair is not part
+      of the returned spec: the dispatcher looks it up from its fitted
+      ``DirectionThresholds`` at each launch and hands it to the engine
+      as an operand.
 
     Deterministic and *total*: a pure function of its arguments, and when
-    the caller passes the ``operands`` bundle (or a bare EllGraph, like
-    every other operand-accepting entry point) it will only ever name a
-    backend whose physical operands exist in that bundle (falling back
-    toward ``ell_push``, which every bundle carries).
+    the caller passes the ``operands`` bundle (or bare forward slabs,
+    like every other operand-accepting entry point) it will only ever
+    name a backend whose physical operands exist in that bundle (falling
+    back toward ``ell_push``, which every bundle carries).
     """
     from .extend import as_operands
 
@@ -597,19 +594,8 @@ def recommend_backend(
     if lanes >= 64 and dense_blocks and have("blocks"):
         return "block_mxu"
     if have("rev_binned"):
-        if thresholds is not None:
-            alpha, beta = thresholds.lookup(family, avg_degree)
-            return ExtendSpec(
-                direction="auto", alpha=float(alpha), beta=float(beta)
-            )
         return "dopt_binned"
     if have("rev"):
-        if thresholds is not None:
-            alpha, beta = thresholds.lookup(family, avg_degree)
-            return ExtendSpec(
-                direction="auto", pull="ell",
-                alpha=float(alpha), beta=float(beta),
-            )
         return "dopt_ell"
     return "ell_push"
 

@@ -6,15 +6,17 @@ Two device-side layouts and one host-side layout:
   generators, the numpy oracle, and for conversion.
 - ``EllGraph`` (device, jnp): padded fixed-width neighbor lists (ELL format).
   TPU-friendly: every row has ``max_deg`` slots, padding uses the out-of-bounds
-  sentinel ``n_nodes`` so scatter ops drop it. This is the layout the IFE engine
-  extends frontiers over.
-- ``BinnedRevEll`` (device, jnp): degree-binned reverse-adjacency slabs for the
-  bottom-up (pull) extension. Rows are permuted into pow2-bounded degree
-  buckets and each bucket is its own ELL slab padded only to that bucket's
-  width, so a pull scan costs ~``sum(in_deg)`` slots instead of the single
-  padded slab's ``n × max_in_deg`` (EmptyHeaded-style per-row layout
-  specialization). The (permutation, inverse) pair restores the original row
-  order bit-identically.
+  sentinel ``n_nodes`` so scatter ops drop it. This is the layout the seed IFE
+  engine (``core.ife`` on a bare ``EllGraph``) extends frontiers over, and
+  the padded reverse operand of ``ell_pull`` / top-k relax.
+- ``BinnedEll`` (device, jnp): degree-binned adjacency slabs, the scan
+  operand of the served push (forward rows) and of the bottom-up pull
+  (reverse rows). Rows are permuted into pow2-bounded degree buckets and
+  each bucket is its own ELL slab padded only to that bucket's width, so a
+  scan costs ~``sum(deg)`` slots instead of the single padded slab's
+  ``n × max_deg`` (EmptyHeaded-style per-row layout specialization). The
+  (permutation, inverse) pair restores the original row order
+  bit-identically.
 - ``BlockAdjacency`` (device, jnp): 0/1 dense blocks of the adjacency matrix plus
   block coordinates — the block-sparse layout consumed by the ``msbfs_extend``
   Pallas kernel (MXU formulation of MS-BFS).
@@ -253,66 +255,116 @@ def truncate_csr(csr: CSRGraph, max_deg: Optional[int]) -> CSRGraph:
     )
 
 
-@jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
-class BinnedRevEll:
-    """Degree-binned reverse-adjacency slabs (the pull-gather operand).
+class BinnedEll:
+    """Degree-binned adjacency slabs: the scan operand of both directions
+    (the push scatters over the forward structure, the pull gathers over
+    the reverse one).
 
-    Reverse rows are partitioned into degree buckets with pow2-bounded
-    edges, refined so that every row's slab width is within
-    ``max_overhead`` of its true in-degree; bucket ``b`` is a dense ELL
-    slab ``slabs[b]: [K, rows_b, width_b]`` holding in-neighbor ids
-    (sentinel = padded row count ⇒ out-of-range gathers fill with the
-    neutral element). ``K`` is the graph shard count: shard ``k`` owns
-    contiguous local rows ``[k·rows_local, (k+1)·rows_local)`` and bins
-    them independently, but slab shapes are common across shards (counts
-    padded to the per-bucket max) so the structure is SPMD under
-    shard_map — leading axes shard over the policy's graph mesh axes.
+    Rows are partitioned into degree buckets with pow2-bounded edges,
+    refined so that every row's slab width is within ``max_overhead`` of
+    its true degree; bucket ``b`` is a dense ELL slab of ``shapes[b] =
+    (rows_b, width_b)`` holding neighbour ids (sentinel = padded row
+    count, out of range for every ``[n_pad]`` scatter and gather). The
+    slabs are stored one after another, each row-major, in one flat
+    ``nbrs: [K, S]`` array (``slabs`` gives the 2-D views), and ``rows``
+    names each slot's local row: a scan walks one flat array in
+    fixed-size steps, which a TPU compiles in seconds where a program
+    over many odd 2-D slab shapes takes minutes. ``K`` is the graph
+    shard count: shard ``k`` owns contiguous local rows
+    ``[k·rows_local, (k+1)·rows_local)`` and bins them independently, but
+    slab shapes are common across shards (counts padded to the
+    per-bucket max) so the structure is SPMD under shard_map — leading
+    axes shard over the policy's graph mesh axes.
 
     Row placement is carried by a per-shard permutation: concatenating
-    the slabs row-major gives a ``[K, rows_binned]`` virtual vector of
-    per-row gather results; ``perm[k, p]`` is the local row stored at
-    binned position ``p`` (``rows_local`` for slab-padding rows) and
-    ``inv[k, r]`` is the binned position of local row ``r`` — so
-    ``cat[inv]`` restores the original row order bit-identically.
+    the slab rows gives a ``[K, rows_binned]`` virtual vector of per-row
+    results; ``perm[k, p]`` is the local row stored at binned position
+    ``p`` (``rows_local`` for slab-padding rows, whose slots' ``rows``
+    are ``rows_local`` too) and ``inv[k, r]`` is the binned position of
+    local row ``r`` — so ``cat[inv]`` restores the original row order
+    bit-identically. ``degrees[k, r]`` is local row ``r``'s degree (its
+    kept edges).
 
-    Zero-in-degree rows (including rows emptied by degree truncation)
-    live in a genuine **zero-width** slab: they cost nothing to scan.
+    Zero-degree rows (including rows emptied by degree truncation) live
+    in a genuine **zero-width** slab: they cost nothing to scan. A full
+    scan costs ~``sum(deg)`` slots instead of a single padded slab's
+    ``n × max_deg`` (EmptyHeaded-style per-row layout specialization).
     """
 
-    slabs: tuple  # of jax.Array [K, rows_b, width_b] int32 per bucket
+    nbrs: jax.Array  # [K, S] int32 neighbour per slot (sentinel = n_pad)
+    rows: jax.Array  # [K, S] int32 local row per slot (rows_local = pad)
     perm: jax.Array  # [K, rows_binned] int32 (binned pos -> local row)
     inv: jax.Array  # [K, rows_local] int32 (local row -> binned pos)
-    slab_weights: Optional[tuple] = None  # [K, rows_b, width_b] f32 each
+    degrees: jax.Array  # [K, rows_local] int32 (kept edges per row)
+    slot_weights: Optional[jax.Array] = None  # [K, S] float32
+    shapes: tuple = ()  # static: (rows_b, width_b) of every bucket
+
+    def _views(self, flat) -> tuple:
+        out, off = [], 0
+        for r, w in self.shapes:
+            out.append(flat[:, off:off + r * w].reshape(flat.shape[0], r, w))
+            off += r * w
+        return tuple(out)
+
+    @property
+    def slabs(self) -> tuple:
+        """Each bucket's ``[K, rows_b, width_b]`` slab (views of ``nbrs``
+        for host numpy leaves, so writes land in the structure)."""
+        return self._views(self.nbrs)
+
+    @property
+    def slab_weights(self) -> Optional[tuple]:
+        return None if self.slot_weights is None else self._views(
+            self.slot_weights)
+
+    def slab_rows(self) -> tuple:
+        """Each bucket's ``[K, rows_b, width_b]`` view of ``rows``."""
+        return self._views(self.rows)
 
     @property
     def n_slabs(self) -> int:
-        return len(self.slabs)
+        return len(self.shapes)
 
     @property
     def rows_local(self) -> int:
         return self.inv.shape[-1]
 
     @property
+    def n_rows(self) -> int:
+        """Rows over every shard the structure holds (``n_pad`` when it
+        holds them all)."""
+        return self.inv.shape[0] * self.inv.shape[1]
+
+    @property
     def widths(self) -> tuple:
-        return tuple(int(s.shape[-1]) for s in self.slabs)
+        return tuple(w for _, w in self.shapes)
 
     @property
     def capacity_slots(self) -> int:
-        """Total adjacency slots of one shard's full scan (the binned
-        pull's worst-case per-iteration scan extent)."""
-        return int(sum(s.shape[-2] * s.shape[-1] for s in self.slabs))
+        """Total adjacency slots of one shard's full scan (what one device
+        scans a push, or a binned pull, an iteration)."""
+        return int(self.nbrs.shape[-1])
+
+    @property
+    def slots(self) -> int:
+        """Adjacency slots over every shard the structure holds."""
+        return int(self.nbrs.shape[0] * self.nbrs.shape[-1])
 
     def row_widths(self) -> np.ndarray:
         """[K, rows_local] host array: each local row's slab width — the
-        slots a pull scan pays for that row (scanned-slot accounting)."""
+        slots a scan pays for that row (scanned-slot accounting)."""
         w = np.concatenate(
-            [
-                np.full((s.shape[-2],), s.shape[-1], np.int64)
-                for s in self.slabs
-            ]
+            [np.full((r,), w, np.int64) for r, w in self.shapes]
         )
         return w[np.asarray(self.inv)]
+
+
+jax.tree_util.register_dataclass(
+    BinnedEll,
+    data_fields=["nbrs", "rows", "perm", "inv", "degrees", "slot_weights"],
+    meta_fields=["shapes"],
+)
 
 
 def _degree_bucket_edges(
@@ -339,118 +391,24 @@ def _degree_bucket_edges(
     return edges
 
 
-def binned_rev_csr(
-    csr: CSRGraph,
-    n_pad: int,
-    shards: int = 1,
-    max_overhead: float = 1.1,
-) -> BinnedRevEll:
-    """Build the degree-binned reverse slabs of (the truncated) ``csr``.
-
-    ``csr`` is the *forward* effective graph (see ``truncate_csr``) so the
-    pull gather enumerates exactly the edge set every other backend scans;
-    ``n_pad`` is the padded row count (divisible by ``shards``); rows
-    ``>= csr.n_nodes`` are empty and land in the zero-width slab.
-    Host-side, vectorized numpy; deterministic in its inputs.
-    """
-    assert n_pad % max(shards, 1) == 0, (n_pad, shards)
-    rev = csr.reverse()
-    n = rev.n_nodes
-    rows_local = n_pad // shards
-    degs = np.zeros(n_pad, np.int64)
-    degs[:n] = rev.degrees
-    nz_edges = _degree_bucket_edges(degs, max_overhead)
-    # bucket 0 is always the zero-width slab (rows with no in-edges)
-    bucket_of = np.zeros(n_pad, np.int64)
-    widths = [0]
-    for b, (lo, hi) in enumerate(nz_edges, start=1):
-        bucket_of[(degs >= lo) & (degs <= hi)] = b
-        widths.append(hi)
-    n_buckets = len(widths)
-    shard_of = np.arange(n_pad, dtype=np.int64) // rows_local
-    local = np.arange(n_pad, dtype=np.int64) % rows_local
-
-    # per-(shard, bucket) counts; slab row counts pad to the shard max
-    counts = np.zeros((shards, n_buckets), np.int64)
-    np.add.at(counts, (shard_of, bucket_of), 1)
-    rows_b = counts.max(axis=0)
-    starts = np.concatenate([[0], np.cumsum(rows_b)])[:-1]
-    rows_binned = int(rows_b.sum())
-
-    # stable slot assignment: rows of one (shard, bucket) keep ascending
-    # local-row order — the permutation is deterministic
-    order = np.lexsort((local, bucket_of, shard_of))
-    o_shard, o_bucket, o_local = (
-        shard_of[order], bucket_of[order], local[order]
-    )
-    key = o_shard * n_buckets + o_bucket
-    run_start = np.concatenate([[0], np.cumsum(np.bincount(
-        key.astype(np.int64), minlength=shards * n_buckets
-    ))])[:-1]
-    slot_in_bucket = np.arange(n_pad, dtype=np.int64) - run_start[key]
-    pos = starts[o_bucket] + slot_in_bucket  # binned position per row
-
-    perm = np.full((shards, rows_binned), rows_local, np.int32)
-    perm[o_shard, pos] = o_local.astype(np.int32)
-    inv = np.zeros((shards, rows_local), np.int32)
-    inv[o_shard, o_local] = pos.astype(np.int32)
-
-    has_w = rev.weights is not None
-    slabs, slab_w = [], []
-    for b in range(n_buckets):
-        w = widths[b]
-        slab = np.full((shards, int(rows_b[b]), w), n_pad, np.int32)
-        wslab = (
-            np.zeros((shards, int(rows_b[b]), w), np.float32)
-            if has_w
-            else None
-        )
-        if w > 0:
-            sel = o_bucket == b  # rows of this bucket, slot order
-            rows = order[sel]  # global row ids
-            kept = degs[rows]
-            flat = np.repeat(np.arange(len(rows)), kept)
-            slots = np.arange(int(kept.sum()), dtype=np.int64) - np.repeat(
-                np.cumsum(kept) - kept, kept
-            )
-            src = rev.indptr[rows][flat] + slots
-            slab[o_shard[sel][flat], slot_in_bucket[sel][flat], slots] = (
-                rev.indices[src]
-            )
-            if has_w:
-                wslab[
-                    o_shard[sel][flat], slot_in_bucket[sel][flat], slots
-                ] = rev.weights[src]
-        slabs.append(jnp.asarray(slab))
-        if has_w:
-            slab_w.append(jnp.asarray(wslab))
-    return BinnedRevEll(
-        slabs=tuple(slabs),
-        perm=jnp.asarray(perm),
-        inv=jnp.asarray(inv),
-        slab_weights=tuple(slab_w) if has_w else None,
-    )
-
-
 @dataclasses.dataclass(frozen=True)
 class BinnedPlan:
-    """Shard-independent layout of the degree-binned reverse slabs.
+    """Shard-independent layout of a ``BinnedEll``.
 
-    Everything that couples shards in ``binned_rev_csr`` — the bucket
-    edges (derived from the *global* degree histogram), the common
-    (max-over-shards) slab row counts, and the row→bucket assignment — is
-    computed here in one O(n) pass, so a single shard's slabs can then be
-    built from its local reverse adjacency alone (``binned_rev_shard``)
-    and bitwise-match the corresponding ``[k:k+1]`` slice of the wholesale
-    build. This is the streamed operand path's planning half: host peak
-    memory per shard is the shard's own slab bytes, not the whole
+    Everything that couples shards — the bucket edges (derived from the
+    *global* degree histogram), the common (max-over-shards) slab row
+    counts, and the row→bucket assignment — is computed here in one O(n)
+    pass, so a single shard's slabs can then be built from its own rows
+    alone (``binned_shard``). ``binned_ell`` stacks those shards, so the
+    streamed operand path and the wholesale build agree bitwise: host
+    peak memory per shard is the shard's own slab bytes, not the whole
     structure's (see docs/scale.md).
     """
 
     widths: tuple  # per-bucket slab width; widths[0] == 0
     rows_b: np.ndarray  # [n_buckets] common slab row counts
     bucket_of: np.ndarray  # [n_pad] bucket id per padded row
-    degs: np.ndarray  # [n_pad] effective in-degree per padded row
+    degs: np.ndarray  # [n_pad] effective degree per padded row
     shards: int
     n_pad: int
 
@@ -464,23 +422,24 @@ class BinnedPlan:
 
 
 def binned_plan(
-    rev_degs: np.ndarray,
+    degs: np.ndarray,
     n_pad: int,
     shards: int = 1,
     max_overhead: float = 1.1,
 ) -> BinnedPlan:
-    """Global planning pass of ``binned_rev_csr`` (same bucketing, same
-    counts arithmetic) without touching any edge data: ``rev_degs`` is the
-    effective graph's in-degree histogram (``np.bincount(eff.indices)``)."""
+    """Global planning pass of a binned build, without touching any edge
+    data: ``degs`` is the degree of each row to bin (out-degrees for the
+    forward structure, ``np.bincount(eff.indices)`` for the reverse)."""
     assert n_pad % max(shards, 1) == 0, (n_pad, shards)
     rows_local = n_pad // shards
-    degs = np.zeros(n_pad, np.int64)
-    degs[: len(rev_degs)] = rev_degs
-    nz_edges = _degree_bucket_edges(degs, max_overhead)
+    degs_pad = np.zeros(n_pad, np.int64)
+    degs_pad[: len(degs)] = degs
+    nz_edges = _degree_bucket_edges(degs_pad, max_overhead)
+    # bucket 0 is always the zero-width slab (rows with no edges)
     bucket_of = np.zeros(n_pad, np.int64)
     widths = [0]
     for b, (lo, hi) in enumerate(nz_edges, start=1):
-        bucket_of[(degs >= lo) & (degs <= hi)] = b
+        bucket_of[(degs_pad >= lo) & (degs_pad <= hi)] = b
         widths.append(hi)
     shard_of = np.arange(n_pad, dtype=np.int64) // rows_local
     counts = np.zeros((shards, len(widths)), np.int64)
@@ -489,23 +448,21 @@ def binned_plan(
         widths=tuple(widths),
         rows_b=counts.max(axis=0),
         bucket_of=bucket_of,
-        degs=degs,
+        degs=degs_pad,
         shards=shards,
         n_pad=n_pad,
     )
 
 
-def binned_rev_shard(
-    plan: BinnedPlan, k: int, rev_local: CSRGraph
-) -> BinnedRevEll:
-    """Shard ``k``'s slice of the wholesale ``binned_rev_csr`` structure
-    (leading axis K=1), built from the shard's local reverse CSR alone
-    (``partition.reverse_shard``). All leaves are host numpy so the caller
-    controls device placement. Bitwise-identical to
-    ``binned_rev_csr(...)``'s ``[k:k+1]`` slices by construction: the slot
-    order within one (shard, bucket) is ascending local row — exactly what
-    the wholesale lexsort produces — and the in-neighbor lists come from
-    the same stable-by-destination edge order."""
+def binned_shard(plan: BinnedPlan, k: int, local: CSRGraph) -> BinnedEll:
+    """Shard ``k`` of a binned structure (leading axis K=1), built from
+    that shard's own rows: ``local`` holds ``plan.rows_local`` rows whose
+    ``indices`` are global neighbour ids (``csr_rows`` for the forward
+    direction, ``partition.reverse_shard`` for the reverse). All leaves
+    are host numpy so the caller controls device placement. Slots within
+    one bucket follow ascending local row, and each row keeps its CSR
+    neighbour order — the structure is a deterministic function of the
+    plan and the rows."""
     rl = plan.rows_local
     n_pad = plan.n_pad
     bucket_k = plan.bucket_of[k * rl : (k + 1) * rl]
@@ -513,9 +470,9 @@ def binned_rev_shard(
     n_buckets = len(plan.widths)
     starts = np.cumsum(plan.rows_b) - plan.rows_b
 
-    local = np.arange(rl, dtype=np.int64)
+    rows_all = np.arange(rl, dtype=np.int64)
     order = np.argsort(bucket_k, kind="stable")  # (bucket, local) asc
-    o_bucket, o_local = bucket_k[order], local[order]
+    o_bucket, o_local = bucket_k[order], rows_all[order]
     run_start = np.concatenate(
         [[0], np.cumsum(np.bincount(o_bucket, minlength=n_buckets))]
     )[:-1]
@@ -527,13 +484,16 @@ def binned_rev_shard(
     inv = np.zeros((1, rl), np.int32)
     inv[0, o_local] = pos.astype(np.int32)
 
-    has_w = rev_local.weights is not None
-    slabs, slab_w = [], []
+    has_w = local.weights is not None
+    slabs, slab_w, slab_r = [], [], []
     for b in range(n_buckets):
         w = plan.widths[b]
         rb = int(plan.rows_b[b])
-        slab = np.full((1, rb, w), n_pad, np.int32)
-        wslab = np.zeros((1, rb, w), np.float32) if has_w else None
+        slab = np.full((rb, w), n_pad, np.int32)
+        wslab = np.zeros((rb, w), np.float32) if has_w else None
+        # each slab row's slots belong to the row stored there
+        p0 = int(starts[b])
+        slab_r.append(np.broadcast_to(perm[0, p0:p0 + rb, None], (rb, w)))
         if w > 0:
             sel = o_bucket == b
             rows = o_local[sel]  # local row ids, slot order
@@ -542,22 +502,64 @@ def binned_rev_shard(
             slots = np.arange(int(kept.sum()), dtype=np.int64) - np.repeat(
                 np.cumsum(kept) - kept, kept
             )
-            src = rev_local.indptr[rows][flat] + slots
-            slab[0, slot_in_bucket[sel][flat], slots] = rev_local.indices[
-                src
-            ]
+            src = local.indptr[rows][flat] + slots
+            slab[slot_in_bucket[sel][flat], slots] = local.indices[src]
             if has_w:
-                wslab[0, slot_in_bucket[sel][flat], slots] = (
-                    rev_local.weights[src]
-                )
+                wslab[slot_in_bucket[sel][flat], slots] = local.weights[src]
         slabs.append(slab)
-        if has_w:
-            slab_w.append(wslab)
-    return BinnedRevEll(
-        slabs=tuple(slabs),
+        slab_w.append(wslab)
+    flat = lambda parts, dt: np.concatenate(
+        [x.reshape(-1) for x in parts]).astype(dt)[None]
+    return BinnedEll(
+        nbrs=flat(slabs, np.int32),
+        rows=flat(slab_r, np.int32),
         perm=perm,
         inv=inv,
-        slab_weights=tuple(slab_w) if has_w else None,
+        degrees=degs_k.astype(np.int32)[None],
+        slot_weights=flat(slab_w, np.float32) if has_w else None,
+        shapes=tuple(
+            (int(r), int(w)) for r, w in zip(plan.rows_b, plan.widths)
+        ),
+    )
+
+
+def csr_rows(csr: CSRGraph, lo: int, hi: int) -> CSRGraph:
+    """Rows ``[lo, hi)`` of ``csr`` as a CSR of ``hi - lo`` rows (global
+    neighbour ids kept); rows at or beyond ``csr.n_nodes`` are empty pad
+    rows. The forward counterpart of ``partition.reverse_shard``."""
+    n = csr.n_nodes
+    lo_r, hi_r = min(lo, n), min(hi, n)
+    e_lo, e_hi = int(csr.indptr[lo_r]), int(csr.indptr[hi_r])
+    indptr = np.full(hi - lo + 1, e_hi - e_lo, np.int64)
+    indptr[: hi_r - lo_r + 1] = csr.indptr[lo_r : hi_r + 1] - e_lo
+    return CSRGraph(
+        indptr=indptr,
+        indices=csr.indices[e_lo:e_hi],
+        weights=None if csr.weights is None else csr.weights[e_lo:e_hi],
+    )
+
+
+def binned_ell(
+    csr: CSRGraph,
+    n_pad: int,
+    shards: int = 1,
+    max_overhead: float = 1.1,
+) -> BinnedEll:
+    """The degree-binned slabs of ``csr``'s rows, stacked over ``shards``
+    (jnp leaves). Build the forward structure from the effective graph
+    (see ``truncate_csr``) and the reverse one from its transpose, so
+    every backend scans the same edge set. ``n_pad`` is the padded row
+    count (divisible by ``shards``); rows ``>= csr.n_nodes`` are empty and
+    land in the zero-width slab. Shard by shard through ``binned_shard``,
+    so the streamed build's pieces are this structure's slices."""
+    plan = binned_plan(csr.degrees, n_pad, shards, max_overhead)
+    rl = plan.rows_local
+    parts = [
+        binned_shard(plan, k, csr_rows(csr, k * rl, (k + 1) * rl))
+        for k in range(shards)
+    ]
+    return jax.tree.map(
+        lambda *xs: jnp.asarray(np.concatenate(xs)), *parts
     )
 
 

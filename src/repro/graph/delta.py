@@ -348,9 +348,11 @@ def _build_ell_host(eff: CSRGraph, n_pad: int) -> EllGraph:
     return EllGraph(indices=indices, degrees=degrees, weights=weights)
 
 
-def _fold_binned(bn, rev: CSRGraph, dirty: np.ndarray, n_pad: int,
+def _fold_binned(bn, rows_csr: CSRGraph, dirty: np.ndarray, n_pad: int,
                  max_overhead: float = 1.1):
-    """Re-bin ``dirty`` (reverse) rows inside the existing slab shapes.
+    """Re-bin ``dirty`` rows of a binned structure inside its existing
+    slab shapes: forward rows against the effective graph, reverse rows
+    against its transpose (``rows_csr`` holds the structure's rows).
 
     A dirty row stays in its bucket when the bucket still satisfies the
     builder's refinement invariant for its new degree
@@ -358,7 +360,8 @@ def _fold_binned(bn, rev: CSRGraph, dirty: np.ndarray, n_pad: int,
     degree 0); otherwise it moves to the narrowest existing bucket that
     satisfies it, claiming a free (sentinel-perm) slot — vacated slots are
     claimable in the same pass, so swaps inside one bucket always fit.
-    Preserves the perm/inverse placement contract for every untouched row.
+    Preserves the perm/inverse placement contract for every untouched row;
+    each dirty row's ``degrees`` entry is rewritten too.
 
     Returns ``(changed_cells, perm_changed, n_moves)`` where
     ``changed_cells`` is ``[(bucket, shard, slot)]`` of rewritten slab
@@ -371,7 +374,9 @@ def _fold_binned(bn, rev: CSRGraph, dirty: np.ndarray, n_pad: int,
     rows_b = np.asarray([int(s.shape[-2]) for s in bn.slabs], np.int64)
     ends = np.cumsum(rows_b)
     starts = ends - rows_b
-    has_w = bn.slab_weights is not None
+    has_w = bn.slot_weights is not None
+    slabs, slab_w, slab_rows = bn.slabs, bn.slab_weights, bn.slab_rows()
+    rev = rows_csr
     n = rev.n_nodes
 
     def fits(d: int, b: int) -> bool:
@@ -412,9 +417,10 @@ def _fold_binned(bn, rev: CSRGraph, dirty: np.ndarray, n_pad: int,
             bn.perm[k, p] = rows_local
             if widths[b] > 0:
                 slot = p - int(starts[b])
-                bn.slabs[b][k, slot, :] = n_pad
+                slabs[b][k, slot, :] = n_pad
+                slab_rows[b][k, slot, :] = rows_local
                 if has_w:
-                    bn.slab_weights[b][k, slot, :] = 0.0
+                    slab_w[b][k, slot, :] = 0.0
                 changed_cells.append((b, k, slot))
             free[(k, b)].append(p)
             free[(k, b)].sort(reverse=True)
@@ -427,20 +433,23 @@ def _fold_binned(bn, rev: CSRGraph, dirty: np.ndarray, n_pad: int,
             p2 = int(slots.pop())
             bn.perm[k, p2] = l
             bn.inv[k, l] = p2
+            if widths[tb] > 0:
+                slab_rows[tb][k, p2 - int(starts[tb]), :] = l
 
     # content rewrite: every dirty row at its (possibly new) slot
     for (r, k, l, d, _, _) in recs:
+        bn.degrees[k, l] = d
         p = int(bn.inv[k, l])
         b = int(np.searchsorted(ends, p, side="right"))
         if widths[b] == 0:
             continue
         slot = p - int(starts[b])
         lo = int(rev.indptr[r])
-        row = bn.slabs[b][k, slot]
+        row = slabs[b][k, slot]
         row[:] = n_pad
         row[:d] = rev.indices[lo : lo + d]
         if has_w:
-            wrow = bn.slab_weights[b][k, slot]
+            wrow = slab_w[b][k, slot]
             wrow[:] = 0.0
             wrow[:d] = rev.weights[lo : lo + d]
         changed_cells.append((b, k, slot))
@@ -558,23 +567,29 @@ def fold_operands(host, old_eff: CSRGraph, new_eff: CSRGraph,
     (``fwd`` required; the rest optional). Returns
     ``(structures_dict, FoldReport)`` where the dict maps each slot name
     to its post-fold structure — in-place-folded mirrors, or fresh
-    rebuilds for the slots the report marks ``reshaped``.
+    rebuilds for the slots the report marks ``reshaped``. The forward
+    and reverse binned slabs fold the same way (``_fold_binned``), each
+    rebuilt where a dirty row fits no existing bucket.
     """
     del old_eff  # the diff already carries everything the folds need
     # local imports: csr builders only (this module stays importable
     # without jax having initialized any backend state)
-    from .csr import binned_rev_csr, sharded_blocks_from_csr
+    from .csr import binned_ell, sharded_blocks_from_csr
 
-    n_pad = int(host.fwd.indices.shape[0])
+    n_pad = int(host.fwd.inv.shape[0] * host.fwd.inv.shape[1])
     changed = {s: False for s in STRUCTURES}
     reshaped = {s: False for s in STRUCTURES}
     moves = 0
 
     fwd = host.fwd
     if len(diff.fwd_dirty):
-        if _fold_ell(fwd, new_eff, diff.fwd_dirty, n_pad) is None:
-            fwd = _build_ell_host(new_eff, n_pad)
+        out = _fold_binned(fwd, new_eff, diff.fwd_dirty, n_pad)
+        if out is None:
+            K = int(fwd.perm.shape[0])
+            fwd = _to_numpy(binned_ell(new_eff, n_pad, K))
             reshaped["fwd"] = True
+        else:
+            moves += out[2]
         changed["fwd"] = True
 
     rev_csr = None
@@ -594,7 +609,7 @@ def fold_operands(host, old_eff: CSRGraph, new_eff: CSRGraph,
         out = _fold_binned(bn, rev_csr, diff.rev_dirty, n_pad)
         if out is None:
             K = int(bn.perm.shape[0])
-            bn = _to_numpy(binned_rev_csr(new_eff, n_pad, K))
+            bn = _to_numpy(binned_ell(rev_csr, n_pad, K))
             reshaped["rev_binned"] = True
             if pack is not None:
                 from ..kernels.binned_pull.ops import build_pack
@@ -603,7 +618,8 @@ def fold_operands(host, old_eff: CSRGraph, new_eff: CSRGraph,
                 reshaped["rev_binned_pack"] = True
                 changed["rev_binned_pack"] = True
         else:
-            cells, perm_changed, moves = out
+            cells, perm_changed, n_moves = out
+            moves += n_moves
             if pack is not None and (cells or perm_changed):
                 _fold_pack(pack, bn, cells, perm_changed)
                 changed["rev_binned_pack"] = True

@@ -2,9 +2,8 @@
 
 The paper evaluates on LDBC100, LiveJournal, Spotify, and Graph500-28
 (20M–4.2B edges). The repo generates *proxies* with matched degree structure
-from a seed, at a scale cut to what the device holds (the padded forward ELL
-sets that limit today); the full-scale shapes appear only in the dry-run
-(ShapeDtypeStructs, no allocation).
+from a seed, at a scale cut to what the device holds; the full-scale
+shapes appear only in the dry-run (ShapeDtypeStructs, no allocation).
 
 All generators are deterministic in (shape, seed).
 """

@@ -1,7 +1,7 @@
 """Pallas TPU kernel: fused slab-major degree-binned pull extension.
 
 One ``pallas_call`` realizes a full bottom-up (pull) frontier extension over
-the degree-binned reverse slabs (``graph.csr.BinnedRevEll``): the per-slab
+the degree-binned reverse slabs (the reverse ``graph.csr.BinnedEll``): the per-slab
 neighbor gathers, the OR / min / min-parent reduction over each row's slab
 width, the ``inv`` un-permute back to local row order, and the visited
 suppression — work the jnp path (``core.extend.BinnedPullBackend``) spreads

@@ -22,14 +22,14 @@ from .ref import fused_binned_pull_ref
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class BinnedPullPack:
-    """Kernel-ready repack of ``graph.csr.BinnedRevEll``.
+    """Kernel-ready repack of the reverse ``graph.csr.BinnedEll``.
 
     Same edge set and the same perm/inverse contract, re-laid-out for the
     fused kernel: every nonzero-width slab is row-padded to a multiple of
     its compute-tile rows (pad rows all-sentinel ⇒ gather the neutral), and
     the permutation pair is re-indexed into the padded binned-position
     space. ``K`` is the graph shard count; leading axes shard over the
-    policy's graph mesh axes exactly like the source ``BinnedRevEll``.
+    policy's graph mesh axes exactly like the source ``BinnedEll``.
     """
 
     slabs: tuple  # of [K, rows_pad_b, width_b] int32 (nonzero-width buckets)
@@ -69,7 +69,7 @@ def pack_plan(pack: BinnedPullPack) -> TilePlan:
 
 
 def build_pack(bn, n_pad: int, as_numpy: bool = False) -> BinnedPullPack:
-    """Host-side (numpy, deterministic) repack of a ``BinnedRevEll``.
+    """Host-side (numpy, deterministic) repack of a reverse ``BinnedEll``.
 
     ``n_pad`` is the padded node count — the slab sentinel value.
     ``as_numpy`` keeps the leaves as host numpy arrays (the streamed

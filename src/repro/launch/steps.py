@@ -29,7 +29,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..configs import base as cfgbase
 from ..core.dispatcher import build_engine, pad_sources, _axes_size
 from ..core.policies import POLICIES
-from ..graph.csr import EllGraph
+from ..core.extend import GraphOperands
+from ..graph.csr import BinnedEll
 from ..graph.partition import padded_n
 from ..models import dcn_v2 as dcn
 from ..models import transformer as tfm
@@ -767,11 +768,18 @@ def _paper_cell(spec, shape, mesh, multi_pod,
         mesh, policy, cfg.edge_compute, n_pad, cfg.max_iters,
         state_layout=state_layout,
     )
-    graph = EllGraph(
-        indices=sds((n_pad, max_deg), jnp.int32),
-        degrees=sds((n_pad,), jnp.int32),
-        weights=None,
-    )
+    # the forward slabs as one bucket of width max_deg (the dry run has
+    # no degree sequence to bin): rows_local x max_deg slots a shard
+    rows_local = n_pad // shards
+    slots = rows_local * max_deg
+    graph = GraphOperands(fwd=BinnedEll(
+        nbrs=sds((shards, slots), jnp.int32),
+        rows=sds((shards, slots), jnp.int32),
+        perm=sds((shards, rows_local), jnp.int32),
+        inv=sds((shards, rows_local), jnp.int32),
+        degrees=sds((shards, rows_local), jnp.int32),
+        shapes=((rows_local, max_deg),),
+    ))
     src_shards = _axes_size(mesh, sa)
     morsels_np = pad_sources(
         np.arange(cfg.n_sources, dtype=np.int32), src_shards,
@@ -785,7 +793,7 @@ def _paper_cell(spec, shape, mesh, multi_pod,
     flops = 2.0 * edges_scanned * lanes
     return Cell(
         spec.arch_id, f"{shape.name}", "query", engine.fn,
-        (graph, morsels), None, flops,
+        (graph, morsels, sds((2,), jnp.float32)), None, flops,
         iters_scale=float(cfg.max_iters),
         notes=(
             f"policy={policy.name} or={or_impl} state={state_layout} "

@@ -54,10 +54,15 @@ tests/test_serving.py).
       repro.dispatch.compile      (kind) a phase-2 launch that compiles
       repro.dispatch.refit        a direction-threshold refit
         repro.dispatch.cost_probe (n_pad) the measured-cost probe
+    repro.dispatch.build_operands (graph_axes) building and placing one
+                                  operand bundle (set-up, first use)
 
 ``EngineCache.compile_s`` sums the host seconds of the compile launches;
 ``SchedulerStats.d2h_prefetched`` / ``d2h_blocking`` count the result
-leaves read to the host with and without a copy enqueued at launch.
+leaves read to the host with and without a copy enqueued at launch;
+``push_iterations`` / ``pull_iterations`` and ``push_slots`` /
+``pull_slots`` count the direction switch's choices (see
+``QueryDispatcher._record_samples``).
 """
 from __future__ import annotations
 
@@ -101,7 +106,8 @@ from ..core import (
     recommend_policy,
 )
 from ..core.dispatcher import _axes_size
-from ..core.extend import GraphOperands, effective_csr
+from ..core.extend import BEAMER_ALPHA, BEAMER_BETA, GraphOperands, \
+    effective_csr
 from ..graph.csr import CSRGraph
 from ..graph.delta import (
     DeltaReport,
@@ -122,6 +128,10 @@ class EngineKey:
     marks the sample-tapped flavor (``build_engine(collect_stats=True)``
     returns ``(result, per-iteration stats)`` — same result state,
     different HLO).
+
+    The direction switch's (alpha, beta) pair is NOT part of the key: the
+    engines take it as an operand on every launch, so a threshold refit
+    reuses the compiled engine.
 
     ``operands_epoch`` is the mutable-graph shape generation of the
     operand structures this engine scans: a ``GraphDelta`` that folds
@@ -345,6 +355,14 @@ class SchedulerStats:
     # or read with no prefetch (a full round trip)
     d2h_prefetched: int = 0
     d2h_blocking: int = 0
+    # the direction switch's choices over the stats tap's iterations (one
+    # per real morsel and iteration of a sample-tapped dopt engine): how
+    # many pushed / pulled, and the slots they scanned — every binned
+    # forward slot a push, the unvisited rows' binned slots a pull
+    push_iterations: int = 0
+    pull_iterations: int = 0
+    push_slots: int = 0
+    pull_slots: int = 0
 
     @property
     def gang_occupancy(self) -> float:
@@ -621,6 +639,8 @@ class QueryDispatcher:
         self._dir_samples: dict[int, collections.deque] = {}
         self._sample_window = int(sample_window)
         self._batches_since_refit = 0
+        # the last (alpha, beta) a launch took, with its device copy
+        self._pair: tuple | None = None
 
     # ------------------------------------------------------------- engines
 
@@ -650,11 +670,13 @@ class QueryDispatcher:
         if key not in self._graphs:
             # pad for mesh.size so every policy's graph shares one n_pad and
             # phase-1 state can resume on the phase-2 graph unchanged
-            ops, n_pad = prepare_graph(
-                self.csr, self.mesh, policy, self.max_deg,
-                pad_shards=self.mesh.size, extend=spec,
-                version=self.operands_version, stream=self.stream,
-            )
+            with TraceAnnotation("repro.dispatch.build_operands",
+                                 graph_axes=str(policy.graph_axes)):
+                ops, n_pad = prepare_graph(
+                    self.csr, self.mesh, policy, self.max_deg,
+                    pad_shards=self.mesh.size, extend=spec,
+                    version=self.operands_version, stream=self.stream,
+                )
             self._graphs[key] = OperandBundle(
                 ops=ops, n_pad=n_pad, version=self.operands_version,
                 policy=policy, spec=spec,
@@ -815,45 +837,38 @@ class QueryDispatcher:
         max_iters: int | None = None,
         state_layout: str = "replicated",
         extend: ExtendSpec = ExtendSpec(),
-        operands=None,
         collect_stats: bool = False,
         morsel_shape=None,
         epoch: int | None = None,
     ):
         cap = int(max_iters if max_iters is not None else self.max_iters)
-        if operands is None and (
-            extend.needs_binned or extend.needs_rev or extend.needs_blocks
-        ):
-            bundle = self._graph_for(policy, extend)
-            operands = bundle.ops
-            if epoch is None:
-                epoch = self._spec_epoch(bundle, extend)
+        if epoch is None:
+            epoch = self._spec_epoch(self._graph_for(policy, extend), extend)
         key = EngineKey(
             kind, policy, edge_compute, n_pad, cap, state_layout, extend,
-            collect_stats, int(epoch) if epoch else 0,
+            collect_stats, int(epoch),
         )
         if kind == "static":
             builder = lambda: build_engine(
                 self.mesh, policy, edge_compute, n_pad, cap,
-                state_layout=state_layout, extend=extend, operands=operands,
+                state_layout=state_layout, extend=extend,
                 collect_stats=collect_stats,
             )
         elif kind == "phase1":
             builder = lambda: build_engine(
                 self.mesh, policy, edge_compute, n_pad, cap,
                 state_layout=state_layout, sync="shard", extend=extend,
-                operands=operands, collect_stats=collect_stats,
+                collect_stats=collect_stats,
             )
         elif kind == "resume":
             builder = lambda: build_resume_engine(
                 self.mesh, policy, edge_compute, n_pad, cap, extend=extend,
-                operands=operands, collect_stats=collect_stats,
+                collect_stats=collect_stats,
             )
         elif kind == "gang":
             builder = lambda: build_gang_resume_engine(
                 self.mesh, policy, edge_compute, n_pad, cap, extend=extend,
-                operands=operands, state_layout=state_layout,
-                collect_stats=collect_stats,
+                state_layout=state_layout, collect_stats=collect_stats,
             )
         else:
             raise ValueError(f"unknown engine kind: {kind}")
@@ -933,21 +948,42 @@ class QueryDispatcher:
     def _record_samples(self, stats: np.ndarray, trips: np.ndarray,
                         n_pad: int, push_slots: int,
                         start: np.ndarray | None = None,
-                        phase: int = 1) -> None:
+                        phase: int = 1, pair=None) -> None:
         """Drain one batch's stats-tap buffer into the sample store: one
         fit-consumable record per (real morsel, iteration). ``start``
         gives each morsel's first recorded row (phase-2 taps resume at
         the survivor's absolute phase-1 exit counter; rows below it are
         zero-padding, not samples); ``phase`` labels the records so
-        consumers can split head/tail iteration populations."""
+        consumers can split head/tail iteration populations.
+
+        ``pair``: the (alpha, beta) a direction-switching engine ran
+        under (None for the fixed backends). Each record holds the
+        predicate's inputs as the engine reduced them, so the direction
+        that iteration took is the predicate evaluated here in the same
+        float32 arithmetic — no extra device output — and it counts into
+        ``stats.push_iterations`` / ``pull_iterations`` and their slots.
+        A gang resume decides once for its packed survivors; its records
+        are per member, so there the count is each member's own
+        predicate."""
         store = self._dir_samples.setdefault(
             int(n_pad), collections.deque(maxlen=self._sample_window)
         )
+        if pair is not None:
+            alpha, beta = np.float32(pair[0]), np.float32(pair[1])
+            rows = np.float32(n_pad)
         for i in range(stats.shape[0]):
             j0 = int(start[i]) if start is not None else 0
             for j in range(j0, int(trips[i])):
+                rec = stats[i, j]
+                if pair is not None:
+                    if (rec[1] * alpha > rec[2]) and (rec[0] * beta > rows):
+                        self.stats.pull_iterations += 1
+                        self.stats.pull_slots += max(int(rec[3]), 0)
+                    else:
+                        self.stats.push_iterations += 1
+                        self.stats.push_slots += int(push_slots)
                 n_f, m_f, m_u, pull, _wall, pbytes = (
-                    float(v) for v in stats[i, j]
+                    float(v) for v in rec
                 )
                 store.append({
                     "it": j,
@@ -988,18 +1024,45 @@ class QueryDispatcher:
     def device_report(self) -> dict:
         """What the dispatcher holds on the device and learned there:
         ``operand_bytes`` per placed bundle (keyed by the graph axes it is
-        split over; bundles with equal axes are summed), ``cost_rates``
-        per n_pad as probed so far, and the number of engine iterations
-        ``sampled`` for threshold refits."""
+        split over; bundles with equal axes are summed; the binned
+        forward slabs are in every bundle), ``cost_rates`` per n_pad as
+        probed so far, the number of engine iterations ``sampled`` for
+        threshold refits, and the direction switch's ``directions``
+        counters (``SchedulerStats``)."""
         operand_bytes: dict = {}
         for key, b in self._graphs.items():
             n = sum(x.nbytes for x in jax.tree.leaves(b.ops))
             operand_bytes[key[0]] = operand_bytes.get(key[0], 0) + n
+        st = self.stats
         return {
             "operand_bytes": operand_bytes,
             "cost_rates": {k: dict(v) for k, v in self._cost_rates.items()},
             "sampled": sum(len(v) for v in self._dir_samples.values()),
+            "directions": {
+                "push_iterations": st.push_iterations,
+                "pull_iterations": st.pull_iterations,
+                "push_slots": st.push_slots,
+                "pull_slots": st.pull_slots,
+            },
         }
+
+    def direction_pair(self) -> tuple:
+        """The (alpha, beta) the next launch's direction switch runs: the
+        fitted table's entry for this graph's (family, degree bucket), or
+        Beamer's constants before any table."""
+        if self.direction_thresholds is None:
+            return (BEAMER_ALPHA, BEAMER_BETA)
+        alpha, beta = self.direction_thresholds.lookup(
+            self.family, self.csr.avg_degree
+        )
+        return (float(alpha), float(beta))
+
+    def _pair_operand(self, pair: tuple) -> jax.Array:
+        """The engines' float32 ``[2]`` operand for ``pair``, placed again
+        only when the pair changes (a refit)."""
+        if self._pair is None or self._pair[0] != pair:
+            self._pair = (pair, jnp.asarray(pair, jnp.float32))
+        return self._pair[1]
 
     def online_trace(self, cost: str | None = None) -> dict:
         """The accumulated live samples as a ``BENCH_direction_opt``-shaped
@@ -1057,7 +1120,8 @@ class QueryDispatcher:
     ):
         """Refit ``direction_thresholds`` from the accumulated live
         samples (no-op before any sample lands). ``backend="recommend"``
-        serves the refitted alpha/beta on the next batch. ``cost``
+        serves the refitted alpha/beta on the next batch — as the engine's
+        threshold operand, so nothing recompiles. ``cost``
         overrides the dispatcher's ``cost_mode`` for this one refit
         (measured-cost fits degrade per-record to slots parity when a
         backend's rate could not be probed)."""
@@ -1102,7 +1166,8 @@ class QueryDispatcher:
 
     def _begin_hybrid(self, pol, ec, g, n_pad, morsels, state_layout,
                       extend=ExtendSpec(), n_real=0, buckets=(), epoch=0,
-                      *, reads: HostReads, result_leaves=None):
+                      *, reads: HostReads, result_leaves=None,
+                      pair=(BEAMER_ALPHA, BEAMER_BETA)):
         """Choose the budget, then DISPATCH phase 1 without blocking: jax
         async dispatch returns device futures immediately, so the caller's
         host thread is free until ``_settle_hybrid`` blocks on them.
@@ -1123,7 +1188,9 @@ class QueryDispatcher:
         and settle would have torn the batch across graph versions —
         phase 1 on the old edges, phase 2 on the new. The pinned ops
         keep the pre-delta device buffers alive for exactly as long as
-        the in-flight batch needs them."""
+        the in-flight batch needs them. ``pair`` — the direction switch's
+        (alpha, beta) — is pinned the same way: phase 2 runs under the
+        pair phase 1 ran under."""
         p1, p2 = hybrid_phases(
             pol.source_axes, pol.graph_axes, lanes=pol.lanes,
             or_impl=pol.or_impl,
@@ -1133,14 +1200,15 @@ class QueryDispatcher:
         compiles0 = self.cache.compile_events
         eng1 = self.engine(
             "phase1", p1, ec, n_pad, max_iters=budget,
-            state_layout=state_layout, extend=extend, operands=g,
+            state_layout=state_layout, extend=extend,
             collect_stats=collect, morsel_shape=morsels.shape[:1],
             epoch=epoch,
         )
         b2 = self._graph_for(p2, extend)
         t0 = time.perf_counter()
         with self.cache.launch(compiles0, "phase1"):
-            out1 = eng1(g, morsels)  # async: no block_until_ready
+            # async: no block_until_ready
+            out1 = eng1(g, morsels, thresholds=self._pair_operand(pair))
         if result_leaves is not None:
             res1, stats1 = out1 if collect else (out1, None)
             leaves = {"iterations": res1.iterations, "stats": stats1}
@@ -1156,7 +1224,7 @@ class QueryDispatcher:
             "n_real": n_real, "budget": budget, "collect": collect,
             "out1": out1, "t0": t0, "epoch": epoch, "reads": reads,
             "g2": b2.ops, "n_pad2": b2.n_pad,
-            "epoch2": self._spec_epoch(b2, extend),
+            "epoch2": self._spec_epoch(b2, extend), "pair": pair,
         }
 
     def _settle_hybrid(self, inf) -> SettledBatch:
@@ -1169,6 +1237,8 @@ class QueryDispatcher:
         state_layout, extend = inf["state_layout"], inf["extend"]
         n_real, budget, collect = inf["n_real"], inf["budget"], inf["collect"]
         reads = inf["reads"]
+        pair = inf["pair"]
+        counted = pair if extend.direction == "auto" else None
         sharded = state_layout == "sharded"
         with TraceAnnotation("repro.dispatch.wait_device"):
             out1 = jax.block_until_ready(inf["out1"])
@@ -1203,7 +1273,7 @@ class QueryDispatcher:
         if stats1 is not None and n_real > 0:
             self._record_samples(
                 stats1[:n_real], iters1[:n_real], n_pad,
-                push_slots=int(np.prod(g.fwd.indices.shape)),
+                push_slots=g.fwd.slots, pair=counted,
             )
         if idx.size == 0:
             return SettledBatch(QueryOutcome(
@@ -1249,18 +1319,19 @@ class QueryDispatcher:
         if use_gang:
             eng2 = self.engine(
                 "gang", p2, ec, n_pad, state_layout=state_layout,
-                extend=extend, operands=g2, collect_stats=collect,
+                extend=extend, collect_stats=collect,
                 morsel_shape=(kp,), epoch=inf["epoch2"],
             )
             self.stats.gangs += 1
             self.stats.gang_slots += kp
         else:
             eng2 = self.engine(
-                "resume", p2, ec, n_pad, extend=extend, operands=g2,
+                "resume", p2, ec, n_pad, extend=extend,
                 collect_stats=collect, epoch=inf["epoch2"],
             )
         with self.cache.launch(compiles0, "gang" if use_gang else "resume"):
-            out2 = eng2(g2, sub_state, jnp.asarray(sub_it))  # async
+            out2 = eng2(g2, sub_state, jnp.asarray(sub_it),  # async
+                        thresholds=self._pair_operand(pair))
         res2, stats2 = out2 if collect else (out2, None)
         reads.prefetch({"iterations2": res2.iterations, "stats2": stats2})
         # block only the tiny per-morsel counters: phase 2 has then fully
@@ -1277,8 +1348,8 @@ class QueryDispatcher:
             # absolute phase-1 exit counter to its final trip count
             self._record_samples(
                 stats2[: idx.size], iters2[: idx.size], n_pad,
-                push_slots=int(np.prod(g.fwd.indices.shape)),
-                start=sub_it[: idx.size], phase=2,
+                push_slots=g.fwd.slots, start=sub_it[: idx.size],
+                phase=2, pair=counted,
             )
 
         final_iters = iters1.copy()
@@ -1315,7 +1386,8 @@ class QueryDispatcher:
         return SettledBatch(outcome, materialize)
 
     def _run_hybrid(self, pol, ec, g, n_pad, morsels, state_layout,
-                    extend=ExtendSpec(), n_real=0, buckets=(), epoch=0):
+                    extend=ExtendSpec(), n_real=0, buckets=(), epoch=0,
+                    pair=(BEAMER_ALPHA, BEAMER_BETA)):
         """Two-phase hybrid on one morsel batch, synchronously: begin +
         settle + finalize back-to-back. Returns a QueryOutcome whose
         result state is bit-identical to the static engine's.
@@ -1335,13 +1407,13 @@ class QueryDispatcher:
         inf = self._begin_hybrid(
             pol, ec, g, n_pad, morsels, state_layout, extend=extend,
             n_real=n_real, buckets=buckets, epoch=epoch,
-            reads=HostReads(self.stats),
+            reads=HostReads(self.stats), pair=pair,
         )
         return self._settle_hybrid(inf).finalize()
 
     def _begin_static(self, pol, ec, g, n_pad, morsels, state_layout,
                       extend=ExtendSpec(), epoch=0, reads=None,
-                      result_leaves=None):
+                      result_leaves=None, pair=(BEAMER_ALPHA, BEAMER_BETA)):
         """Dispatch the single engine without blocking. With
         ``result_leaves`` the host copies of iterations and those leaves
         are enqueued behind it, under either layout: a static result is
@@ -1349,12 +1421,12 @@ class QueryDispatcher:
         compiles0 = self.cache.compile_events
         eng = self.engine(
             "static", pol, ec, n_pad, state_layout=state_layout,
-            extend=extend, operands=g, morsel_shape=morsels.shape[:1],
-            epoch=epoch,
+            extend=extend, morsel_shape=morsels.shape[:1], epoch=epoch,
         )
         t0 = time.perf_counter()
         with self.cache.launch(compiles0, "static"):
-            res = eng(g, morsels)  # async: no block_until_ready
+            # async: no block_until_ready
+            res = eng(g, morsels, thresholds=self._pair_operand(pair))
         if result_leaves is not None:
             reads.prefetch({
                 "iterations": res.iterations,
@@ -1373,10 +1445,11 @@ class QueryDispatcher:
         ))
 
     def _run_static(self, pol, ec, g, n_pad, morsels, state_layout,
-                    extend=ExtendSpec(), n_real=0, buckets=(), epoch=0):
+                    extend=ExtendSpec(), n_real=0, buckets=(), epoch=0,
+                    pair=(BEAMER_ALPHA, BEAMER_BETA)):
         inf = self._begin_static(
             pol, ec, g, n_pad, morsels, state_layout, extend=extend,
-            epoch=epoch,
+            epoch=epoch, pair=pair,
         )
         return self._settle_static(inf).finalize()
 
@@ -1435,8 +1508,7 @@ class QueryDispatcher:
         if backend == "recommend":
             backend = recommend_backend(
                 ec, self.csr.avg_degree, n_nodes=self.csr.n_nodes,
-                lanes=pol.lanes, family=self.family,
-                thresholds=self.direction_thresholds,
+                lanes=pol.lanes,
             )
         spec = as_spec(backend)
         bundle = self._graph_for(pol, spec)
@@ -1484,7 +1556,7 @@ class QueryDispatcher:
             else np.zeros(0, np.int64)
         )
         return sources, name, pol, ec, spec, g, n_pad, morsels, chunk, \
-            n_real, buckets, epoch
+            n_real, buckets, epoch, self.direction_pair()
 
     def _hybrid_eligible(self, pol, state_layout: str) -> bool:
         return (
@@ -1530,7 +1602,7 @@ class QueryDispatcher:
     def _begin(self, sources, returns_paths, policy, state_layout, backend,
                query_kind) -> InflightBatch:
         (sources, name, pol, ec, spec, g, n_pad, morsels, chunk, n_real,
-         buckets, epoch) = self._plan_query(
+         buckets, epoch, pair) = self._plan_query(
              sources, returns_paths, policy, backend, query_kind)
         reads = HostReads(self.stats)
         if morsels.shape[0] > chunk:
@@ -1541,7 +1613,7 @@ class QueryDispatcher:
                 "sources": sources, "name": name, "pol": pol, "ec": ec,
                 "spec": spec, "g": g, "n_pad": n_pad, "morsels": morsels,
                 "chunk": chunk, "state_layout": state_layout,
-                "epoch": epoch,
+                "epoch": epoch, "pair": pair,
             }
             return InflightBatch("chunked", name, n_real, buckets, payload,
                                  reads)
@@ -1554,12 +1626,13 @@ class QueryDispatcher:
             inf = self._begin_hybrid(
                 pol, ec, g, n_pad, jnp.asarray(morsels), state_layout,
                 extend=spec, n_real=n_real, buckets=buckets, epoch=epoch,
-                reads=reads, result_leaves=leaves,
+                reads=reads, result_leaves=leaves, pair=pair,
             )
             return InflightBatch("hybrid", name, n_real, buckets, inf, reads)
         inf = self._begin_static(
             pol, ec, g, n_pad, jnp.asarray(morsels), state_layout,
             extend=spec, epoch=epoch, reads=reads, result_leaves=leaves,
+            pair=pair,
         )
         return InflightBatch("static", name, n_real, buckets, inf, reads)
 
@@ -1579,7 +1652,7 @@ class QueryDispatcher:
                 outcome = self._run_chunked(
                     p["pol"], p["ec"], p["g"], p["n_pad"], p["morsels"],
                     p["chunk"], p["state_layout"], p["spec"],
-                    inflight.n_real, inflight.buckets, p.get("epoch", 0),
+                    inflight.n_real, inflight.buckets, p["epoch"], p["pair"],
                 )
                 settled = SettledBatch(outcome)
             elif inflight.kind == "hybrid":
@@ -1598,7 +1671,7 @@ class QueryDispatcher:
         return settled.finalize()
 
     def _run_chunked(self, pol, ec, g, n_pad, morsels, chunk, state_layout,
-                     spec, n_real, buckets, epoch=0) -> QueryOutcome:
+                     spec, n_real, buckets, epoch, pair) -> QueryOutcome:
         """The in-flight-cap chunk loop: fixed-size chunks, host-stitched
         into one outcome (learning/stats are applied once by the caller)."""
         run_fn = (
@@ -1619,7 +1692,7 @@ class QueryDispatcher:
                 run_fn(
                     pol, ec, g, n_pad, jnp.asarray(part), state_layout,
                     extend=spec, n_real=real_in,
-                    buckets=buckets[i : i + real_in], epoch=epoch,
+                    buckets=buckets[i : i + real_in], epoch=epoch, pair=pair,
                 )
             )
         result = IFEResult(

@@ -148,3 +148,41 @@ def sssp(csr: CSRGraph, sources) -> np.ndarray:
                 dist[int(v)] = nd
                 heapq.heappush(heap, (nd, int(v)))
     return dist
+
+
+def push_step(csr: CSRGraph, op: str, values: np.ndarray,
+              normalize: bool = False) -> np.ndarray:
+    """One plain push over every edge u -> v of ``csr``: ``values[u]``
+    combined into ``out[v]``. ``op``: "or" (bool or [n, L] uint8 lanes),
+    "min_parent" (min u whose ``values[u]`` is set, per lane when 2-D;
+    2**31 - 1 where none), "min_dist" (min of ``values[u] + w(u, v)``,
+    unit weights when the CSR has none) or "sum" (``values[u]``, divided
+    by u's out-degree with ``normalize``)."""
+    n = csr.n_nodes
+    src, dst = csr.edge_list()
+    src, dst = src.astype(np.int64), dst.astype(np.int64)
+    values = np.asarray(values)
+    if op == "or":
+        out = np.zeros(values.shape, values.dtype)
+        np.maximum.at(out, dst, values[src])
+        return out
+    if op == "min_parent":
+        ids = np.arange(n, dtype=np.int64).reshape((n,) + (1,) * (values.ndim - 1))
+        cand = np.where(values != 0, ids, 2**31 - 1)
+        out = np.full(values.shape, 2**31 - 1, np.int64)
+        np.minimum.at(out, dst, cand[src])
+        return out
+    if op == "min_dist":
+        w = (np.ones(len(src), np.float32) if csr.weights is None
+             else csr.weights)
+        out = np.full(n, np.inf, np.float32)
+        np.minimum.at(out, dst, (values[src] + w).astype(np.float32))
+        return out
+    if op == "sum":
+        v = values.astype(np.float64)
+        if normalize:
+            v = v / np.maximum(csr.degrees, 1)
+        out = np.zeros(n, np.float64)
+        np.add.at(out, dst, v[src])
+        return out
+    raise ValueError(op)

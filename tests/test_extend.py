@@ -20,7 +20,7 @@ from oracle import bfs_levels
 
 from repro.graph.csr import (
     CSRGraph,
-    binned_rev_csr,
+    binned_ell,
     csr_from_edges,
     ell_from_csr,
     truncate_csr,
@@ -219,7 +219,7 @@ def test_truncation_emptied_rows_zero_width_slab():
     assert g0.indices.shape == (64, 0)
     # an edgeless graph's binned reverse: single zero-width slab, zero
     # capacity — scanning it costs nothing
-    bn = binned_rev_csr(eff, 64, shards=1)
+    bn = binned_ell(eff.reverse(), 64, shards=1)
     assert bn.widths == (0,)
     assert bn.capacity_slots == 0
     # and the full pipeline still converges under EVERY backend flavor
@@ -265,7 +265,7 @@ def test_prop_binned_slab_pack_unpack_roundtrip(seed, n, kind):
     eff = truncate_csr(csr, cap)
     shards = 1 if seed % 3 else 2
     n_pad = -(-n // (shards * 8)) * (shards * 8)
-    bn = binned_rev_csr(eff, n_pad, shards=shards)
+    bn = binned_ell(eff.reverse(), n_pad, shards=shards)
     rows_local = n_pad // shards
     rev = eff.reverse()
     rev_deg = np.zeros(n_pad, np.int64)
@@ -520,7 +520,7 @@ def test_binned_operands_rebuild_for_pad_shards():
     assert g.rev_binned is not None
     assert g.rev_binned.inv.shape == (1, n_pad)  # policy has 1 graph shard
     eng = build_engine(
-        mesh, pol, "sp_lengths", n_pad, 64, extend=spec, operands=g
+        mesh, pol, "sp_lengths", n_pad, 64, extend=spec
     )
     srcs = np.array([0, 11, 42], np.int32)
     res = eng(g, jnp.asarray(pad_sources(srcs, 1, 1, n_pad)))
@@ -566,7 +566,7 @@ def test_cost_probe_rates_one_shard_scan(shards):
     )
     rates = BackendCostProbe(reps=1).rates(ops, n_pad)
     assert set(rates) == {"ell_push", "pull_binned"}
-    assert rates["ell_push"]["slots"] == ops.fwd.indices.size // shards
+    assert rates["ell_push"]["slots"] == ops.fwd.capacity_slots
     assert rates["pull_binned"]["slots"] == ops.rev_binned.capacity_slots
     for r in rates.values():
         assert np.isfinite(r["ms_per_slot"]) and r["ms_per_slot"] > 0

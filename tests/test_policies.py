@@ -243,19 +243,15 @@ def test_recommend_backend_deterministic_and_total():
     result on repeated calls across the whole argument grid) and total:
     with an operand bundle it only ever names a backend that bundle can
     actually run."""
-    th = DirectionThresholds(table={("powerlaw", 3): (4.0, 16.0)})
     grid = itertools.product(
         ["sp_lengths", "sp_parents", "bellman_ford", "msbfs_lengths"],
         [2.0, 8.0, 300.0],
         [512, 10**7],
         [1, 64],
-        [None, th],
     )
-    for ec, deg, n, lanes, t in grid:
-        r1 = recommend_backend(ec, deg, n_nodes=n, lanes=lanes,
-                               family="powerlaw", thresholds=t)
-        r2 = recommend_backend(ec, deg, n_nodes=n, lanes=lanes,
-                               family="powerlaw", thresholds=t)
+    for ec, deg, n, lanes in grid:
+        r1 = recommend_backend(ec, deg, n_nodes=n, lanes=lanes)
+        r2 = recommend_backend(ec, deg, n_nodes=n, lanes=lanes)
         assert r1 == r2, (ec, deg, n, lanes)
         as_spec(r1)  # always a constructible spec
 
@@ -268,7 +264,7 @@ def test_recommend_backend_deterministic_and_total():
                           ("msbfs_lengths", 64)]:
             rec = recommend_backend(
                 ec, csr.avg_degree, n_nodes=csr.n_nodes, lanes=lanes,
-                operands=ops, thresholds=th, family="powerlaw",
+                operands=ops,
             )
             spec = as_spec(rec)
             assert not spec.needs_rev or ops.rev is not None

@@ -89,40 +89,40 @@ def tiny_budget(monkeypatch):
 
 
 def test_binned_slab_gathers_chunk_parity(tiny_budget):
+    """The binned pull's scan, cut into many small steps by the forced
+    budget, equals plain unchunked gathers over the same slabs."""
     csr = _hub_graph()
     ops, n_pad = build_operands(csr, extend="pull_binned")
     bn = ops.rev_binned
-    widths = [int(s.shape[-1]) for s in bn.slabs]
     L = 8
     rng = np.random.default_rng(5)
     gl = jnp.asarray((rng.random((n_pad, L)) < 0.3).astype(np.uint8))
 
-    # the hub slab must actually be wider than the forced chunk
-    assert max(widths) > E._deg_chunk(int(bn.slabs[-1].shape[-2]), L)
+    # the scan really takes many steps, more than the hub slab's width
+    step = EC._deg_chunk(1, L)
+    assert max(bn.widths) > step and bn.capacity_slots > 4 * step
 
-    got_reach = np.asarray(E._binned_map(
-        bn, lambda b, s: E._slab_gather_lanes(s, gl),
-        lambda r: jnp.zeros((r, L), gl.dtype),
-    ))
-    got_par = np.asarray(E._binned_map(
-        bn, lambda b, s: E._slab_min_parent_lanes(s, gl),
-        lambda r: jnp.full((r, L), NO_PARENT, jnp.int32),
-    ))
+    ctx = E.ExtendCtx(n_out=n_pad)
+    got_reach = np.asarray(E.BinnedPullBackend._reach_lanes(
+        ops, gl, None, ctx))
+    got_par = np.asarray(E.BinnedPullBackend._min_parent_lanes(
+        ops, gl, None, ctx))
 
-    # oracle: plain unchunked gathers over the same slabs
-    def reach_ref(b, s):
-        return gl.at[s].get(mode="fill", fill_value=0).max(axis=1)
-
-    def par_ref(b, s):
+    # oracle: plain unchunked gathers over the same slabs, un-permuted
+    reach_parts, par_parts = [], []
+    for s in bn.slabs:
+        s = s[0]
         act = gl.at[s].get(mode="fill", fill_value=0)
-        cand = jnp.where(act != 0, s[:, :, None].astype(jnp.int32),
-                         NO_PARENT)
-        return cand.min(axis=1)
-
-    ref_reach = np.asarray(E._binned_map(
-        bn, reach_ref, lambda r: jnp.zeros((r, L), gl.dtype)))
-    ref_par = np.asarray(E._binned_map(
-        bn, par_ref, lambda r: jnp.full((r, L), NO_PARENT, jnp.int32)))
+        if s.shape[1] == 0:
+            reach_parts.append(jnp.zeros((s.shape[0], L), gl.dtype))
+            par_parts.append(jnp.full((s.shape[0], L), NO_PARENT, jnp.int32))
+            continue
+        reach_parts.append(act.max(axis=1))
+        par_parts.append(jnp.where(act != 0, s[:, :, None], NO_PARENT)
+                         .min(axis=1))
+    inv = bn.inv[0]
+    ref_reach = np.asarray(jnp.concatenate(reach_parts)[inv])
+    ref_par = np.asarray(jnp.concatenate(par_parts)[inv])
     np.testing.assert_array_equal(got_reach, ref_reach)
     np.testing.assert_array_equal(got_par, ref_par)
 
@@ -409,7 +409,9 @@ flat = {}
 for kp, leaf in jax.tree_util.tree_flatten_with_path(ops)[0]:
     flat[jax.tree_util.keystr(kp)] = leaf
 names = {
-    ".fwd.indices": "fwd.indices", ".fwd.degrees": "fwd.degrees",
+    ".fwd.nbrs": "fwd.nbrs", ".fwd.rows": "fwd.rows",
+    ".fwd.perm": "fwd.perm", ".fwd.inv": "fwd.inv",
+    ".fwd.degrees": "fwd.degrees",
     ".rev_binned.perm": "bn.perm", ".rev_binned.inv": "bn.inv",
     ".rev_binned_pack.inv_pad": "pack.inv_pad",
 }

@@ -343,7 +343,7 @@ def _static_levels(kind: str, backend: str, srcs: np.ndarray,
         g, n_pad = prepare_graph(csr, mesh11(), policy_ntks(), extend=spec)
         eng = build_engine(
             mesh11(), policy_ntks(), "sp_lengths", n_pad, 64,
-            state_layout=layout, extend=spec, operands=g,
+            state_layout=layout, extend=spec,
         )
         _STATIC_CACHE[key] = (csr, g, n_pad, eng)
     csr, g, n_pad, eng = _STATIC_CACHE[key]
@@ -647,7 +647,7 @@ def test_gang_engine_direct_bellman_ford():
         state0,
     )
     eng = build_gang_resume_engine(
-        mesh11(), p2, "bellman_ford", n_pad, 64, operands=g2
+        mesh11(), p2, "bellman_ford", n_pad, 64
     )
     res = eng(g2, state0, jnp.zeros((4,), jnp.int32))
     dist = np.asarray(res.state.dist)

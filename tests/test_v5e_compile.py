@@ -86,19 +86,29 @@ def _nbytes(tree):
                for x in jax.tree.leaves(tree))
 
 
+def _slot_bytes(*structures):
+    """Bytes of the slot arrays (neighbours and rows) every binned scan
+    reads; placement leaves such as ``perm`` are host bookkeeping that a
+    program may leave out of its arguments."""
+    return sum(_nbytes((bn.nbrs, bn.rows)) for bn in structures)
+
+
 def test_served_engine_compiles_for_v5e(mesh11, served_operands):
     """The phase-1 nTkS ``sp_lengths`` engine the serving loop runs by
     default (sync="shard" with the online-adapt stats tap)."""
     spec, ops, n_pad = served_operands
     eng = build_engine(
         mesh11, policy_ntks(), "sp_lengths", n_pad, 8, sync="shard",
-        extend=spec, operands=ops, collect_stats=True,
+        extend=spec, collect_stats=True,
     )
     morsels = jax.ShapeDtypeStruct(
         (8, 1), jnp.int32, sharding=NamedSharding(mesh11, P("data", None))
     )
-    compiled = eng.fn.lower(ops, morsels).compile()
-    _fits_one_chip(compiled, _nbytes(ops))
+    pair = jax.ShapeDtypeStruct(
+        (2,), jnp.float32, sharding=NamedSharding(mesh11, P())
+    )
+    compiled = eng.fn.lower(ops, morsels, pair).compile()
+    _fits_one_chip(compiled, _slot_bytes(ops.fwd, ops.rev_binned))
     assert "while" in compiled.as_text()
     # the name the device trace's XLA Modules line gives this program
     assert compiled.as_text().startswith(
@@ -122,7 +132,7 @@ def test_extension_step_compiles_for_v5e(mesh11, served_operands, backend,
     ctx = ExtendCtx(n_out=n_pad, row_offset=0)
     step = jax.jit(lambda o, f, v: backend.reach_dense(o, f, v, ctx))
     compiled = step.lower(ops, vec, vec).compile()
-    _fits_one_chip(compiled, _nbytes(getattr(ops, scanned)))
+    _fits_one_chip(compiled, _slot_bytes(getattr(ops, scanned)))
 
 
 @pytest.mark.xfail(
