@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..graph.csr import BinnedEll, EllGraph
+from ..graph.csr import SCAN_CHUNK, BinnedEll, EllGraph
 
 INF_U8 = jnp.uint8(255)
 NO_PARENT = jnp.int32(2**31 - 1)
@@ -279,15 +279,70 @@ def ell_min_parent_lanes(
 #: seconds for a few hundred thousand 64-lane rows); a loop of fixed-size
 #: steps compiles one step, whatever the graph's size
 SCAN_SLOTS = 1 << 14
+#: a gather index no array reaches: ``mode="fill"`` reads give the fill,
+#: ``mode="drop"`` writes vanish
+_OUT_OF_RANGE = np.iinfo(np.int32).max
+
+
+def _any_lane(mask: jax.Array) -> jax.Array:
+    return mask if mask.ndim == 1 else mask.reshape(
+        mask.shape[0], -1).any(axis=1)
+
+
+def rows_off_fill(src: jax.Array, fill) -> jax.Array:
+    """``[rows]`` bool: the rows of ``src`` that differ from ``fill`` in
+    some lane. Under max, min or integer add, ``fill`` is the
+    reduction's identity, so only these rows can change a push."""
+    return _any_lane(src != fill)
+
+
+def unvisited_rows(visited: jax.Array) -> jax.Array:
+    """``[rows]`` bool: the rows some lane has not visited. A pull
+    suppresses every visited lane after its scan, so only these rows can
+    change its result."""
+    return _any_lane(visited == 0)
+
+
+#: entries one matmul of ``prefix_count`` sums
+_PREFIX_BLOCK = 512
+
+
+def prefix_count(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sums (int32) of a 1-D bool vector. A TPU compiles
+    ``jnp.cumsum`` over a few hundred thousand entries in seconds, so
+    each block of ``_PREFIX_BLOCK`` entries is summed by a matmul with a
+    triangular ones matrix (0/1 operands are exact in bfloat16, their
+    sums exact in float32), and the blocks' totals by a short cumsum."""
+    n, b = x.shape[0], _PREFIX_BLOCK
+    blocks = jnp.pad(x.astype(jnp.bfloat16), (0, -n % b)).reshape(-1, b)
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
+           <= jax.lax.broadcasted_iota(jnp.int32, (b, b), 1))
+    inner = jnp.dot(blocks, tri.astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32).astype(jnp.int32)
+    total = inner[:, -1]
+    return (inner + (jnp.cumsum(total) - total)[:, None]).reshape(-1)[:n]
+
+
+def live_chunks(bn: BinnedEll, live: jax.Array) -> jax.Array:
+    """``[n_chunks]`` bool: the ``SCAN_CHUNK``-slot chunks of ``bn``'s
+    first shard that hold a slot of a ``live`` row (``[rows_local]``
+    bool, local row order). ``live`` goes into binned order through
+    ``perm`` (slab-padding rows read False) and is prefix-summed; a
+    chunk is live where the sum rises across its span of positions
+    (``chunk_lo``, ``chunk_hi``): a read a row and two a chunk, none a
+    slot."""
+    m = live.at[bn.perm[0]].get(mode="fill", fill_value=False)
+    c = jnp.concatenate([jnp.zeros((1,), jnp.int32), prefix_count(m)])
+    return c[bn.chunk_hi[0] + 1] > c[bn.chunk_lo[0]]
 
 
 def binned_scan(bn: BinnedEll, src: jax.Array, out: jax.Array,
                 reducer: str, fill, *, pull: bool = False,
-                value=None, slot_add=None) -> jax.Array:
-    """One pass over every slot of ``bn`` (its slabs in bucket order,
+                value=None, slot_add=None, live=None) -> jax.Array:
+    """One pass over the slots of ``bn`` (its slabs in bucket order,
     each row-major: the flat ``nbrs`` / ``rows`` arrays) in fixed steps of
-    at most ``SCAN_SLOTS`` slots — a ``fori_loop`` plus one static tail;
-    the steps, and the ``_deg_chunk`` budget, bound the temps.
+    at most ``SCAN_SLOTS`` slots; the steps, and the ``_deg_chunk``
+    budget, bound the temps.
 
     Push (``pull=False``): each slot reads ``src`` (``[rows_local, ...]``
     per-row values) at its own row — slab-padding slots read ``fill`` —
@@ -298,30 +353,89 @@ def binned_scan(bn: BinnedEll, src: jax.Array, out: jax.Array,
     ``value(got, nbr)`` maps the read values to the reduced ones;
     ``slot_add`` (per-slot weights, ``[K, S]``) is added to them.
 
-    The slots and the reduction equal a single padded scan's, so the
+    ``live`` (``[rows_local]`` bool, local row order) names the rows
+    whose slots can change the result; the caller vouches that every
+    other row's slots reduce identities or are overwritten after the
+    scan. The scan then reads only the chunks that hold a live slot
+    (``live_chunks``): their ids, compacted, feed a ``while_loop`` whose
+    trips each gather ``SCAN_SLOTS // SCAN_CHUNK`` chunks and run one
+    step on them; ids past the live ones name no chunk and read as
+    sentinels. Without ``live``, or when every chunk is live, every slot
+    is read in place: a ``fori_loop`` plus one static tail.
+
+    Either way the slots and the reduction are a subset of a single
+    padded scan's whose other slots reduce identities, so the
     order-invariant reductions (max / min / integer add) are bitwise
-    equal to it, and a float add sums each node's contributions in one
-    fixed order."""
+    equal to it; a float add, which is never given ``live``, sums each
+    node's contributions in one fixed order."""
     nbr, own = bn.nbrs[0], bn.rows[0]
     wts = None if slot_add is None else slot_add[0]
     total = int(nbr.shape[0])
     if not total:
         return out
+    assert total % SCAN_CHUNK == 0, total
     item = max(int(np.prod(out.shape[1:], dtype=np.int64)), 1) * max(
         jnp.dtype(out.dtype).itemsize, 1)
     step = min(SCAN_SLOTS, total, _deg_chunk(1, item))
-    read_at, write_at = (nbr, own) if pull else (own, nbr)
 
-    def part(start, width, acc):
-        cut = lambda a: jax.lax.dynamic_slice_in_dim(a, start, width)
-        got = src.at[cut(read_at)].get(mode="fill", fill_value=fill)
+    def reduce(nb, ow, w, acc):
+        read_at, write_at = (nb, ow) if pull else (ow, nb)
+        got = src.at[read_at].get(mode="fill", fill_value=fill)
         if value is not None:
-            got = value(got, cut(nbr))
-        if wts is not None:
-            got = got + cut(wts)
-        return getattr(acc.at[cut(write_at)], reducer)(got, mode="drop")
+            got = value(got, nb)
+        if w is not None:
+            got = got + w
+        return getattr(acc.at[write_at], reducer)(got, mode="drop")
 
-    return chunk_fold(total, step, part, out)
+    def dense(acc):
+        def part(start, width, acc):
+            cut = lambda a: None if a is None else (
+                jax.lax.dynamic_slice_in_dim(a, start, width))
+            return reduce(cut(nbr), cut(own), cut(wts), acc)
+
+        return chunk_fold(total, step, part, acc)
+
+    if live is None:
+        return dense(out)
+
+    per_trip = max(step // SCAN_CHUNK, 1)
+    n_chunks = total // SCAN_CHUNK
+    act = live_chunks(bn, live)
+    rank = prefix_count(act)
+
+    def compact(acc):
+        # the live chunks' ids in order, then n_chunks (no chunk) to the end
+        size = -(-n_chunks // per_trip) * per_trip
+        work = jnp.full((size,), n_chunks, jnp.int32).at[
+            jnp.where(act, rank - 1, size)].set(
+            jnp.arange(n_chunks, dtype=jnp.int32), mode="drop")
+        views = [(None if a is None else a.reshape(n_chunks, SCAN_CHUNK), pad)
+                 for a, pad in ((nbr, _OUT_OF_RANGE), (own, _OUT_OF_RANGE),
+                                (wts, 0))]
+        trips = (rank[-1] + per_trip - 1) // per_trip
+
+        def trip(carry):
+            t, acc = carry
+            ids = jax.lax.dynamic_slice_in_dim(work, t * per_trip, per_trip)
+            nb, ow, w = (
+                None if v is None
+                else v.at[ids].get(mode="fill", fill_value=pad).reshape(-1)
+                for v, pad in views)
+            return t + 1, reduce(nb, ow, w, acc)
+
+        return jax.lax.while_loop(
+            lambda carry: carry[0] < trips, trip, (jnp.int32(0), acc))[1]
+
+    # every chunk live: the plain steps, without the chunk gathers
+    return jax.lax.cond(rank[-1] == n_chunks, dense, compact, out)
+
+
+def _binned_push(bn: BinnedEll, src: jax.Array, out: jax.Array,
+                 reducer: str, fill, **kw) -> jax.Array:
+    """``binned_scan`` in push form over the chunks that hold a row of
+    ``src`` other than ``fill``."""
+    return binned_scan(bn, src, out, reducer, fill,
+                       live=rows_off_fill(src, fill), **kw)
 
 
 def binned_reach_dense(bn: BinnedEll, frontier: jax.Array, row_offset=None,
@@ -332,8 +446,8 @@ def binned_reach_dense(bn: BinnedEll, frontier: jax.Array, row_offset=None,
     int32: a one-byte scatter compiles far slower on a TPU."""
     n = frontier.shape[0] if n_out is None else n_out
     local = _local_rows(frontier, bn.rows_local, row_offset)
-    out = binned_scan(bn, local.astype(jnp.int32),
-                      jnp.zeros((n,), jnp.int32), "max", 0)
+    out = _binned_push(bn, local.astype(jnp.int32),
+                       jnp.zeros((n,), jnp.int32), "max", 0)
     return out > 0
 
 
@@ -344,7 +458,7 @@ def binned_reach_lanes(bn: BinnedEll, lanes: jax.Array, row_offset=None,
     n = lanes.shape[0] if n_out is None else n_out
     local = _local_rows(lanes, bn.rows_local, row_offset)
     out = jnp.zeros((n, lanes.shape[-1]), jnp.uint8)
-    return binned_scan(bn, local, out, "max", 0)
+    return _binned_push(bn, local, out, "max", 0)
 
 
 def binned_min_parent(bn: BinnedEll, frontier: jax.Array, row_offset=None,
@@ -354,7 +468,7 @@ def binned_min_parent(bn: BinnedEll, frontier: jax.Array, row_offset=None,
     cand = jnp.where(local, _row_ids(bn.rows_local, row_offset, row_base),
                      NO_PARENT)
     out = jnp.full((n,), NO_PARENT, jnp.int32)
-    return binned_scan(bn, cand, out, "min", int(NO_PARENT))
+    return _binned_push(bn, cand, out, "min", int(NO_PARENT))
 
 
 def binned_min_parent_lanes(bn: BinnedEll, lanes: jax.Array,
@@ -365,7 +479,7 @@ def binned_min_parent_lanes(bn: BinnedEll, lanes: jax.Array,
     ids = _row_ids(bn.rows_local, row_offset, row_base)[:, None]
     cand = jnp.where(local != 0, ids, NO_PARENT)
     out = jnp.full((n, lanes.shape[-1]), NO_PARENT, jnp.int32)
-    return binned_scan(bn, cand, out, "min", int(NO_PARENT))
+    return _binned_push(bn, cand, out, "min", int(NO_PARENT))
 
 
 def binned_min_dist(bn: BinnedEll, dist: jax.Array, frontier: jax.Array,
@@ -377,10 +491,10 @@ def binned_min_dist(bn: BinnedEll, dist: jax.Array, frontier: jax.Array,
                      row_offset)
     out = jnp.full((n,), jnp.inf, jnp.float32)
     if bn.slot_weights is None:
-        return binned_scan(bn, du, out, "min", jnp.inf,
-                           value=lambda got, nbr: got + 1.0)
-    return binned_scan(bn, du, out, "min", jnp.inf,
-                       slot_add=bn.slot_weights)
+        return _binned_push(bn, du, out, "min", jnp.inf,
+                            value=lambda got, nbr: got + 1.0)
+    return _binned_push(bn, du, out, "min", jnp.inf,
+                        slot_add=bn.slot_weights)
 
 
 def binned_push_sum(bn: BinnedEll, values: jax.Array, row_offset=None,
@@ -588,11 +702,14 @@ class BellmanFord:
     @staticmethod
     def gang_extend(be, ops, state: BellmanFordState, ctx):
         # weighted relax has no saturating lane formulation (float min, not
-        # OR); batch the gang with vmap instead — still one while_loop for
-        # the whole gang, so re-dispatch does not serialize
-        return jax.vmap(
-            lambda st: BellmanFord.extend(be, ops, st, ctx)
-        )(state)
+        # OR); map the members instead — still one while_loop for the
+        # whole gang, so re-dispatch does not serialize. Not vmap: a
+        # batched predicate turns the scans' and the direction switch's
+        # ``lax.cond`` into a select that runs both branches for every
+        # member, so no member's scan could skip its dead chunks
+        return jax.lax.map(
+            lambda st: BellmanFord.extend(be, ops, st, ctx), state
+        )
 
     @staticmethod
     def apply(state: BellmanFordState, cand: jax.Array, it: jax.Array):

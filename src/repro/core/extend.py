@@ -10,8 +10,9 @@ direction-optimizing switch:
 - ``ell_push``  — forward scatter over the **degree-binned forward slabs**
   (``GraphOperands.fwd``, a ``graph.csr.BinnedEll``): every local row
   broadcasts its frontier bit down its out-neighbor list, bucket by
-  bucket. Scan cost is the whole binned structure (~``sum(out_deg)``
-  slots) regardless of frontier density.
+  bucket. It reads only the 128-slot chunks that hold a frontier row
+  (``edge_compute.binned_scan``), at most the whole binned structure
+  (~``sum(out_deg)`` slots).
 - ``ell_pull``  — gather over the *reverse* ELL with visited-suppression:
   each unvisited v scans its in-neighbor list and ORs the frontier bits it
   finds — the classic bottom-up win when frontiers are large, because the
@@ -26,8 +27,9 @@ direction-optimizing switch:
   full scan costs ~``sum(in_deg)`` slots instead of ``n × max_in_deg`` —
   the EmptyHeaded lesson (degree-specialized physical layouts) applied to
   the bottom-up direction, which is what makes pull (and therefore the
-  Beamer switch) profitable on skewed graphs. The push's forward slabs
-  are the same structure built from the forward rows.
+  Beamer switch) profitable on skewed graphs. It reads only the 128-slot
+  chunks that hold a row some lane has not visited. The push's forward
+  slabs are the same structure built from the forward rows.
 - ``pull_binned_fused`` — the same contract and the same binned slabs,
   realized by the fused Pallas kernel (``kernels.binned_pull``): per-slab
   gathers, reductions, the un-permute, and the visited suppression in one
@@ -84,6 +86,7 @@ from ..kernels.binned_pull.ops import (
     build_pack as build_binned_pack,
 )
 from ..graph.csr import (
+    SCAN_CHUNK,
     BinnedEll,
     BinnedPlan,
     CSRGraph,
@@ -114,6 +117,9 @@ from .edge_compute import (
     binned_scan,
     chunk_fold,
     ell_min_topk,
+    live_chunks,
+    rows_off_fill,
+    unvisited_rows,
 )
 
 BACKENDS = (
@@ -328,10 +334,13 @@ def _round8(cap: int) -> int:
     return -(-cap // 8) * 8 if cap > 0 else 0
 
 
+_BINNED_FIELDS = ("nbrs", "rows", "perm", "inv", "degrees", "chunk_lo",
+                  "chunk_hi")
+
+
 def _binned_leaves(prefix: str, bn: BinnedEll) -> dict:
     """Flat ``name -> array`` leaves of one binned structure."""
-    leaves = {f"{prefix}.{f}": getattr(bn, f)
-              for f in ("nbrs", "rows", "perm", "inv", "degrees")}
+    leaves = {f"{prefix}.{f}": getattr(bn, f) for f in _BINNED_FIELDS}
     if bn.slot_weights is not None:
         leaves[f"{prefix}.w"] = bn.slot_weights
     return leaves
@@ -339,8 +348,7 @@ def _binned_leaves(prefix: str, bn: BinnedEll) -> dict:
 
 def _binned_from_leaves(prefix: str, g: dict, plan: BinnedPlan) -> BinnedEll:
     return BinnedEll(
-        **{f: g[f"{prefix}.{f}"]
-           for f in ("nbrs", "rows", "perm", "inv", "degrees")},
+        **{f: g[f"{prefix}.{f}"] for f in _BINNED_FIELDS},
         slot_weights=g.get(f"{prefix}.w"),
         shapes=tuple((int(r), int(w))
                      for r, w in zip(plan.rows_b, plan.widths)),
@@ -865,6 +873,17 @@ def _binned_pull(bn: BinnedEll, src: jax.Array, acc0: jax.Array,
     return binned_scan(bn, src, acc0, reducer, fill, pull=True, **kw)
 
 
+def _local_visited(visited, rows: int, ctx: ExtendCtx):
+    return None if visited is None else _local_state(visited, rows, ctx)
+
+
+def _pull_live(vloc):
+    """The rows a suppressed pull can change (``unvisited_rows`` of this
+    shard's visited rows); None, so every slot is read, when the edge
+    compute keeps no visited set."""
+    return None if vloc is None else unvisited_rows(vloc)
+
+
 class BinnedPullBackend:
     """The ``ell_pull`` contract over the reverse ``BinnedEll`` slabs.
 
@@ -883,21 +902,23 @@ class BinnedPullBackend:
     def _reach_dense(ops, gf, visited, ctx):
         bn = ops.rev_binned
         rows = bn.rows_local
+        vloc = _local_visited(visited, rows, ctx)
         # int32 rows: a one-byte scatter compiles far slower on a TPU
         acc = jnp.zeros((rows,), jnp.int32)
-        reached = _binned_pull(bn, gf.astype(jnp.int32), acc, "max", 0) > 0
+        reached = _binned_pull(bn, gf.astype(jnp.int32), acc, "max", 0,
+                               live=_pull_live(vloc)) > 0
         if visited is not None:
-            reached &= ~_local_state(visited, rows, ctx)
+            reached &= ~vloc
         return _place_rows(reached, ctx, False)
 
     @staticmethod
     def _reach_lanes(ops, gl, visited, ctx):
         bn = ops.rev_binned
         rows = bn.rows_local
+        vloc = _local_visited(visited, rows, ctx)
         acc = jnp.zeros((rows, gl.shape[-1]), gl.dtype)
-        reached = _binned_pull(bn, gl, acc, "max", 0)
+        reached = _binned_pull(bn, gl, acc, "max", 0, live=_pull_live(vloc))
         if visited is not None:
-            vloc = _local_state(visited, rows, ctx)
             reached = jnp.where(vloc != 0, 0, reached)
         return _place_rows(reached, ctx, 0)
 
@@ -905,29 +926,28 @@ class BinnedPullBackend:
     def _min_parent(ops, gf, visited, ctx):
         bn = ops.rev_binned
         rows = bn.rows_local
+        vloc = _local_visited(visited, rows, ctx)
         acc = jnp.full((rows,), NO_PARENT, jnp.int32)
         cand = _binned_pull(
-            bn, gf, acc, "min", False,
+            bn, gf, acc, "min", False, live=_pull_live(vloc),
             value=lambda got, nbr: jnp.where(got, nbr, NO_PARENT),
         )
         if visited is not None:
-            cand = jnp.where(
-                _local_state(visited, rows, ctx), NO_PARENT, cand
-            )
+            cand = jnp.where(vloc, NO_PARENT, cand)
         return _place_rows(cand, ctx, NO_PARENT)
 
     @staticmethod
     def _min_parent_lanes(ops, gl, visited, ctx):
         bn = ops.rev_binned
         rows = bn.rows_local
+        vloc = _local_visited(visited, rows, ctx)
         acc = jnp.full((rows, gl.shape[-1]), NO_PARENT, jnp.int32)
         cand = _binned_pull(
-            bn, gl, acc, "min", 0,
+            bn, gl, acc, "min", 0, live=_pull_live(vloc),
             value=lambda got, nbr: jnp.where(got != 0, nbr[:, None],
                                              NO_PARENT),
         )
         if visited is not None:
-            vloc = _local_state(visited, rows, ctx)
             cand = jnp.where(vloc != 0, NO_PARENT, cand)
         return _place_rows(cand, ctx, NO_PARENT)
 
@@ -1258,7 +1278,7 @@ def _predicate_locals(ops, frontier, visited, ctx: ExtendCtx):
 
 #: columns of one ``frontier_stats`` sample (and of the ``collect_stats``
 #: carry rows the engine builders write)
-STATS_WIDTH = 6
+STATS_WIDTH = 8
 #: bytes one int32 adjacency slot streams through an extension scan
 #: (4 B neighbor id + 1 B activation read/write) — the analytic factor the
 #: measured-cost lane multiplies slot counts by
@@ -1267,15 +1287,23 @@ BYTES_PER_SLOT = 5.0
 
 def frontier_stats(ops, state, ctx: ExtendCtx, bin_widths=None):
     """One per-iteration sample for the online direction-threshold
-    learner: ``[n_f, m_f, m_u, pull_slots_binned, wall_ms, pull_bytes]``
-    (float32, reduced over ``ctx.axes``) of the state ABOUT to extend —
-    the inputs of the Beamer predicate plus the slots a degree-binned
-    pull would scan at this state (the widths of the still-unvisited
-    rows; full capacity when the edge compute keeps no visited set).
-    ``bin_widths`` is this shard's per-local-row slab width vector; when
-    the engine's operands carry no binned slabs the cost columns are the
-    sentinel ``-1`` and the record is skipped by
-    ``fit_direction_thresholds``.
+    learner: ``[n_f, m_f, m_u, pull_slots_binned, wall_ms, pull_bytes,
+    push_chunks, pull_chunks]`` (float32, reduced over ``ctx.axes``) of
+    the state ABOUT to extend — the inputs of the Beamer predicate plus
+    the slots a degree-binned pull would scan at this state (the widths
+    of the still-unvisited rows; full capacity when the edge compute
+    keeps no visited set). ``bin_widths`` is this shard's per-local-row
+    slab width vector; when the engine's operands carry no binned slabs
+    the cost columns are the sentinel ``-1`` and the record is skipped
+    by ``fit_direction_thresholds``.
+
+    ``push_chunks`` / ``pull_chunks`` are the ``SCAN_CHUNK``-slot chunks
+    the push over ``ops.fwd`` and the binned pull over
+    ``ops.rev_binned`` would read at this state (``live_chunks`` of the
+    frontier rows, resp. of the rows some lane has not visited: every
+    chunk without a visited set). Both are ``-1``, and cost no device
+    work, when the operands carry no reverse binned slabs: no binned
+    direction switch runs there.
 
     The measured-cost lane: ``pull_bytes`` is the device-computable
     analytic stream volume (``BYTES_PER_SLOT`` × slots); ``wall_ms`` is a
@@ -1299,14 +1327,28 @@ def frontier_stats(ops, state, ctx: ExtendCtx, bin_widths=None):
         pull = bin_widths.sum()
     else:
         pull = jnp.sum(bin_widths * unvis)
+    rows = ops.fwd.rows_local
+    rev = ops.rev_binned
+    push_chunks = pull_chunks = jnp.float32(0.0)
+    if rev is not None:
+        push_chunks = live_chunks(
+            ops.fwd, rows_off_fill(_local_state(frontier, rows, ctx), 0)
+        ).sum(dtype=jnp.float32)
+        pull_chunks = jnp.float32(rev.scan_slots // SCAN_CHUNK) if (
+            visited is None) else live_chunks(
+            rev, unvisited_rows(_local_state(visited, rows, ctx))
+        ).sum(dtype=jnp.float32)
     stats = jnp.stack(
-        [n_f, m_f, m_u, pull, jnp.float32(0.0), pull * BYTES_PER_SLOT]
+        [n_f, m_f, m_u, pull, jnp.float32(0.0), pull * BYTES_PER_SLOT,
+         push_chunks, pull_chunks]
     )
     if ctx.axes:
         stats = lax.psum(stats, ctx.axes)
     stats = stats.at[4].set(-1.0)  # wall: host-filled, never device-summed
     if bin_widths is None:
         stats = stats.at[3].set(-1.0).at[5].set(-1.0)
+    if rev is None:
+        stats = stats.at[6:].set(-1.0)
     return stats
 
 
@@ -1448,7 +1490,9 @@ class BackendCostProbe:
     ``rates(ops, n_pad)`` times one jitted ``reach_dense`` step per backend
     the operand bundle supports (push always; jnp binned pull and the fused
     kernel when their operands are present) against a half-full frontier,
-    and divides by each backend's full-scan slot count. The resulting
+    and divides by the slots each step read: the push's live chunks, the
+    binned pull's every chunk (nothing is visited), the fused kernel's
+    full scan. The resulting
     ms/slot rates convert the slot columns of ``frontier_stats`` samples
     into per-iteration wall estimates without perturbing the engines — the
     probe runs out-of-band on the same device-placed operands.
@@ -1488,10 +1532,14 @@ class BackendCostProbe:
             jnp.arange(n_pad) < max(n_pad // 2, 1)
         )  # half-full: both directions do real work
         visited = jnp.zeros(n_pad, jnp.bool_)
-        probes = {"ell_push": (PushBackend, ops.fwd.capacity_slots)}
+        # one program: op-by-op, the readout's ops would compile one each
+        push_chunks = jax.jit(
+            lambda bn, f: live_chunks(bn, f[: bn.rows_local]).sum()
+        )(ops.fwd, frontier)
+        probes = {"ell_push": (PushBackend, SCAN_CHUNK * int(push_chunks))}
         if ops.rev_binned is not None:
             probes["pull_binned"] = (
-                BinnedPullBackend, ops.rev_binned.capacity_slots
+                BinnedPullBackend, ops.rev_binned.scan_slots
             )
         if ops.rev_binned_pack is not None:
             probes["pull_binned_fused"] = (

@@ -255,6 +255,34 @@ def truncate_csr(csr: CSRGraph, max_deg: Optional[int]) -> CSRGraph:
     )
 
 
+#: slots of one chunk of a binned structure's flat slot arrays: the unit a
+#: scan skips by (``BinnedEll.chunk_lo`` / ``chunk_hi``)
+SCAN_CHUNK = 128
+
+
+def _chunk_spans(shapes) -> tuple[np.ndarray, np.ndarray]:
+    """Binned positions of the first and the last row that each
+    ``SCAN_CHUNK``-slot chunk of the flat slot arrays touches, for slabs
+    of ``shapes`` (``(rows_b, width_b)`` each) stored one after another,
+    row-major."""
+    rows_b = np.asarray([r for r, _ in shapes], np.int64)
+    widths = np.asarray([w for _, w in shapes], np.int64)
+    size = rows_b * widths
+    total = int(size.sum())
+    off = np.concatenate([[0], np.cumsum(size)])
+    first = np.concatenate([[0], np.cumsum(rows_b)])[:-1]
+
+    def pos(s):
+        # the slab holding slot s: the last whose offset is <= s, which
+        # skips every slab with no slots
+        b = np.searchsorted(off, s, side="right") - 1
+        return first[b] + (s - off[b]) // widths[b]
+
+    a = np.arange(0, total, SCAN_CHUNK, dtype=np.int64)
+    last = np.minimum(a + SCAN_CHUNK - 1, total - 1)
+    return pos(a).astype(np.int32), pos(last).astype(np.int32)
+
+
 @dataclasses.dataclass(frozen=True)
 class BinnedEll:
     """Degree-binned adjacency slabs: the scan operand of both directions
@@ -286,6 +314,15 @@ class BinnedEll:
     bit-identically. ``degrees[k, r]`` is local row ``r``'s degree (its
     kept edges).
 
+    The flat arrays end in sentinel slots up to a whole number of
+    ``SCAN_CHUNK``-slot chunks, and ``chunk_lo[k, c]`` / ``chunk_hi[k, c]``
+    are the binned positions of the first and the last row chunk ``c``
+    touches: slots are row-major in binned order, so a chunk's rows are
+    one span of positions, and a scan can tell from per-row activity
+    alone which chunks hold a slot that can change its result
+    (``edge_compute.live_chunks``). The spans follow from ``shapes``
+    alone, so they hold across shards and across in-place delta folds.
+
     Zero-degree rows (including rows emptied by degree truncation) live
     in a genuine **zero-width** slab: they cost nothing to scan. A full
     scan costs ~``sum(deg)`` slots instead of a single padded slab's
@@ -297,6 +334,8 @@ class BinnedEll:
     perm: jax.Array  # [K, rows_binned] int32 (binned pos -> local row)
     inv: jax.Array  # [K, rows_local] int32 (local row -> binned pos)
     degrees: jax.Array  # [K, rows_local] int32 (kept edges per row)
+    chunk_lo: jax.Array  # [K, S / SCAN_CHUNK] int32 (first row's position)
+    chunk_hi: jax.Array  # [K, S / SCAN_CHUNK] int32 (last row's position)
     slot_weights: Optional[jax.Array] = None  # [K, S] float32
     shapes: tuple = ()  # static: (rows_b, width_b) of every bucket
 
@@ -342,14 +381,20 @@ class BinnedEll:
 
     @property
     def capacity_slots(self) -> int:
-        """Total adjacency slots of one shard's full scan (what one device
-        scans a push, or a binned pull, an iteration)."""
-        return int(self.nbrs.shape[-1])
+        """Adjacency slots of one shard's slabs (the trailing chunk padding
+        left out)."""
+        return int(sum(r * w for r, w in self.shapes))
 
     @property
     def slots(self) -> int:
         """Adjacency slots over every shard the structure holds."""
-        return int(self.nbrs.shape[0] * self.nbrs.shape[-1])
+        return int(self.nbrs.shape[0]) * self.capacity_slots
+
+    @property
+    def scan_slots(self) -> int:
+        """Slots of one shard's flat arrays, whole chunks: what a scan
+        that skips no chunk reads."""
+        return int(self.nbrs.shape[-1])
 
     def row_widths(self) -> np.ndarray:
         """[K, rows_local] host array: each local row's slab width — the
@@ -362,7 +407,8 @@ class BinnedEll:
 
 jax.tree_util.register_dataclass(
     BinnedEll,
-    data_fields=["nbrs", "rows", "perm", "inv", "degrees", "slot_weights"],
+    data_fields=["nbrs", "rows", "perm", "inv", "degrees", "chunk_lo",
+                 "chunk_hi", "slot_weights"],
     meta_fields=["shapes"],
 )
 
@@ -508,18 +554,25 @@ def binned_shard(plan: BinnedPlan, k: int, local: CSRGraph) -> BinnedEll:
                 wslab[slot_in_bucket[sel][flat], slots] = local.weights[src]
         slabs.append(slab)
         slab_w.append(wslab)
-    flat = lambda parts, dt: np.concatenate(
-        [x.reshape(-1) for x in parts]).astype(dt)[None]
+    shapes = tuple(
+        (int(r), int(w)) for r, w in zip(plan.rows_b, plan.widths)
+    )
+    # sentinel slots up to a whole chunk: their neighbour and row drop
+    pad = -sum(r * w for r, w in shapes) % SCAN_CHUNK
+    flat = lambda parts, dt, fill: np.concatenate(
+        [x.reshape(-1) for x in parts] + [np.full(pad, fill, dt)]
+    ).astype(dt)[None]
+    lo, hi = _chunk_spans(shapes)
     return BinnedEll(
-        nbrs=flat(slabs, np.int32),
-        rows=flat(slab_r, np.int32),
+        nbrs=flat(slabs, np.int32, n_pad),
+        rows=flat(slab_r, np.int32, rl),
         perm=perm,
         inv=inv,
         degrees=degs_k.astype(np.int32)[None],
-        slot_weights=flat(slab_w, np.float32) if has_w else None,
-        shapes=tuple(
-            (int(r), int(w)) for r, w in zip(plan.rows_b, plan.widths)
-        ),
+        chunk_lo=lo[None],
+        chunk_hi=hi[None],
+        slot_weights=flat(slab_w, np.float32, 0) if has_w else None,
+        shapes=shapes,
     )
 
 

@@ -30,7 +30,7 @@ from ..configs import base as cfgbase
 from ..core.dispatcher import build_engine, pad_sources, _axes_size
 from ..core.policies import POLICIES
 from ..core.extend import GraphOperands
-from ..graph.csr import BinnedEll
+from ..graph.csr import SCAN_CHUNK, BinnedEll
 from ..graph.partition import padded_n
 from ..models import dcn_v2 as dcn
 from ..models import transformer as tfm
@@ -769,15 +769,19 @@ def _paper_cell(spec, shape, mesh, multi_pod,
         state_layout=state_layout,
     )
     # the forward slabs as one bucket of width max_deg (the dry run has
-    # no degree sequence to bin): rows_local x max_deg slots a shard
+    # no degree sequence to bin): rows_local x max_deg slots a shard, in
+    # whole scan chunks
     rows_local = n_pad // shards
-    slots = rows_local * max_deg
+    chunks = -(-rows_local * max_deg // SCAN_CHUNK)
+    slots = chunks * SCAN_CHUNK
     graph = GraphOperands(fwd=BinnedEll(
         nbrs=sds((shards, slots), jnp.int32),
         rows=sds((shards, slots), jnp.int32),
         perm=sds((shards, rows_local), jnp.int32),
         inv=sds((shards, rows_local), jnp.int32),
         degrees=sds((shards, rows_local), jnp.int32),
+        chunk_lo=sds((shards, chunks), jnp.int32),
+        chunk_hi=sds((shards, chunks), jnp.int32),
         shapes=((rows_local, max_deg),),
     ))
     src_shards = _axes_size(mesh, sa)

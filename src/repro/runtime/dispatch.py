@@ -61,7 +61,9 @@ tests/test_serving.py).
 ``SchedulerStats.d2h_prefetched`` / ``d2h_blocking`` count the result
 leaves read to the host with and without a copy enqueued at launch;
 ``push_iterations`` / ``pull_iterations`` and ``push_slots`` /
-``pull_slots`` count the direction switch's choices (see
+``pull_slots`` count the direction switch's choices, and
+``scanned_slots`` / ``dense_slots`` the slots those scans read against
+what a scan that skips no chunk reads (see
 ``QueryDispatcher._record_samples``).
 """
 from __future__ import annotations
@@ -108,7 +110,7 @@ from ..core import (
 from ..core.dispatcher import _axes_size
 from ..core.extend import BEAMER_ALPHA, BEAMER_BETA, GraphOperands, \
     effective_csr
-from ..graph.csr import CSRGraph
+from ..graph.csr import SCAN_CHUNK, CSRGraph
 from ..graph.delta import (
     DeltaReport,
     GraphDelta,
@@ -363,6 +365,11 @@ class SchedulerStats:
     pull_iterations: int = 0
     push_slots: int = 0
     pull_slots: int = 0
+    # over the same iterations, where the chosen direction scans through
+    # ``binned_scan``: the slots it read (SCAN_CHUNK x the chunks that
+    # hold a live row) and the slots a scan that skips nothing reads
+    scanned_slots: int = 0
+    dense_slots: int = 0
 
     @property
     def gang_occupancy(self) -> float:
@@ -948,7 +955,8 @@ class QueryDispatcher:
     def _record_samples(self, stats: np.ndarray, trips: np.ndarray,
                         n_pad: int, push_slots: int,
                         start: np.ndarray | None = None,
-                        phase: int = 1, pair=None) -> None:
+                        phase: int = 1, pair=None,
+                        dense=(None, None)) -> None:
         """Drain one batch's stats-tap buffer into the sample store: one
         fit-consumable record per (real morsel, iteration). ``start``
         gives each morsel's first recorded row (phase-2 taps resume at
@@ -964,7 +972,11 @@ class QueryDispatcher:
         ``stats.push_iterations`` / ``pull_iterations`` and their slots.
         A gang resume decides once for its packed survivors; its records
         are per member, so there the count is each member's own
-        predicate."""
+        predicate. ``dense`` gives the push's and the pull's slots with
+        no chunk skipped (``_dense_slots``; None where that direction
+        does not scan through ``binned_scan``): the chosen direction's
+        live chunks and these count into ``scanned_slots`` /
+        ``dense_slots``."""
         store = self._dir_samples.setdefault(
             int(n_pad), collections.deque(maxlen=self._sample_window)
         )
@@ -979,11 +991,16 @@ class QueryDispatcher:
                     if (rec[1] * alpha > rec[2]) and (rec[0] * beta > rows):
                         self.stats.pull_iterations += 1
                         self.stats.pull_slots += max(int(rec[3]), 0)
+                        chunks, full = rec[7], dense[1]
                     else:
                         self.stats.push_iterations += 1
                         self.stats.push_slots += int(push_slots)
+                        chunks, full = rec[6], dense[0]
+                    if full is not None and chunks >= 0:
+                        self.stats.scanned_slots += SCAN_CHUNK * int(chunks)
+                        self.stats.dense_slots += full
                 n_f, m_f, m_u, pull, _wall, pbytes = (
-                    float(v) for v in rec
+                    float(v) for v in rec[:6]
                 )
                 store.append({
                     "it": j,
@@ -995,6 +1012,17 @@ class QueryDispatcher:
                     "pull_slots_binned": None if pull < 0 else pull,
                     "pull_bytes_binned": None if pbytes < 0 else pbytes,
                 })
+
+    @staticmethod
+    def _dense_slots(ops: GraphOperands, extend: ExtendSpec) -> tuple:
+        """``(push, pull)`` slots a scan that skips no chunk reads over
+        every shard of ``ops``: the forward slabs, and the reverse binned
+        slabs where the direction switch pulls through them (None
+        otherwise)."""
+        whole = lambda bn: int(bn.nbrs.shape[0]) * bn.scan_slots
+        rev = ops.rev_binned
+        binned = extend.pull == "binned" and rev is not None
+        return whole(ops.fwd), whole(rev) if binned else None
 
     def _rates_for(self, n_pad: int) -> dict:
         """Measured per-backend ms/slot rates for ``n_pad``, probed lazily
@@ -1043,6 +1071,8 @@ class QueryDispatcher:
                 "pull_iterations": st.pull_iterations,
                 "push_slots": st.push_slots,
                 "pull_slots": st.pull_slots,
+                "scanned_slots": st.scanned_slots,
+                "dense_slots": st.dense_slots,
             },
         }
 
@@ -1274,6 +1304,7 @@ class QueryDispatcher:
             self._record_samples(
                 stats1[:n_real], iters1[:n_real], n_pad,
                 push_slots=g.fwd.slots, pair=counted,
+                dense=self._dense_slots(g, extend),
             )
         if idx.size == 0:
             return SettledBatch(QueryOutcome(
@@ -1349,7 +1380,7 @@ class QueryDispatcher:
             self._record_samples(
                 stats2[: idx.size], iters2[: idx.size], n_pad,
                 push_slots=g.fwd.slots, start=sub_it[: idx.size],
-                phase=2, pair=counted,
+                phase=2, pair=counted, dense=self._dense_slots(g2, extend),
             )
 
         final_iters = iters1.copy()

@@ -7,7 +7,14 @@ collectives merge them), with zero-degree rows, a degree cap and weights;
 the streamed build and a delta fold against the wholesale build; the
 served path's choice of the binned direction switch for 64 lanes; and a
 threshold refit that moves alpha/beta without compiling anything.
+
+The scans that read only the live 128-slot chunks (``binned_scan`` given
+``live``) against the scan that reads every slot, push and pull, bit for
+bit; and the dispatcher's ``scanned_slots`` / ``dense_slots`` counters
+against chunks counted here from the slot arrays.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,9 +31,14 @@ from repro.core.edge_compute import (
     binned_push_sum,
     binned_reach_dense,
     binned_reach_lanes,
+    SCAN_SLOTS,
+    binned_scan,
+    rows_off_fill,
+    unvisited_rows,
 )
 from repro.core.extend import effective_csr
-from repro.graph.csr import CSRGraph, csr_from_edges
+from repro.core.policies import DirectionThresholds
+from repro.graph.csr import SCAN_CHUNK, CSRGraph, csr_from_edges
 from repro.graph.delta import diff_effective, fold_operands, random_delta, \
     apply_delta_csr
 from repro.graph.generators import erdos_renyi, powerlaw
@@ -39,9 +51,10 @@ def mesh11():
 
 
 def _graph(kind: str, seed: int, weighted: bool) -> CSRGraph:
-    """A seeded graph whose last 9 nodes have no edges at all."""
-    n = 150
-    g = (powerlaw(n - 9, 5.0, seed=seed) if kind == "pl"
+    """A seeded graph whose last 9 nodes have no edges at all;
+    ``pl_large`` holds more slots than one scan step."""
+    n = 8000 if kind == "pl_large" else 150
+    g = (powerlaw(n - 9, 5.0, seed=seed) if kind.startswith("pl")
          else erdos_renyi(n - 9, 4.0, seed=seed))
     src, dst = g.edge_list()
     w = None
@@ -129,6 +142,177 @@ def test_binned_push_entry_points_match_plain_push(kind, K, cap):
             == int(NO_PARENT)).all()
     # every row's slab width stays within 1.1 of its degree
     assert ops.fwd.row_widths().sum() <= 1.1 * eff.n_edges + 1e-9
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("reducer", "fill", "pull", "value"))
+def _both_scans(bn, src, out, live, *, reducer, fill, pull=False,
+                value=None):
+    """The scan of every slot and the scan of the live chunks, in one
+    program (compiled once a shape)."""
+    kw = dict(pull=pull, value=value)
+    return (binned_scan(bn, src, out, reducer, fill, **kw),
+            binned_scan(bn, src, out, reducer, fill, live=live, **kw))
+
+
+def _parent_of(got, nbr):
+    return jnp.where(got != 0, nbr, NO_PARENT)
+
+
+def _parent_of_lanes(got, nbr):
+    return jnp.where(got != 0, nbr[:, None], NO_PARENT)
+
+
+def _case_rows(bn, case: str, lanes: int):
+    """``[rows_local, lanes]`` bool: the lanes in which each local row of
+    ``bn`` (shard 0) is live for one frontier ``case``."""
+    rl = bn.rows_local
+    rows = np.zeros(rl, bool)
+    if case in ("full", "one_lane_left"):
+        rows[:] = True
+    elif case == "first_half":
+        # the rows the first half of the chunks touch
+        perm = np.asarray(bn.perm[0])
+        upto = perm[:int(bn.chunk_lo[0, bn.chunk_lo.shape[1] // 2])]
+        rows[upto[upto < rl]] = True
+    elif case == "last_chunk":
+        # the last real row of the last chunk that touches one (a shard's
+        # last chunks may hold only the slab-padding rows of K > 1)
+        perm = np.asarray(bn.perm[0])
+        for lo, hi in zip(np.asarray(bn.chunk_lo[0])[::-1],
+                          np.asarray(bn.chunk_hi[0])[::-1]):
+            real = perm[lo:hi + 1][perm[lo:hi + 1] < rl]
+            if real.size:
+                rows[real[-1]] = True
+                break
+    mask = np.ones((rl, lanes), bool)
+    if case == "one_lane_left":
+        mask = np.arange(lanes)[None, :] == (np.arange(rl) % lanes)[:, None]
+    return rows[:, None] & mask
+
+
+@pytest.mark.parametrize("kind, K, case", [
+    (kind, K, case)
+    for kind in ("pl", "er") for K in (1, 2)
+    for case in ("empty", "full", "first_half", "last_chunk", "one_lane_left")
+] + [("pl_large", 1, case) for case in ("full", "first_half", "last_chunk")])
+def test_live_chunk_scan_equals_the_dense_scan(kind, K, case):
+    """Push and pull, max, min and integer add, lanes and dense: the scan
+    that reads only the chunks holding a live row equals the scan of
+    every slot bit for bit (the pull after its visited suppression).
+    ``one_lane_left``: every row is live in one lane only (pull: visited
+    in every lane but one); ``pl_large`` takes several scan steps."""
+    L = 8
+    csr = _graph(kind, {"pl": 3, "er": 5, "pl_large": 9}[kind] + K,
+                 weighted=False)
+    ops, n_pad = build_operands(csr, "dopt_binned", shards=K)
+    # the slabs end mid-chunk: the sentinel padding is in play
+    assert ops.fwd.capacity_slots % SCAN_CHUNK
+    assert ops.fwd.scan_slots % SCAN_CHUNK == 0
+    if kind == "pl_large":
+        assert ops.fwd.scan_slots > SCAN_SLOTS  # several steps
+    rng = np.random.default_rng(K)
+    ids = lambda k, rl: jnp.arange(rl, dtype=jnp.int32)[:, None] + k * rl
+
+    def both(bn, src, out, reducer, fill, live, **kw):
+        return tuple(np.asarray(x) for x in _both_scans(
+            bn, src, out, live, reducer=reducer, fill=fill, **kw))
+
+    checked = 0
+    for k in range(K):
+        fwd = jax.tree.map(lambda x: x[k:k + 1], ops.fwd)
+        rev = jax.tree.map(lambda x: x[k:k + 1], ops.rev_binned)
+        rl = fwd.rows_local
+        on = _case_rows(fwd, case, L)
+        vals = np.where(on, rng.integers(1, 7, on.shape), 0)
+        pushes = []
+        for lanes_src in (vals, vals[:, :1]):  # lanes, then one lane
+            src = jnp.asarray(lanes_src.astype(np.int32))
+            n_l = src.shape[1]
+            cand = jnp.where(src != 0, ids(k, rl), NO_PARENT)
+            for s, red, fill, out in (
+                (src, "max", 0, jnp.zeros((n_pad, n_l), jnp.int32)),
+                (cand, "min", int(NO_PARENT),
+                 jnp.full((n_pad, n_l), NO_PARENT, jnp.int32)),
+                (src, "add", 0, jnp.zeros((n_pad, n_l), jnp.int32)),
+            ):
+                if n_l == 1:  # the dense state's [rows] vectors
+                    s, out = s[:, 0], out[:, 0]
+                pushes.append(both(fwd, s, out, red, fill,
+                                   rows_off_fill(s, fill)))
+        on = _case_rows(rev, case, L)
+        gl = jnp.asarray(rng.integers(0, 2, (n_pad, L)).astype(np.uint8))
+        counts = jnp.asarray(rng.integers(0, 7, (n_pad, L)).astype(np.int32))
+        pulls = []
+        for vis in (np.where(on, 0, 1).astype(np.uint8), ~on[:, 0]):
+            lanes = vis.ndim == 2
+            shape = (rl, L) if lanes else (rl,)
+            act, cnt = (gl, counts) if lanes else (gl[:, 0], counts[:, 0])
+            vloc = jnp.asarray(vis)
+            parent = _parent_of_lanes if lanes else _parent_of
+            for src, red, fill, out, kw in (
+                (act, "max", 0, jnp.zeros(shape, jnp.uint8), {}),
+                (act, "min", 0, jnp.full(shape, NO_PARENT, jnp.int32),
+                 {"value": parent}),
+                (cnt, "add", 0, jnp.zeros(shape, jnp.int32), {}),
+            ):
+                dense, sparse = both(rev, src, out, red, fill,
+                                     unvisited_rows(vloc), pull=True, **kw)
+                seen = np.asarray(vloc != 0)
+                pulls.append((np.where(seen, 0, dense),
+                              np.where(seen, 0, sparse)))
+        for dense, sparse in pushes + pulls:
+            np.testing.assert_array_equal(sparse, dense)
+            checked += 1
+        if case == "full":  # the scans did real work
+            assert any((d != 0).any() for d, _ in pushes[:1] + pulls[:1])
+    assert checked == 12 * K
+
+
+@pytest.mark.parametrize("direction", ["push", "pull", "isolated"])
+def test_scanned_and_dense_slot_counters(direction):
+    """``device_report()``'s ``scanned_slots`` is SCAN_CHUNK times the
+    chunks that hold a live row, counted here from the slot arrays over
+    every iteration of one query, and ``dense_slots`` is the padded slot
+    count times the iterations. The thresholds pin the direction: never
+    pull, or pull whenever the frontier has out-edges. A source with no
+    edges pushes an empty frontier once: 0 slots read."""
+    csr = _graph("pl", 7, weighted=False)
+    n = csr.n_nodes
+    pair = {"push": (0.0, 0.0), "pull": (1e9, 1e9),
+            "isolated": (0.0, 0.0)}[direction]
+    d = QueryDispatcher(
+        mesh11(), csr, backend="dopt_binned", family="powerlaw",
+        direction_thresholds=DirectionThresholds({}, pair),
+    )
+    src = n - 1 if direction == "isolated" else 0
+    assert (csr.degrees[src] == 0) == (direction == "isolated")
+    out = d.query(np.asarray([src], np.int32))
+    levels = np.asarray(out.result.state.levels)[0]
+    ops = d._graphs[next(iter(d._graphs))].ops
+    bn = ops.rev_binned if direction == "pull" else ops.fwd
+    rl = bn.rows_local
+    slot_row = np.asarray(bn.rows[0])
+    depth = int(levels[levels >= 0].max()) + 1  # iterations the query ran
+    want = 0
+    for it in range(depth):
+        if direction == "pull":
+            live = ~((levels >= 0) & (levels <= it))  # not yet visited
+        else:
+            live = levels == it  # the frontier
+        hot = np.zeros(slot_row.shape, bool)
+        real = slot_row < rl
+        hot[real] = live[slot_row[real]]
+        want += SCAN_CHUNK * int(hot.reshape(-1, SCAN_CHUNK).any(1).sum())
+    dirs = d.device_report()["directions"]
+    assert dirs[f"{'pull' if direction == 'pull' else 'push'}_iterations"] \
+        == depth
+    assert dirs["scanned_slots"] == want
+    assert dirs["dense_slots"] == depth * bn.scan_slots
+    if direction == "isolated":
+        assert want == 0 and depth == 1
+    else:
+        assert 0 < want < dirs["dense_slots"]
 
 
 @pytest.mark.parametrize("K", [1, 2, 4])

@@ -556,9 +556,12 @@ def test_extend_spec_validation_and_errors():
 @pytest.mark.parametrize("shards", [1, 4])
 def test_cost_probe_rates_one_shard_scan(shards):
     """The cost="measured" probe times what one device scans: operands
-    stacked for several graph shards are probed on shard 0, so each
-    backend's slot count is its per-shard full scan."""
+    stacked for several graph shards are probed on shard 0, and each
+    backend's slot count is what its step read there: the push's chunks
+    that hold a row of the half-full frontier, the pull's every chunk
+    (nothing visited)."""
     from repro.core.extend import BackendCostProbe
+    from repro.graph.csr import SCAN_CHUNK
 
     csr = powerlaw(200, 5.0, seed=2)
     ops, n_pad = build_operands(
@@ -566,8 +569,14 @@ def test_cost_probe_rates_one_shard_scan(shards):
     )
     rates = BackendCostProbe(reps=1).rates(ops, n_pad)
     assert set(rates) == {"ell_push", "pull_binned"}
-    assert rates["ell_push"]["slots"] == ops.fwd.capacity_slots
-    assert rates["pull_binned"]["slots"] == ops.rev_binned.capacity_slots
+    # shard 0's local rows are the global rows [0, rows_local);
+    # slab-padding slots name row rows_local
+    slot_row = np.asarray(ops.fwd.rows[0])
+    live = slot_row < min(ops.fwd.rows_local, n_pad // 2)
+    hot = live.reshape(-1, SCAN_CHUNK).any(axis=1)
+    assert rates["ell_push"]["slots"] == SCAN_CHUNK * int(hot.sum())
+    assert 0 < rates["ell_push"]["slots"] <= ops.fwd.scan_slots
+    assert rates["pull_binned"]["slots"] == ops.rev_binned.scan_slots
     for r in rates.values():
         assert np.isfinite(r["ms_per_slot"]) and r["ms_per_slot"] > 0
 

@@ -623,9 +623,9 @@ def test_gang_ntkms_lane_morsels():
 
 def test_gang_engine_direct_bellman_ford():
     """The gang engine is edge-compute generic: weighted relax (merge=min,
-    no lane formulation => vmap batching) resumed from freshly-initialized
-    states must match the BFS oracle on a unit-weight graph, with correct
-    per-morsel trip counts and an inert pad slot."""
+    no lane formulation => members mapped one by one) resumed from
+    freshly-initialized states must match the BFS oracle on a unit-weight
+    graph, with correct per-morsel trip counts and an inert pad slot."""
     from repro.core import build_gang_resume_engine
     from repro.core.edge_compute import EDGE_COMPUTES
     from repro.core.policies import hybrid_phases

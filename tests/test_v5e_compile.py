@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import build_operands, policy_ntks
+from repro.core import build_operands, policy_ntkms, policy_ntks
 from repro.core.dispatcher import build_engine, strip_operands
 from repro.core.extend import (
     BinnedPullBackend,
@@ -27,6 +27,7 @@ from repro.core.extend import (
     PushBackend,
     as_spec,
 )
+from repro.graph.csr import SCAN_CHUNK
 from repro.graph.generators import ldbc_proxy
 from repro.kernels.binned_pull.ops import binned_pull
 
@@ -113,6 +114,34 @@ def test_served_engine_compiles_for_v5e(mesh11, served_operands):
     # the name the device trace's XLA Modules line gives this program
     assert compiled.as_text().startswith(
         "HloModule jit_engine_phase1_ntks_dopt_binned")
+
+
+def test_lane_engine_compiles_for_v5e(mesh11, served_operands):
+    """The phase-1 nTkMS engine of 64-lane morsels: its binned scans read
+    only the live chunks, through fixed ``[chunks, SCAN_CHUNK]`` views
+    of the flat slot arrays and one step's shapes, so no slab's own
+    ``[rows, width]`` shape enters the program."""
+    spec, ops, n_pad = served_operands
+    eng = build_engine(
+        mesh11, policy_ntkms(), "msbfs_lengths", n_pad, 8, sync="shard",
+        extend=spec, collect_stats=True,
+    )
+    morsels = jax.ShapeDtypeStruct(
+        (1, 64), jnp.int32, sharding=NamedSharding(mesh11, P("data", None))
+    )
+    pair = jax.ShapeDtypeStruct(
+        (2,), jnp.float32, sharding=NamedSharding(mesh11, P())
+    )
+    compiled = eng.fn.lower(ops, morsels, pair).compile()
+    _fits_one_chip(compiled, _slot_bytes(ops.fwd, ops.rev_binned))
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_engine_phase1_ntkms_dopt_binned")
+    for bn in (ops.fwd, ops.rev_binned):
+        chunks = bn.scan_slots // SCAN_CHUNK
+        assert f"s32[{chunks},{SCAN_CHUNK}]" in text
+        for rows, width in bn.shapes:
+            if rows > 1 and width > 1:
+                assert f"[{rows},{width}]" not in text, (rows, width)
 
 
 @pytest.mark.parametrize(
